@@ -14,6 +14,12 @@ profiler-only are retained in the always-on ring buffer and come out in
 stall dumps and Perfetto exports.  ``RAFT_OBS_SPANS=0`` disables just
 the recording half; ``RAFT_TPU_TRACING=0`` disables both.
 
+One clock: the span is opened first and the profiler annotation second,
+carrying the span's ``span_id`` and ``t_ns`` (its start on the recorder's
+clock) as annotation metadata.  A ``jax.profiler`` capture stamps its
+events from its own start, so ``start_ns - t_ns`` of any annotation in it
+is the one offset that lays the capture over the flight recorder.
+
 Push/pop discipline (satellite of ISSUE 9): :func:`pop_range` is safe on
 an empty per-thread stack (returns ``False`` and counts
 ``raft_tracing_unbalanced_pops_total`` instead of raising or silently
@@ -50,23 +56,35 @@ def _recorder():
     return recorder()
 
 
+def _annotation(name: str, span):
+    """The profiler annotation for ``span``: its id and start on the
+    recorder's clock ride along as metadata (the event name is
+    unchanged)."""
+    if span is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, span_id=span.span_id,
+                                        t_ns=span.t_start_ns)
+
+
 @contextlib.contextmanager
-def range(fmt: str, *args):
+def range(fmt: str, *args, **attrs):
     """RAII-style range (``nvtx::range`` parity). Usage::
 
         with tracing.range("select_k(batch=%d,k=%d)", batch, k):
             ...
 
-    Emits the profiler annotation + HLO scope AND a flight-recorder span
-    (auto-parented to the innermost open range/span on this thread).
+    Records a flight-recorder span (auto-parented to the innermost open
+    range/span on this thread, carrying ``attrs``), then emits the
+    profiler annotation stamped with that span's id and start, and the
+    HLO scope.  Yields the span (``None`` when recording is off).
     """
     if not _ENABLED:
-        yield
+        yield None
         return
     name = (fmt % args) if args else fmt
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name), \
-            _recorder().span(name):
-        yield
+    with _recorder().span(name, **attrs) as span, \
+            _annotation(name, span), jax.named_scope(name):
+        yield span
 
 
 def push_range(fmt: str, *args) -> None:
@@ -74,9 +92,9 @@ def push_range(fmt: str, *args) -> None:
     if not _ENABLED:
         return
     name = (fmt % args) if args else fmt
-    cm = jax.profiler.TraceAnnotation(name)
-    cm.__enter__()
     span = _recorder().start(name)
+    cm = _annotation(name, span)
+    cm.__enter__()
     _stack().append((cm, span))
 
 

@@ -185,16 +185,25 @@ class StallWatchdog:
         """Best-effort ``jax.profiler`` capture.  The profiler runs on
         *this* thread — a wedge that blocks the dispatch thread usually
         leaves the runtime traceable; when it does not, the error string
-        is the evidence."""
+        is the evidence.  The capture holds one ``obs.stall_capture``
+        annotation whose ``span_id`` and ``t_ns`` (monotonic ns) are
+        returned too: its ``start_ns - t_ns`` in the capture is the
+        offset onto ``flight.trace.json``."""
         try:
             import jax
 
+            from ..core import tracing
+
             jax.profiler.start_trace(logdir)
             try:
-                self._sleep(self.capture_s)
+                with tracing.range("obs.stall_capture") as span:
+                    self._sleep(self.capture_s)
             finally:
                 jax.profiler.stop_trace()
-            return {"ok": True, "logdir": logdir}
+            out = {"ok": True, "logdir": logdir}
+            if span is not None:
+                out.update(span_id=span.span_id, t_ns=span.t_start_ns)
+            return out
         except Exception as exc:  # noqa: BLE001 - evidence, not control flow
             return {"ok": False, "error": repr(exc)}
 

@@ -74,7 +74,7 @@ def _kernel(x_ref, y_ref, yn_ref, v1_ref, i1_ref, v2_ref, i2_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def _call(xb, yb, yn, bm, bn, interpret):
+def fused_l2_topk(xb, yb, yn, bm, bn, interpret):
     m = xb.shape[0]
     n = yb.shape[0]
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn))
@@ -95,6 +95,7 @@ def _call(xb, yb, yn, bm, bn, interpret):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_l2_topk",
     )(xb, yb, yn)
     # reconstruct column ids: col = block_id * BN + lane position
     lane = jax.lax.broadcasted_iota(jnp.int32, (m, bn), 1)
@@ -161,7 +162,7 @@ def fused_shortlist(
     yn = yn.reshape(1, -1).astype(jnp.float32)
     from .gate import interpret
 
-    return _call(x, y, yn, bm, bn, interpret("fused_l2_topk"))
+    return fused_l2_topk(x, y, yn, bm, bn, interpret("fused_l2_topk"))
 
 
 def center_int8(a: jax.Array) -> jax.Array:

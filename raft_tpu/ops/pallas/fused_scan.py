@@ -76,7 +76,7 @@ def _kernel(q_ref, v_ref, b_ref, v1_ref, i1_ref, v2_ref, i2_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def _call(q, vecs, base, bm, bn, interpret):
+def fused_scan(q, vecs, base, bm, bn, interpret):
     nq, c, d = vecs.shape
     grid = (pl.cdiv(nq, bm), c // bn)
     out_spec = pl.BlockSpec((bm, bn), lambda i, j: (i, 0),
@@ -100,6 +100,7 @@ def _call(q, vecs, base, bm, bn, interpret):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_scan",
     )(q, vecs, base)
     # reconstruct slab positions: pos = c_block_id * BN + lane position
     lane = jax.lax.broadcasted_iota(jnp.int32, (nq, bn), 1)
@@ -153,5 +154,5 @@ def fused_slab_topk(
         vecs = jnp.pad(vecs, ((0, 0), (0, cpad), (0, 0)))
         base = jnp.pad(base, ((0, 0), (0, cpad)), constant_values=jnp.inf)
     bm = min(bm, max(1, nq))
-    return _call(q.astype(jnp.bfloat16), vecs.astype(jnp.bfloat16),
-                 base.astype(jnp.float32), bm, bn, interpret)
+    return fused_scan(q.astype(jnp.bfloat16), vecs.astype(jnp.bfloat16),
+                      base.astype(jnp.float32), bm, bn, interpret)

@@ -84,7 +84,7 @@ def _kernel(x_ref, val_ref, idx_ref, *, k: int, kpad: int, bn: int, length: int)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bm", "bn", "interpret"))
-def _call(x, k: int, bm: int, bn: int, interpret: bool):
+def select_k(x, k: int, bm: int, bn: int, interpret: bool):
     batch, length = x.shape
     kpad = max(_LANES, ((k + _LANES - 1) // _LANES) * _LANES)
     grid = (pl.cdiv(batch, bm), pl.cdiv(length, bn))
@@ -106,6 +106,7 @@ def _call(x, k: int, bm: int, bn: int, interpret: bool):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="select_k",
     )(x)
     return val[:batch, :k], idx[:batch, :k]
 
@@ -140,7 +141,7 @@ def select_k_pallas(
     x = in_val if select_min else -in_val
     from .gate import interpret
 
-    val, idx = _call(x, int(k), bm, bn, interpret("select_k"))
+    val, idx = select_k(x, int(k), bm, bn, interpret("select_k"))
     if not select_min:
         val = -val
     return val.astype(in_val.dtype), idx

@@ -392,39 +392,43 @@ class SearchServer:
         the accelerator."""
         if now is None:
             now = self.clock()
-        t_plan = self.recorder.clock_ns() if self.recorder.enabled else 0
-        with self._cond:
-            expired = [r for r in self._pending if r.deadline < now]
-            if expired:
-                self._pending = [r for r in self._pending
-                                 if r.deadline >= now]
-            if not self._pending:
-                batch = None
-            else:
-                depth = len(self._pending)
-                batch, bucket = plan_batch(self._pending, self.ladder)
-                chosen = set(map(id, batch))
-                self._pending = [r for r in self._pending
-                                 if id(r) not in chosen]
-        for req in expired:
-            self.metrics.count("rejected_deadline")
-            self.recorder.finish(req.span, status="rejected_deadline")
-            req.reject(DeadlineExceeded(
-                f"deadline passed {1e3 * (now - req.deadline):.1f}ms before"
-                " dispatch (queue wait exceeded the budget)"))
-        if batch is None:
-            return len(expired)
-        if self.recorder.enabled:
-            # post-hoc: planning ran under the queue lock; the span is
-            # recorded after release, parented to the batch head's request
-            self.recorder.record("serve.batch_form", t_plan,
-                                 self.recorder.clock_ns(),
-                                 parent=batch[0].span,
-                                 n_requests=len(batch), queue_depth=depth)
-        level = self.admission.guarded_level(
-            depth, self._apply_quality_guard,
-            max_level=len(self.config.degrade_effort_scales) - 1)
-        self._execute(batch, bucket, level)
+        with tracing.range("serve.dispatch(%s):form", self.family) as form:
+            t_plan = self.recorder.clock_ns() if self.recorder.enabled \
+                else 0
+            with self._cond:
+                expired = [r for r in self._pending if r.deadline < now]
+                if expired:
+                    self._pending = [r for r in self._pending
+                                     if r.deadline >= now]
+                if not self._pending:
+                    batch = None
+                else:
+                    depth = len(self._pending)
+                    batch, bucket = plan_batch(self._pending, self.ladder)
+                    chosen = set(map(id, batch))
+                    self._pending = [r for r in self._pending
+                                     if id(r) not in chosen]
+            for req in expired:
+                self.metrics.count("rejected_deadline")
+                self.recorder.finish(req.span, status="rejected_deadline")
+                req.reject(DeadlineExceeded(
+                    f"deadline passed {1e3 * (now - req.deadline):.1f}ms"
+                    " before dispatch (queue wait exceeded the budget)"))
+            if batch is None:
+                return len(expired)
+            if self.recorder.enabled:
+                # post-hoc: planning ran under the queue lock; the span is
+                # recorded after release, parented to the batch head's
+                # request
+                self.recorder.record("serve.batch_form", t_plan,
+                                     self.recorder.clock_ns(),
+                                     parent=batch[0].span,
+                                     n_requests=len(batch),
+                                     queue_depth=depth)
+            level = self.admission.guarded_level(
+                depth, self._apply_quality_guard,
+                max_level=len(self.config.degrade_effort_scales) - 1)
+        self._execute(batch, bucket, level, form)
         return len(expired) + len(batch)
 
     def _apply_quality_guard(self, level: int) -> int:
@@ -508,10 +512,16 @@ class SearchServer:
 
         return self.cache.get(key, build), operands
 
-    def _execute(self, batch, bucket: int, level: int) -> None:
+    def _execute(self, batch, bucket: int, level: int, form) -> None:
+        """Run one planned batch: the host phases ``stage`` (pad and put
+        the queries), ``launch`` (look up and call the executable),
+        ``fetch`` (wait for and copy back the answers) and ``reply`` are
+        sibling ``tracing`` ranges named ``serve.dispatch(<family>,b=,k=,
+        lvl=):<phase>``, each carrying ``dispatch=`` the id of this
+        batch's ``serve.dispatch`` span (``form``, the ``step()`` range
+        that planned the batch, gets it too)."""
         rows = sum(r.rows for r in batch)
-        qpad = pad_rows(np.concatenate([r.queries for r in batch], axis=0)
-                        if len(batch) > 1 else batch[0].queries, bucket)
+        k = batch[0].k
         retry = self.config.retry
         backoffs = retry.start(self._retry_rng)
         attempt = 0
@@ -524,26 +534,34 @@ class SearchServer:
             level=int(level), n_requests=len(batch),
             request_spans=[r.span.span_id for r in batch
                            if r.span is not None])
+        phase = {"dispatch": dispatch.span_id} if dispatch is not None \
+            else {}
+        if form is not None:
+            form.attrs.update(phase)
+        name = "serve.dispatch(%s,b=%d,k=%d,lvl=%d)" % (
+            self.family, bucket, k, level)
         self._inflight = ("execute", self.clock())
         try:
             while True:
                 try:
-                    self.faults.fire("execute")
-                    compiled, operands = self._compiled(bucket, batch[0].k,
-                                                        qpad.dtype, level)
-                    with self.recorder.span("serve.device_exec",
-                                            parent=dispatch,
-                                            attempt=attempt), \
-                            tracing.range(
-                                "serve.dispatch(%s,b=%d,k=%d,lvl=%d)",
-                                self.family, bucket, batch[0].k, level):
-                        # explicit transfers at the serving boundary:
-                        # device_put / device_get pass
-                        # ``jax.transfer_guard("disallow")``, so a
-                        # TraceGuard-wrapped serve loop proves these are the
-                        # ONLY host<->device crossings on the path
-                        d, i = compiled(self._stage_queries(qpad), *operands)
-                        d, i = jax.device_get((d, i))  # host fetch = completion barrier
+                    # explicit transfers at the serving boundary:
+                    # device_put / device_get pass
+                    # ``jax.transfer_guard("disallow")``, so a
+                    # TraceGuard-wrapped serve loop proves these are the
+                    # ONLY host<->device crossings on the path
+                    with tracing.range(name + ":stage", **phase):
+                        qpad = pad_rows(
+                            np.concatenate([r.queries for r in batch], axis=0)
+                            if len(batch) > 1 else batch[0].queries, bucket)
+                        q = self._stage_queries(qpad)
+                    with tracing.range(name + ":launch", **phase):
+                        self.faults.fire("execute")
+                        compiled, operands = self._compiled(
+                            bucket, k, qpad.dtype, level)
+                        d, i = compiled(q, *operands)
+                    with tracing.range(name + ":fetch", **phase):
+                        # host fetch = completion barrier
+                        d, i = jax.device_get((d, i))
                         d = np.asarray(d)
                         i = np.asarray(i)
                     break
@@ -590,28 +608,30 @@ class SearchServer:
             self._inflight = None
         self.recorder.finish(dispatch, status="ok", attempts=attempt + 1)
         done = self.clock()
-        self.metrics.observe_batch(bucket, rows, level)
-        lo = 0
-        for req in batch:
-            hi = lo + req.rows
-            reply_ns = self.recorder.clock_ns() if self.recorder.enabled else 0
-            req.resolve(d[lo:hi], i[lo:hi])
-            if self.quality is not None:
-                # shadow-sampling hook: one hash per request; selected
-                # requests copy onto the bounded oracle queue (overflow
-                # drops) — the reply above is already on its way
-                self.quality.maybe_sample(
-                    req.queries, i[lo:hi], level=level,
-                    generation=self._registry.gen_id,
-                    scan_kernel=self._scan_kernel)
-            if req.span is not None:
-                self.recorder.record("serve.reply", reply_ns,
-                                     self.recorder.clock_ns(),
-                                     parent=req.span, part=req.part)
-                self.recorder.finish(req.span, status="ok")
-            self.metrics.observe_latency(1e3 * (done - req.t_submit),
-                                         late=done > req.deadline)
-            lo = hi
+        with tracing.range(name + ":reply", **phase):
+            self.metrics.observe_batch(bucket, rows, level)
+            lo = 0
+            for req in batch:
+                hi = lo + req.rows
+                reply_ns = self.recorder.clock_ns() \
+                    if self.recorder.enabled else 0
+                req.resolve(d[lo:hi], i[lo:hi])
+                if self.quality is not None:
+                    # shadow-sampling hook: one hash per request; selected
+                    # requests copy onto the bounded oracle queue (overflow
+                    # drops) — the reply above is already on its way
+                    self.quality.maybe_sample(
+                        req.queries, i[lo:hi], level=level,
+                        generation=self._registry.gen_id,
+                        scan_kernel=self._scan_kernel)
+                if req.span is not None:
+                    self.recorder.record("serve.reply", reply_ns,
+                                         self.recorder.clock_ns(),
+                                         parent=req.span, part=req.part)
+                    self.recorder.finish(req.span, status="ok")
+                self.metrics.observe_latency(1e3 * (done - req.t_submit),
+                                             late=done > req.deadline)
+                lo = hi
 
     # -- generation handoff -------------------------------------------------
 
@@ -695,22 +715,31 @@ class SearchServer:
         wait_s = self.config.max_wait_ms / 1e3
         while True:
             with self._cond:
-                while self._running and not self._pending:
-                    self._cond.wait(0.05)
+                full = sum(r.rows for r in self._pending) >= max_rows
+                if self._running and (not self._pending
+                                      or (wait_s > 0 and not full)):
+                    with tracing.range("serve.dispatch(%s):wait",
+                                       self.family):
+                        self._wait_for_batch(max_rows, wait_s)
                 if not self._running and not self._pending:
                     return
-                # batching window: hold for more arrivals until the
-                # largest bucket fills or the window elapses (real time —
-                # see the clock note in the class docstring)
-                t0 = time.monotonic()
-                while (self._running
-                       and sum(r.rows for r in self._pending) < max_rows):
-                    rem = t0 + wait_s - time.monotonic()
-                    if rem <= 0:
-                        break
-                    self._cond.wait(rem)
             while self.step():
                 pass
+
+    def _wait_for_batch(self, max_rows: int, wait_s: float) -> None:  # racelint: holds _cond
+        """Hold the queue lock's condition until something is queued,
+        then for the batching window: until the largest bucket fills or
+        the window elapses (real time — see the clock note in the class
+        docstring)."""
+        while self._running and not self._pending:
+            self._cond.wait(0.05)
+        t0 = time.monotonic()
+        while (self._running
+               and sum(r.rows for r in self._pending) < max_rows):
+            rem = t0 + wait_s - time.monotonic()
+            if rem <= 0:
+                break
+            self._cond.wait(rem)
 
     # -- observability ------------------------------------------------------
 

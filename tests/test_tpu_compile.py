@@ -41,6 +41,10 @@ _KERNELS = {
     "fused_slab_topk_256x4096x128": ("fused_slab", 256, 4096, 8, 512),
 }
 
+# the device op each kind of kernel shows up as
+_OP_NAMES = {"select_k": "select_k", "fused_l2": "fused_l2_topk",
+             "fused_slab": "fused_scan"}
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -83,19 +87,19 @@ def _kernel_program(case, s):
         from raft_tpu.ops.pallas import select_k
 
         _, shape, k, bm, bn = case
-        return select_k._call, (_spec(shape, jnp.float32, s), k, bm, bn,
+        return select_k.select_k, (_spec(shape, jnp.float32, s), k, bm, bn,
                                 False)
     if kind == "fused_l2":
         from raft_tpu.ops.pallas import fused_l2_topk
 
         _, dtype, m, n, bm, bn = case
-        return fused_l2_topk._call, (
+        return fused_l2_topk.fused_l2_topk, (
             _spec((m, 128), dtype, s), _spec((n, 128), dtype, s),
             _spec((1, n), jnp.float32, s), bm, bn, False)
     from raft_tpu.ops.pallas import fused_scan
 
     _, nq, c, bm, bn = case
-    return fused_scan._call, (
+    return fused_scan.fused_scan, (
         _spec((nq, 128), jnp.bfloat16, s),
         _spec((nq, c, 128), jnp.bfloat16, s),
         _spec((nq, c), jnp.float32, s), bm, bn, False)
@@ -103,9 +107,14 @@ def _kernel_program(case, s):
 
 @pytest.mark.parametrize("name", sorted(_KERNELS))
 def test_pallas_kernel_compiles_for_v5e(one_chip, name):
-    fn, args = _kernel_program(_KERNELS[name], one_chip)
-    compiled = fn.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    case = _KERNELS[name]
+    fn, args = _kernel_program(case, one_chip)
+    text = fn.lower(*args).compile().as_text()
+    # the instruction's name is what the profiler's "XLA Ops" line shows
+    kernel = [line.lstrip() for line in text.splitlines()
+              if "tpu_custom_call" in line]
+    assert kernel and all(line.startswith(f"%{_OP_NAMES[case[0]]}.")
+                          for line in kernel), kernel
 
 
 def test_exact_knn_compiles_for_one_v5e(one_chip):
