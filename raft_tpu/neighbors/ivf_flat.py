@@ -11,15 +11,37 @@ TPU-first design:
 * **List layout**: one dense ``[n_lists, cap, d]`` slab + ``[n_lists, cap]``
   source ids, pad entries masked by per-list counts.  Gathers of whole lists
   are contiguous HBM reads; no pointer-chasing.
+* **Stored capacity**: a list holds at most ``⌈list_cap_ratio·n/L⌉``
+  rows; its slab row is padded up to the dtype's sublane tile
+  (:func:`slab_capacity`), so a TPU stores the slab list-major and no
+  search program relayouts it.
 * **Search**: query→centroid distances on the MXU, ``top_k`` probe pick,
-  then one scan iteration per **probe block** of B probe ranks: one
-  ``[nq, B·cap, d]`` slab gather, one batched MXU dot, pads masked, ONE
-  merge into the running top-k via ``select_k`` (same merge primitive as
-  brute force) — ⌈n_probes/B⌉ merges instead of n_probes, with unsorted
-  intermediate carries and a single ranked selection after the scan.
+  then one of two probe scans, chosen from the platform and the index's
+  static shapes (:func:`resolve_scan`, ``scan_kernel="auto"``):
+
+  - **grouped** (list-major) on a TPU for batches that probe a list
+    half a time or more on average (:func:`grouped_batch`), wherever
+    its kernel takes the input (:func:`grouped_takes`): the (query,
+    list) pairs are sorted by list and cut into tiles of 16 query slots;
+    one Pallas kernel scores each tile with one ``[16, d]·[d, cap]`` MXU
+    product at ``precision=HIGHEST`` and keeps its exact top-k, so a
+    list is read once per run of tiles instead of once per query
+    (``ops.blocked_scan.scan_topk_grouped``).  Same candidate set as
+    the query-major scan; distances within a few ulps.
+  - **query-major** off a TPU, for smaller batches, 8-bit and bf16
+    slabs, per-query filter bitmaps, k > 128, lists too large for VMEM,
+    ``scan_kernel="xla"|"fused"`` and :func:`search_sharded`: one scan
+    iteration per **probe block** of B probe ranks: one
+    ``[nq, B·cap, d]`` slab gather, one batched dot, pads masked, ONE
+    merge into the running top-k via ``select_k`` — ⌈n_probes/B⌉ merges
+    instead of n_probes, with unsorted intermediate carries and a single
+    ranked selection after the scan.  Results are bit-identical for
+    every B (the cross-block invariance contract holds for this path);
+    B defaults from the measured ``_probe_block_table``
+    (``bench/tune_probe_block.py``).
+
   Everything static-shape, jit-compiled once per
-  (nq, k, n_probes, probe_block) config; B defaults from the measured
-  ``_probe_block_table`` (``bench/tune_probe_block.py``).
+  (nq, k, n_probes, probe_block, scan_kernel) config.
 * **Sharded variant**: lists are partitioned round-robin over the mesh axis;
   every shard searches its local lists with the same program and the
   per-shard candidates merge with one ``all_gather`` + ``select_k`` -- the
@@ -77,17 +99,21 @@ class IvfFlatIndexParams:
 class IvfFlatSearchParams:
     n_probes: int = 32
     query_chunk: int = 4096  # cap on the [chunk, cap, d] gather working set
-    # probes gathered+scored+merged per scan step; 0 = auto (measured
-    # table via bench/tune_probe_block.py, else a working-set heuristic).
-    # Results are bit-identical for every value — this is a pure
-    # latency/throughput knob (docs/tuning_guide.md).
+    # probes gathered+scored+merged per query-major scan step; 0 = auto
+    # (measured table via bench/tune_probe_block.py, else a working-set
+    # heuristic).  Results are bit-identical for every value — this is a
+    # pure latency/throughput knob (docs/tuning_guide.md); the grouped
+    # scan takes no probe blocks.
     probe_block: int = 0
-    # blocked-scan engine: "auto" | "xla" | "fused".  "xla" is the
-    # bit-exact two-pass scan; "fused" runs the Pallas distance+partial
-    # top-k kernel per block with an exact re-score of 4k finalists
-    # (recall-gated, not bit-pinned).  "auto" resolves through
-    # ops.blocked_scan.resolve_scan_kernel (Mosaic gate + tuned table) and
-    # is always "xla" off-TPU (docs/tuning_guide.md).
+    # probe-scan engine: "auto" | "grouped" | "xla" | "fused".  "grouped"
+    # is the list-major scan (one MXU product per tile of queries sharing
+    # a list); "xla" is the bit-exact query-major two-pass scan; "fused"
+    # runs the Pallas distance+partial top-k kernel per block with an
+    # exact re-score of 4k finalists (recall-gated, not bit-pinned).
+    # "auto" takes "grouped" on a TPU wherever grouped_takes() allows it,
+    # else resolves through ops.blocked_scan.resolve_scan_kernel (tuned
+    # table), which is "xla" where no table says otherwise.  A "grouped"
+    # program scans batches too small for grouped_batch() query-major.
     scan_kernel: str = "auto"
 
 
@@ -116,6 +142,18 @@ class IvfFlatIndex:
     @property
     def size(self) -> int:
         return int(jnp.sum(self.counts))  # jaxlint: disable=JX01 size is a host-facing API scalar, not on the search path
+
+
+def slab_capacity(cap: int, dtype) -> int:
+    """Stored rows per list of a slab whose lists hold at most ``cap``
+    rows: ``cap`` rounded up to the dtype's sublane tile (8 rows of f32,
+    16 of bf16, 32 of 8-bit); the extra slots are padding.  On a TPU a
+    ``[L, cap, d]`` slab whose ``cap`` is not a multiple of the tile is
+    stored with the list axis second-minor, so every list-major read
+    first relayouts the whole slab; an aligned ``cap`` is stored
+    list-major."""
+    tile = max(8, 32 // jnp.dtype(dtype).itemsize)
+    return -(-int(cap) // tile) * tile
 
 
 @tracing.annotate("ivf_flat.build")
@@ -148,7 +186,8 @@ def build(dataset, params: Optional[IvfFlatIndexParams] = None, *,
     ids = (jnp.asarray(source_ids, jnp.int32) if source_ids is not None
            else jnp.arange(n, dtype=jnp.int32))
     (data, out_ids), counts = pack_lists(
-        labels, (x, ids), n_lists=p.n_lists, cap=cap, fills=(0.0, -1))
+        labels, (x, ids), n_lists=p.n_lists, cap=slab_capacity(cap, x.dtype),
+        fills=(0.0, -1))
     norms = jnp.sum(data.astype(jnp.float32) ** 2, axis=2)
     return IvfFlatIndex(centroids, data, out_ids, counts, norms, p.metric)
 
@@ -182,7 +221,8 @@ def _flat_step_impl(slabs, counts, centroids, xc, idc, *,
     between the stages.  Pad rows (``idc < 0``, from the fixed-shape tail
     padding) never request a list, never consume capacity, and
     scatter-drop via label −1, so the padded stream is bit-identical to
-    the unpadded per-op loop.
+    the unpadded per-op loop.  ``cap`` bounds each list's rows; the slabs
+    may store more slots (:func:`slab_capacity`).
 
     Two jitted forms: :func:`_flat_chunk_step` donates the slabs (build
     loops own their buffers); :func:`_flat_chunk_step_cow` leaves the
@@ -195,7 +235,7 @@ def _flat_step_impl(slabs, counts, centroids, xc, idc, *,
     valid = idc >= 0
     labels, _ = _capped_assign_impl(xc, centroids, cap - counts, valid)
     return _scatter_append_impl(slabs, counts, labels, (xc, idc),
-                                n_lists=n_lists, cap=cap)
+                                n_lists=n_lists, cap=slabs[0].shape[1])
 
 
 _flat_chunk_step = partial(jax.jit, static_argnames=("n_lists", "cap"),
@@ -212,9 +252,9 @@ def _stream_pipelined(dataset, centroids, p: IvfFlatIndexParams, n: int,
     :func:`_flat_chunk_step` — one executable, one dispatch per chunk."""
     from ._packing import device_full, prefetch_chunks_padded
 
-    d = dataset.shape[1]
-    data = device_full((p.n_lists, cap, d), 0, dtype)
-    ids_slab = device_full((p.n_lists, cap), -1, jnp.int32)
+    d, slab_cap = dataset.shape[1], slab_capacity(cap, dtype)
+    data = device_full((p.n_lists, slab_cap, d), 0, dtype)
+    ids_slab = device_full((p.n_lists, slab_cap), -1, jnp.int32)
     counts = device_full((p.n_lists,), 0, jnp.int32)
     for lo, hi, xc, idc in prefetch_chunks_padded(dataset, chunk_rows,
                                                   source_ids, dtype=dtype):
@@ -236,8 +276,9 @@ def _stream_perop(dataset, centroids, p: IvfFlatIndexParams, n: int,
     from ..cluster.kmeans import capped_assign_room
     from ._packing import prefetch_chunks, scatter_append
 
-    data = jnp.zeros((p.n_lists, cap, dataset.shape[1]), dtype)
-    ids_slab = jnp.full((p.n_lists, cap), -1, jnp.int32)
+    slab_cap = slab_capacity(cap, dtype)
+    data = jnp.zeros((p.n_lists, slab_cap, dataset.shape[1]), dtype)
+    ids_slab = jnp.full((p.n_lists, slab_cap), -1, jnp.int32)
     counts = jnp.zeros((p.n_lists,), jnp.int32)
     for lo, hi, xc_h, idc_h in prefetch_chunks(dataset, chunk_rows,
                                                source_ids):
@@ -246,7 +287,7 @@ def _stream_perop(dataset, centroids, p: IvfFlatIndexParams, n: int,
         labels, _ = capped_assign_room(xc, centroids, cap - counts)
         (data, ids_slab), counts = scatter_append(
             (data, ids_slab), counts, labels, (xc, idc),
-            n_lists=p.n_lists, cap=cap)
+            n_lists=p.n_lists, cap=slab_cap)
     return data, ids_slab, counts
 
 
@@ -376,7 +417,8 @@ def extend(index: IvfFlatIndex, new_vectors, new_ids=None, *,
         added = jax.ops.segment_sum(jnp.ones_like(labels, jnp.int32),
                                     labels, num_segments=L)
         need = int(jnp.max(index.counts + added))  # jaxlint: disable=JX01 slab capacity must be a host int at extend time (static shapes)
-        new_cap = max(need, cap + (cap + 1) // 2)  # geometric headroom
+        new_cap = slab_capacity(max(need, cap + (cap + 1) // 2),
+                                index.data.dtype)  # geometric headroom
         pad = new_cap - cap
         grown = (jnp.pad(index.data, ((0, 0), (0, pad), (0, 0))),
                  jnp.pad(index.ids, ((0, 0), (0, pad)), constant_values=-1))
@@ -386,16 +428,109 @@ def extend(index: IvfFlatIndex, new_vectors, new_ids=None, *,
                         index.metric)
 
 
+#: the grouped scan's least share of probes per list in one batch,
+#: nq·P / L.  Measured on a v5e at 1024 lists × 32 probes (one search):
+#: the query-major scan is faster at 8 rows, 0.25 a list (1.58 against
+#: 1.94 ms), the grouped one from 16 rows, 0.5 a list (2.77 against
+#: 3.43 ms; 4.70 against 10.59 at 64 rows).  Below that most tiles hold
+#: a single live query slot.
+GROUPED_MIN_PROBES_PER_LIST = 0.5
+#: the grouped kernel keeps one whole list in VMEM, double-buffered
+GROUPED_MAX_LIST_BYTES = 4 << 20
+#: the grouped kernel's k min-extraction passes run per tile row
+GROUPED_MAX_K = 128
+
+
+def grouped_takes(cap: int, dim: int, k: int, dtype,
+                  keep_ndim: int = 0) -> bool:
+    """Whether the grouped kernel takes a search over an index's
+    ``[L, cap, dim]`` slab of ``dtype``: f32 slabs whose lists fit its
+    VMEM block, k ≤ ``GROUPED_MAX_K``, and no per-query filter bitmap
+    (``keep_ndim`` 2)."""
+    return not (jnp.dtype(dtype) != jnp.float32 or keep_ndim == 2
+                or k > GROUPED_MAX_K
+                or cap * dim * 4 > GROUPED_MAX_LIST_BYTES)
+
+
+def grouped_batch(nq: int, n_probes: int, n_lists: int) -> bool:
+    """Whether a batch of ``nq`` rows takes the grouped scan: where it
+    probes the index's ``n_lists`` lists ``GROUPED_MIN_PROBES_PER_LIST``
+    times or more a list on average.  A fleet
+    shard passes the whole index's ``n_lists`` and so decides as the
+    single-device program does."""
+    return nq * n_probes >= GROUPED_MIN_PROBES_PER_LIST * n_lists
+
+
+def resolve_scan(requested: str, index: IvfFlatIndex, k: int,
+                 probe_block: int, keep=None) -> str:
+    """The probe scan a search over ``index`` runs.  ``"auto"`` takes the
+    grouped scan on a TPU where :func:`grouped_takes` allows it, else
+    what ``ops.blocked_scan.resolve_scan_kernel`` picks.  Off a TPU the
+    kernel runs interpreted, slower than the XLA gather, so there the
+    grouped scan runs only when asked for by name.  ``"grouped"``,
+    ``"xla"`` and ``"fused"`` as given.  A ``"grouped"`` program scans a
+    batch too small for :func:`grouped_batch` query-major."""
+    from ..ops.blocked_scan import resolve_scan_kernel
+    from ..ops.pallas.gate import on_tpu
+
+    takes = grouped_takes(index.list_cap, index.dim, int(k),
+                          index.data.dtype, 0 if keep is None else keep.ndim)
+    if requested == "grouped":
+        expects(takes, "the grouped scan takes f32 lists of at most "
+                f"{GROUPED_MAX_LIST_BYTES} bytes, k <= {GROUPED_MAX_K} and "
+                "no per-query bitmap")
+        return "grouped"
+    kernel = resolve_scan_kernel(requested, "ivf_flat",
+                                 probe_block * index.list_cap, int(k))
+    return "grouped" if requested == "auto" and takes and on_tpu() else kernel
+
+
+def count_scan_path(path: str) -> None:
+    """Count one lowering of ``path`` in
+    ``raft_ivf_scan_path_total{path}`` (the program's trace runs once per
+    compiled program, so a chip run shows which buckets took which
+    path)."""
+    from ..obs.metrics import registry
+
+    registry().counter(
+        "raft_ivf_scan_path_total",
+        "IVF-Flat probe-scan lowerings by path",
+    ).inc(path=path)
+
+
+def slot_bias(norms, ids, counts, metric: str, keep=None):
+    """``[L, 1, cap]`` per-slot offset of the grouped scan: the stored
+    squared norm (L2 metrics) or 0 (inner product) where the slot holds
+    a live row (below ``counts``, id ≥ 0, kept by ``keep``), ``+inf``
+    where it does not — the masks of the query-major scan."""
+    from ._packing import keep_lookup
+
+    cap = ids.shape[1]
+    live = (jnp.arange(cap)[None, :] < counts[:, None]) & (ids >= 0)
+    if keep is not None:
+        live = live & keep_lookup(keep, ids)
+    base = (jnp.zeros(norms.shape, jnp.float32) if metric == "inner_product"
+            else norms.astype(jnp.float32))
+    return jnp.where(live, base, jnp.inf)[:, None, :]
+
+
 def _probe_scan(q, qn, data, ids, counts, norms, probes, k: int, metric: str,
                 keep=None, probe_block: int = 1, scan_kernel: str = "xla"):
-    """Scan probe *blocks* through the shared ``ops.blocked_scan`` core.
+    """Scan the probed lists through the shared ``ops.blocked_scan`` core.
 
-    q: [nq, d]; probes: [nq, P].  One iteration gathers the next B probed
-    lists of every query (one ``[nq, B·cap, d]`` slab), scores it with
-    ``slab_dots`` (B pinned in the einsum's batch dims — the bit-invariance
-    contract: results identical across block sizes) and folds it into the
-    running top-k — ⌈P/B⌉ merges instead of P.  Pad probes (P not
-    divisible by B) are masked to +inf, never duplicated.
+    q: [nq, d]; probes: [nq, P].  ``scan_kernel="grouped"`` runs the
+    list-major scan (``blocked_scan.scan_topk_grouped``): pairs sorted by
+    list, one MXU product per tile of queries sharing a list, each list
+    read once per run of tiles; distances within a few ulps of the
+    query-major scan's, the same candidate set, and ``probe_block``
+    unused.
+
+    The query-major scans gather, per iteration, the next B probed lists
+    of every query (one ``[nq, B·cap, d]`` slab), score it with
+    ``slab_dots`` (B pinned in the einsum's batch dims — the
+    bit-invariance contract: results identical across block sizes) and
+    fold it into the running top-k — ⌈P/B⌉ merges instead of P.  Pad
+    probes (P not divisible by B) are masked to +inf, never duplicated.
     ``keep``: optional bool prefilter by source id.  ``scan_kernel``:
     ``"xla"`` (bit-exact two-pass) or ``"fused"`` (Pallas distance+partial
     top-k in one kernel, exact re-score of 4k finalists — recall-gated,
@@ -405,6 +540,11 @@ def _probe_scan(q, qn, data, ids, counts, norms, probes, k: int, metric: str,
 
     nq = q.shape[0]
     cap = data.shape[1]
+    if scan_kernel == "grouped":
+        bias = slot_bias(norms, ids, counts, metric, keep)
+        return _scan.scan_topk_grouped(
+            q.astype(jnp.float32), qn, data, bias, ids, probes, k,
+            l2=metric != "inner_product")
     lists_xs, pvalid = blocked_probe_plan(probes, probe_block)
 
     def gather(inp):
@@ -456,8 +596,13 @@ def _probe_scan(q, qn, data, ids, counts, norms, probes, k: int, metric: str,
 def _search_impl(centroids, data, ids, counts, norms, q, k: int,
                  n_probes: int, metric: str, keep=None,
                  probe_block: int = 1, scan_kernel: str = "xla"):
-    from ..ops.blocked_scan import row_sq_norms
+    from ..ops.blocked_scan import resolve_scan_kernel, row_sq_norms
 
+    if scan_kernel == "grouped" and not grouped_batch(q.shape[0], n_probes,
+                                                      data.shape[0]):
+        scan_kernel = resolve_scan_kernel("auto", "ivf_flat",
+                                          probe_block * data.shape[1], k)
+    count_scan_path("grouped" if scan_kernel == "grouped" else "query_major")
     qf = q.astype(jnp.float32)
     qn = row_sq_norms(qf)   # dot-contraction: rounds the same in the
     # fleet's SPMD executable (serve bit-identity, ops.blocked_scan doc)
@@ -492,13 +637,10 @@ def search(index: IvfFlatIndex, queries, k: int,
     n_probes = min(p.n_probes, index.n_lists)
     probe_block = resolve_probe_block(p.probe_block, int(n_probes),
                                       index.list_cap, "ivf_flat")
-    from ..ops.blocked_scan import resolve_scan_kernel
-
-    scan_kernel = resolve_scan_kernel(p.scan_kernel, "ivf_flat",
-                                      probe_block * index.list_cap, int(k))
     keep = as_keep_mask(filter, nq=q.shape[0])  # indexes source ids
     if keep is not None:
         check_filter_covers_ids(keep, index.ids)
+    scan_kernel = resolve_scan(p.scan_kernel, index, k, probe_block, keep)
 
     impl = lambda qc, kc: _search_impl(
         index.centroids, index.data, index.ids, index.counts,
@@ -534,12 +676,9 @@ def searcher(index: IvfFlatIndex, k: int,
     n_probes = int(min(p.n_probes, index.n_lists))
     probe_block = resolve_probe_block(p.probe_block, n_probes,
                                       index.list_cap, "ivf_flat")
-    from ..ops.blocked_scan import resolve_scan_kernel
-
-    scan_kernel = resolve_scan_kernel(p.scan_kernel, "ivf_flat",
-                                      probe_block * index.list_cap, int(k))
     metric = index.metric
     keep = as_keep_mask(filter)
+    scan_kernel = resolve_scan(p.scan_kernel, index, k, probe_block, keep)
     if keep is not None:
         expects(keep.ndim == 1,
                 "serving filters are shared bitsets (1-D); per-query "
@@ -594,7 +733,8 @@ def _sharded_build_program(mesh: Mesh, axis: str, n_orig: int, per: int,
         # rows padded to even out the shards are dropped here, not stored
         labels = jnp.where(gid < n_orig, labels, -1)
         (data, out_ids), counts = pack_lists(
-            labels, (x_l, gid), n_lists=n_lists_local, cap=cap,
+            labels, (x_l, gid), n_lists=n_lists_local,
+            cap=slab_capacity(cap, x_l.dtype),
             fills=(0.0, -1))
         norms = jnp.sum(data.astype(jnp.float32) ** 2, axis=2)
         # centroids keep the fit dtype (f32 for integer corpora —
@@ -671,7 +811,7 @@ def _sharded_chunk_step_program(mesh: Mesh, axis: str, n_lists_local: int,
         labels, _ = _capped_assign_impl(xc_l, c_l, cap - counts_l, valid)
         (data_l, ids_l), counts_l = _scatter_append_impl(
             (data_l, ids_l), counts_l, labels, (xc_l, idc_l),
-            n_lists=n_lists_local, cap=cap)
+            n_lists=n_lists_local, cap=data_l.shape[1])
         return data_l, ids_l, counts_l
 
     return jax.jit(shard_map(
@@ -731,8 +871,10 @@ def build_chunked_sharded(dataset, mesh: Mesh,
     centroids = train(xt_sh)
 
     L = n_dev * n_lists_local
-    data = jax.device_put(jnp.zeros((L, cap, d), dtype), sharding)
-    ids_slab = jax.device_put(jnp.full((L, cap), -1, jnp.int32), sharding)
+    slab_cap = slab_capacity(cap, dtype)
+    data = jax.device_put(jnp.zeros((L, slab_cap, d), dtype), sharding)
+    ids_slab = jax.device_put(jnp.full((L, slab_cap), -1, jnp.int32),
+                              sharding)
     counts = jax.device_put(jnp.zeros((L,), jnp.int32), sharding)
     step = _sharded_chunk_step_program(mesh, axis, n_lists_local, cap)
     heartbeat = build_heartbeat("ivf_flat.build_chunked_sharded", n)

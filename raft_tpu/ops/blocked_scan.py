@@ -29,6 +29,16 @@ module owns the contract:
   Approximate-partial: the candidate *set* is recall-gated, not
   bit-pinned (a true neighbor is shed only on a ≥3-way lane-bucket
   collision within one slab block).
+* :func:`scan_topk_grouped` — IVF-Flat's list-major scan for batches that
+  share lists: :func:`grouped_plan` sorts the (query, list) pairs by list
+  into tiles of :data:`GROUPED_TILE` query slots, and one Pallas kernel
+  (``ops/pallas/grouped_scan.py``) scores each tile with one MXU product
+  against its list and keeps each row's exact top-k.  The cross-block
+  bit-invariance contract of :func:`slab_dots` is the query-major path's;
+  the grouped path guarantees instead the same candidate set, distances
+  within a few f32 ulps of the query-major ones (only the dot's
+  accumulation order differs), and results independent of
+  ``probe_block``.
 
 Quantized-scan sub-API
 ----------------------
@@ -73,7 +83,9 @@ __all__ = ["int8_tier_eligible", "exact_gathered_dots", "slab_dots",
            "unpack_sign_bits", "packed_sign_dots",
            "row_sq_norms",
            "fold_topk", "fold_topk_payload", "topk_carry", "ranked_finish",
-           "scan_topk", "scan_topk_fused", "list_slab_ptr", "l2_rescorer",
+           "scan_topk", "scan_topk_fused", "grouped_plan",
+           "grouped_tile_bound", "scan_topk_grouped", "list_slab_ptr",
+           "l2_rescorer",
            "resolve_scan_kernel", "scan_kernel_sha"]
 
 
@@ -344,6 +356,100 @@ def scan_topk_fused(q, slab_step: Callable, xs, rescore: Callable,
     dist = rescore(bp, bi)
     dist = jnp.where(jnp.isfinite(bv) & (bi >= 0), dist, jnp.inf)
     return ranked_finish(dist, bi, k)
+
+
+#: query slots of one list-major tile: the rows of one MXU product
+#: against one list.  Sixteen is the smallest tile of two f32 sublane
+#: groups; a batch probes each list ~16 times at the SIFT-1M-class cell's
+#: 512 queries × 32 probes over 1024 lists, so tiles are mostly full there.
+GROUPED_TILE = 16
+
+
+def grouped_tile_bound(n_pairs: int, n_lists: int, qt: int) -> int:
+    """Static tile count of :func:`grouped_plan`: every list's run of
+    pairs takes ⌈c/Qt⌉ < c/Qt + 1 tiles, so the total stays within
+    ⌈n_pairs/Qt⌉ + min(L, n_pairs) under any skew."""
+    return -(-n_pairs // qt) + min(n_lists, n_pairs)
+
+
+def grouped_plan(lists, n_lists: int, qt: int, pair_valid=None):
+    """List-major plan of a ``[nq, P]`` probe table (slab rows).
+
+    The nq·P (query, list) pairs are sorted by list and each list's run
+    is cut into tiles of ``qt`` query slots, so every tile belongs to
+    exactly one list.  Pairs with ``pair_valid`` False take no slot.
+    Returns ``(tile_list [T], n_used [1], slot_query [T·qt],
+    pair_pos [nq, P])``: each tile's list (idle tiles past ``n_used``
+    repeat the last used list, so they fetch nothing), each slot's query
+    row (0 in unused slots), and each pair's slot (``T·qt`` for an
+    invalid pair).  ``T`` is :func:`grouped_tile_bound`."""
+    nq, n_probes = lists.shape
+    n = nq * n_probes
+    n_tiles = grouped_tile_bound(n, n_lists, qt)
+    flat = lists.reshape(-1).astype(jnp.int32)
+    if pair_valid is not None:  # sentinel list n_lists sorts last
+        flat = jnp.where(pair_valid.reshape(-1), flat, n_lists)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    sl = flat[order]
+    per_list = jnp.zeros((n_lists + 1,), jnp.int32).at[flat].add(1)[:n_lists]
+    tiles_per = (per_list + qt - 1) // qt
+    tile_start = jnp.cumsum(tiles_per) - tiles_per
+    pair_start = jnp.cumsum(per_list) - per_list
+    live = sl < n_lists
+    slc = jnp.minimum(sl, n_lists - 1)
+    rank = jnp.arange(n, dtype=jnp.int32) - pair_start[slc]
+    tile = jnp.where(live, tile_start[slc] + rank // qt, n_tiles)
+    pos = jnp.where(live, tile * qt + rank % qt, n_tiles * qt)
+    slot_query = jnp.zeros((n_tiles * qt,), jnp.int32).at[pos].set(
+        order // n_probes, mode="drop")
+    tile_list = jnp.full((n_tiles,), -1, jnp.int32).at[tile].set(
+        sl, mode="drop")
+    tile_list = jnp.maximum(jax.lax.cummax(tile_list), 0)
+    pair_pos = jnp.zeros((n,), jnp.int32).at[order].set(pos)
+    n_used = jnp.sum(tiles_per, keepdims=True)
+    return tile_list, n_used, slot_query, pair_pos.reshape(nq, n_probes)
+
+
+def scan_topk_grouped(qf, qn, data, bias, ids, lists, k: int, *, l2: bool,
+                      pair_valid=None, qt: int = GROUPED_TILE
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """List-major probe scan (the grouped path of IVF-Flat).
+
+    ``lists`` [nq, P]: each query's probed slab rows; ``data`` [L, cap, d]
+    f32 and ``ids`` [L, cap]; ``bias`` [L, 1, cap]: the stored squared
+    norm (``l2``) or 0 at a live slot, ``+inf`` elsewhere.  One
+    :func:`grouped_plan`, one ``ivf_grouped_scan`` kernel (each tile's
+    exact top-k of its list), the tiles' rows scattered back to
+    ``[nq, P·k]`` through the plan, and one ranked selection per query.
+
+    The candidate set is the query-major scan's: every live row of every
+    probed list, scored by the same algebra at ``precision=HIGHEST``.
+    Only the dot's accumulation order differs (one MXU product per tile,
+    not a per-query mat-vec), so distances agree within a few f32 ulps
+    and ids agree wherever no two candidates tie that closely.  Results
+    do not depend on ``probe_block``.  ``pair_valid`` [nq, P] drops pairs
+    from the plan (a fleet shard's probes of lists it does not own): the
+    pairs that remain form the same tiles, in the same slots, as in the
+    whole index's plan, so a shard scores them as the whole index does."""
+    from .pallas.grouped_scan import grouped_list_topk
+
+    nq, n_probes = lists.shape
+    n_lists, cap = ids.shape
+    tile_list, n_used, slot_query, pair_pos = grouped_plan(
+        lists, n_lists, qt, pair_valid)
+    n_tiles = tile_list.shape[0]
+    qs = qf[slot_query].reshape(n_tiles, qt, qf.shape[1])
+    qns = qn[slot_query].reshape(n_tiles, qt, 1)
+    vals, slots = grouped_list_topk(tile_list, n_used, qs, qns, data, bias,
+                                    k, l2=l2)
+    row = jnp.minimum(pair_pos.reshape(-1), n_tiles * qt - 1)
+    pv = vals.reshape(n_tiles * qt, k)[row].reshape(nq, n_probes, k)
+    ps = slots.reshape(n_tiles * qt, k)[row].reshape(nq, n_probes, k)
+    live = jnp.isfinite(pv) & (pair_pos < n_tiles * qt)[..., None]
+    vids = ids.reshape(-1)[jnp.maximum(lists[..., None] * cap + ps, 0)]
+    pv = jnp.where(live, pv, jnp.inf).reshape(nq, n_probes * k)
+    vids = jnp.where(live, vids, -1).reshape(nq, n_probes * k)
+    return ranked_finish(pv, vids, k)
 
 
 def list_slab_ptr(lists, cap: int):

@@ -10,9 +10,10 @@ Three layers compose here (ISSUE 16 / ROADMAP "pod-scale serving"):
    ``all_gather`` + ranked ``select_k`` finishes the merge.  The result
    is **bit-identical** to the single-device searcher — values AND ids —
    because per-candidate scores never depend on slab partitioning
-   (``slab_dots`` pins the block axis as a batch dim) and the global
-   top-k of a union of per-shard top-ks equals the top-k of all
-   candidates.  ``tests/test_fleet.py`` pins this across mesh widths.
+   (``slab_dots`` pins the block axis as a batch dim; a shard's grouped
+   IVF-Flat plan keeps the single-device tiles of the lists it owns) and
+   the global top-k of a union of per-shard top-ks equals the top-k of
+   all candidates.  ``tests/test_fleet.py`` pins this across mesh widths.
 
 2. **Replica groups + routing** — :class:`FleetServer` runs N
    :class:`_FleetReplicaServer` replicas (each a full
@@ -132,11 +133,18 @@ def _brute_fleet_program(mesh: Mesh, axis: str, k: int, metric: str,
 @lru_cache(maxsize=32)
 def _ivf_flat_fleet_program(mesh: Mesh, axis: str, k: int, n_probes: int,
                             metric: str, probe_block: int, lp: int,
-                            has_keep: bool):
+                            has_keep: bool, grouped: bool, n_lists: int):
     """shard_map'd IVF-Flat fan-out: replicated (padded) centroid table
     ranks the SAME global probe list everywhere; each shard scans only
     the probed lists it owns (owned-mask, not clamp-and-count) and the
-    merge is one all_gather + ranked finish."""
+    merge is one all_gather + ranked finish.
+
+    ``grouped``: the program resolved to the grouped scan, as the
+    single-device one does (``ivf_flat.resolve_scan``); a batch takes it
+    where ``ivf_flat.grouped_batch`` says so for the whole index's
+    ``n_lists``, as on one device.  A shard's plan drops the pairs of
+    lists it does not own, and the pairs it keeps form the single-device
+    tiles."""
 
     def local(q, cen, data, ids, counts, norms, *rest):
         keep = rest[0] if has_keep else None
@@ -148,6 +156,15 @@ def _ivf_flat_fleet_program(mesh: Mesh, axis: str, k: int, n_probes: int,
         _, probes = jax.lax.top_k(-cd, n_probes)  # pads rank last
         shard = jax.lax.axis_index(axis)
         lo = shard * lp
+        on_grouped = grouped and _ivf.grouped_batch(nq, n_probes, n_lists)
+        _ivf.count_scan_path("grouped" if on_grouped else "query_major")
+        if on_grouped:
+            owned = (probes >= lo) & (probes < lo + lp)
+            bias = _ivf.slot_bias(norms, ids, counts, metric, keep)
+            bv, bi = _scan.scan_topk_grouped(
+                qf, qn, data, bias, ids, jnp.clip(probes - lo, 0, lp - 1), k,
+                l2=metric != "inner_product", pair_valid=owned)
+            return _merge(bv, bi)
         lists_xs, pvalid = blocked_probe_plan(probes, probe_block)
 
         def score(inp):
@@ -180,6 +197,10 @@ def _ivf_flat_fleet_program(mesh: Mesh, axis: str, k: int, n_probes: int,
 
         (bv, bi), _ = jax.lax.scan(step, _scan.topk_carry(nq, k),
                                    (lists_xs, pvalid))
+        return _merge(bv, bi)
+
+    def _merge(bv, bi):
+        nq = bv.shape[0]
         av = jax.lax.all_gather(bv, axis, tiled=False)
         ai = jax.lax.all_gather(bi, axis, tiled=False)
         av = jnp.moveaxis(av, 0, 1).reshape(nq, -1)
@@ -334,7 +355,9 @@ def make_fleet_searcher(index, k: int, params=None, *, mesh: Mesh,
     (the replica server caches them so the degradation ladder's levels
     share device slabs instead of re-slicing per level).
 
-    Fleet fan-out always dispatches the bit-exact ``"xla"`` blocked
+    IVF-Flat fan-out dispatches the grouped scan where
+    ``scan_kernel="auto"`` takes it on one device
+    (``ivf_flat.resolve_scan``), else the bit-exact ``"xla"`` blocked
     scan; ``brute_force`` ``mode="fast"`` is rejected — its approximate
     shortlist cannot be bit-pinned across shard boundaries.
     ``seed`` is accepted for signature parity (no stochastic family is
@@ -388,9 +411,12 @@ def make_fleet_searcher(index, k: int, params=None, *, mesh: Mesh,
         n_probes = int(min(p.n_probes, index.n_lists))
         probe_block = resolve_probe_block(p.probe_block, n_probes,
                                           index.list_cap, "ivf_flat")
+        grouped = _ivf.resolve_scan(p.scan_kernel, index, k, probe_block,
+                                    keep_arr) == "grouped"
         prog = _ivf_flat_fleet_program(mesh, axis, int(k), n_probes,
                                        index.metric, probe_block,
-                                       sl.lists_per, keep_arr is not None)
+                                       sl.lists_per, keep_arr is not None,
+                                       grouped, sl.n_lists)
         ops = (sl.centroids, sl.data, sl.ids, sl.counts, sl.norms)
         if keep_arr is not None:
             kp = jax.device_put(keep_arr, rep)

@@ -155,7 +155,7 @@ def test_fleet_ivf_flat_compiles_for_v5e_2x2(topo):
     rep, sh = NamedSharding(mesh, P()), NamedSharding(mesh, P("shard"))
     lists, cap, d, nq = 1024, 12_208, 96, 64
     fn = _ivf_flat_fleet_program(mesh, "shard", 10, 32, "sqeuclidean", 1,
-                                 lists // 4, False)
+                                 lists // 4, False, False, lists)
     args = (_spec((nq, d), jnp.float32, rep),
             _spec((lists, d), jnp.float32, rep),
             _spec((lists, cap, d), jnp.float32, sh),
@@ -169,3 +169,68 @@ def test_fleet_ivf_flat_compiles_for_v5e_2x2(topo):
         per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                       + mem.temp_size_in_bytes)
         assert per_device < 16e9
+
+
+def test_fleet_ivf_flat_grouped_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The fleet's IVF-Flat fan-out on the grouped scan over the four
+    described chips at the SIFT-1M-class cell's widths: 1024 lists of
+    1960 × 128 f32 rows, 512 queries × 32 probes — each shard lowers
+    ``ivf_grouped_scan`` through Mosaic, then one all-gather merge."""
+    from raft_tpu.ops.pallas import gate
+    from raft_tpu.serve.fleet import _ivf_flat_fleet_program
+
+    monkeypatch.setattr(gate, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("shard",))
+    rep, sh = NamedSharding(mesh, P()), NamedSharding(mesh, P("shard"))
+    lists, cap, d, nq = 1024, 1960, 128, 512
+    fn = _ivf_flat_fleet_program(mesh, "shard", 10, 32, "sqeuclidean", 1,
+                                 lists // 4, False, True, lists)
+    args = (_spec((nq, d), jnp.float32, rep),
+            _spec((lists, d), jnp.float32, rep),
+            _spec((lists, cap, d), jnp.float32, sh),
+            _spec((lists, cap), jnp.int32, sh),
+            _spec((lists,), jnp.int32, sh),
+            _spec((lists, cap), jnp.float32, sh))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "all-gather" in text
+    assert any("tpu_custom_call" in line and "ivf_grouped_scan" in line
+               for line in text.splitlines())
+
+
+@pytest.mark.parametrize("cap,rows", [(1954, 512), (1960, 512), (1960, 1)])
+def test_ivf_flat_grouped_search_compiles_for_one_v5e(one_chip, monkeypatch,
+                                                      cap, rows):
+    """The served IVF-Flat program at the SIFT-1M-class cell's widths:
+    ``rows`` queries × 32 probes over 1024 lists of ``cap`` × 128 f32
+    rows, k = 10.  At 512 rows it takes the grouped (list-major) scan,
+    lowered through Mosaic as ``ivf_grouped_scan``; at 1 row the
+    query-major one.  At 1960, the slab capacity the build stores for
+    1954 rows a list (padded to the f32 sublane tile), the slab is stored
+    list-major and nothing in either program copies it."""
+    from raft_tpu.neighbors import ivf_flat
+    from raft_tpu.ops.pallas import gate
+
+    monkeypatch.setattr(gate, "on_tpu", lambda: True)
+    lists, d = 1024, 128
+    index = ivf_flat.IvfFlatIndex(
+        _spec((lists, d), jnp.float32, one_chip),
+        _spec((lists, cap, d), jnp.float32, one_chip),
+        _spec((lists, cap), jnp.int32, one_chip),
+        _spec((lists,), jnp.int32, one_chip),
+        _spec((lists, cap), jnp.float32, one_chip), "sqeuclidean")
+    fn, ops = ivf_flat.searcher(index, 10, ivf_flat.IvfFlatSearchParams(
+        n_probes=32))
+    text = jax.jit(fn).lower(_spec((rows, d), jnp.float32, one_chip),
+                             *ops).compile().as_text()
+    kernel = [line.lstrip() for line in text.splitlines()
+              if "tpu_custom_call" in line and "ivf_grouped_scan" in line]
+    if ivf_flat.grouped_batch(rows, 32, lists):
+        assert kernel and kernel[0].startswith("%ivf_grouped_scan."), kernel
+    else:
+        assert not kernel, kernel
+    slab = f"f32[{lists},{cap},{d}]"
+    made = [line.split(" = ")[0].strip() for line in text.splitlines()
+            if f"= {slab}" in line and "parameter(" not in line
+            and "get-tuple-element" not in line]
+    if cap % 8 == 0:
+        assert not made, made
