@@ -315,6 +315,31 @@ def _assign_balanced(x, c, counts, penalty, n_per,
     return labels, real
 
 
+#: elements of the largest ``[rows, k]`` f32 distance block the balanced
+#: fit and the capped assignment hold at once (1 GiB).  Above it they
+#: score row tiles in turn: a 1M-row trainset over 4096 lists is a 16 GB
+#: block, more than one 16 GB chip holds beside anything else.
+TILE_ELEMS = 1 << 28
+
+
+def _tile_rows(n: int, k: int) -> int:
+    """Rows of one distance tile, or 0 where the whole block fits."""
+    if n * k <= TILE_ELEMS:
+        return 0
+    return max(8, TILE_ELEMS // k // 8 * 8)
+
+
+def _map_row_tiles(fn, x, rows: int):
+    """``fn(x_tile)`` over ``rows``-row tiles of ``x`` (the last padded),
+    its per-row outputs concatenated back to ``n`` rows."""
+    n = x.shape[0]
+    pad = -n % rows
+    tiles = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, rows, x.shape[1])
+    out = jax.lax.map(fn, tiles)
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:])[:n], out)
+
+
 def _capped_assign_impl(x, centroids, room, valid=None):
     """Shared core of :func:`capped_assign` / :func:`capped_assign_room`:
     ``room`` is a traced per-cluster capacity vector (k,) int32.
@@ -330,10 +355,26 @@ def _capped_assign_impl(x, centroids, room, valid=None):
     """
     n = x.shape[0]
     k = centroids.shape[0]
-    d2 = sq_l2(x, centroids)
     INF = jnp.float32(jnp.inf)
     if valid is None:
         valid = jnp.ones((n,), bool)
+
+    def open_nearest(d2, full):
+        """Each row's nearest cluster with room, and its distance."""
+        cost = jnp.where(full[None, :], INF, d2)
+        cand = jnp.argmin(cost, axis=1).astype(jnp.int32)
+        return cand, jnp.take_along_axis(d2, cand[:, None], 1)[:, 0]
+
+    rows = _tile_rows(n, k)
+    if rows:  # recompute each tile's distances every round
+        def nearest(full):
+            return _map_row_tiles(
+                lambda xt: open_nearest(sq_l2(xt, centroids), full), x, rows)
+    else:
+        d2 = sq_l2(x, centroids)
+
+        def nearest(full):
+            return open_nearest(d2, full)
 
     def pending(labels):
         return jnp.sum(((labels < 0) & valid).astype(jnp.int32))
@@ -347,10 +388,8 @@ def _capped_assign_impl(x, centroids, room, valid=None):
         labels, counts, _ = carry
         prev_left = pending(labels)
         unassigned = (labels < 0) & valid
-        full = counts >= room
-        cost = jnp.where(full[None, :], INF, d2)
-        cand = jnp.argmin(cost, axis=1).astype(jnp.int32)
-        req_d2 = jnp.where(unassigned, jnp.take_along_axis(d2, cand[:, None], 1)[:, 0], INF)
+        cand, cand_d2 = nearest(counts >= room)
+        req_d2 = jnp.where(unassigned, cand_d2, INF)
         rank = _within_group_rank(cand, req_d2, k)
         left_room = (room - counts)[cand]
         accept = unassigned & (rank < left_room)
@@ -408,10 +447,18 @@ def _balanced_fit_impl(x, key, k: int, max_iter: int, penalty: float, cap: int,
     c0 = kmeans_plus_plus_init(key, x, k).astype(jnp.float32)
     counts0 = jnp.zeros((k,), jnp.float32)
 
+    rows = _tile_rows(n, k)
+
+    def assign(c, counts_s):
+        if not rows:
+            return _assign_balanced(x, c, counts_s, penalty, n_per, precision)
+        return _map_row_tiles(
+            lambda xt: _assign_balanced(xt, c, counts_s, penalty, n_per,
+                                        precision), x, rows)
+
     def body(it, carry):
         c, counts_s, _ = carry
-        labels, d2 = _assign_balanced(x, c, counts_s, penalty, n_per,
-                                      precision)
+        labels, d2 = assign(c, counts_s)
         sums, cnts = _update(x, labels, k)
         c2 = _new_centroids(sums, cnts, c)
         # revive genuinely empty clusters (otherwise frozen forever): slot
@@ -435,8 +482,11 @@ def _balanced_fit_impl(x, key, k: int, max_iter: int, penalty: float, cap: int,
     c = _new_centroids(sums, cnts, c)
     # inertia measured against the RETURNED centroids and labels (a stale
     # training-loop value would mislead seed/penalty sweeps)
-    d2_final = sq_l2(x, c)
-    real = jnp.take_along_axis(d2_final, safe[:, None], axis=1)[:, 0]
+    if rows:
+        real = jnp.sum(jnp.square(x.astype(jnp.float32) - c[safe]), axis=1)
+    else:
+        d2_final = sq_l2(x, c)
+        real = jnp.take_along_axis(d2_final, safe[:, None], axis=1)[:, 0]
     inertia = jnp.sum(real * assigned)
     return c.astype(_centroid_dtype(x)), labels, counts, inertia
 

@@ -67,6 +67,7 @@ __all__ = [
     "build_chunked",
     "extend",
     "search",
+    "search_tier",
     "searcher",
     "build_sharded",
     "build_chunked_sharded",
@@ -214,12 +215,6 @@ class IvfPqIndex:
             packed=False)
 
 
-def _split_subspaces(x, m: int):
-    """[n, d] → [n, m, d/m] (d padded to a multiple of m at build)."""
-    n, d = x.shape
-    return x.reshape(n, m, d // m)
-
-
 def _subspace_dots(x, codebooks, precision=None):
     """``⟨x_j, codebooks[j, e]⟩`` for every subspace j and entry e:
     ``[n, m·ds] × [m, c, ds] → [n, m, c]`` as ONE plain product against
@@ -252,27 +247,45 @@ def _first_min(d2):
     return jnp.min(jnp.where(hit, lane, c), axis=-1)
 
 
+@jax.jit
+def _nearest_lists(x, centroids):
+    """Each row's nearest centroid, a row tile at a time where the whole
+    ``[n, L]`` distance block would not fit (``kmeans.TILE_ELEMS``)."""
+    from ..cluster.kmeans import _map_row_tiles, _tile_rows
+
+    def nearest(xt):
+        return _first_min(sq_l2(xt, centroids))
+
+    rows = _tile_rows(x.shape[0], centroids.shape[0])
+    return _map_row_tiles(nearest, x, rows) if rows else nearest(x)
+
+
 @partial(jax.jit, static_argnames=("m", "c", "iters"))
 def _train_codebooks(residuals, key, m: int, c: int, iters: int):
     """Per-subspace Lloyd kmeans over residual slices, every subspace in
     each step at once.  Small trainsets (< codebook size) seed with
     replacement: duplicate seeds merge over the iterations, matching the
     reference's tolerance of n_train < 2^pq_bits."""
-    n = residuals.shape[0]
-    sub = _split_subspaces(residuals, m)  # [n, m, ds]
-    ds = sub.shape[2]
+    n, d = residuals.shape
+    ds = d // m
+    # component t of every subspace, [n, m] each: an [n, m, ds] array
+    # would be stored with its ds-wide minor dimension padded to 128
+    # lanes on the TPU (24.6 GB for a 1M-row trainset at ds = 2)
+    comps = [residuals[:, t::ds] for t in range(ds)]
     keys = jax.random.split(key, m)
     idx = jax.vmap(lambda k: jax.random.choice(k, n, (c,), replace=n < c))(
         keys)                                                     # [m, c]
-    cb0 = sub[idx, jnp.arange(m)[:, None]]                        # [m, c, ds]
-    flat = sub.reshape(n * m, ds)
+    cb0 = jnp.stack([x[idx, jnp.arange(m)[:, None]] for x in comps],
+                    axis=-1)                                      # [m, c, ds]
     base = jnp.arange(m, dtype=jnp.int32) * c
 
     def body(cb, _):
-        d2 = (jnp.sum(cb * cb, axis=2)[None]
-              - 2.0 * _subspace_dots(residuals, cb, jax.lax.Precision.HIGHEST))
-        seg = (_first_min(d2) + base).reshape(-1)                 # [n·m]
-        sums = jax.ops.segment_sum(flat, seg, num_segments=m * c)
+        # the encode's own nearest codewords, _ENCODE_ROWS rows at a time
+        codes, _ = _encode(residuals, cb, m)
+        seg = (codes.astype(jnp.int32) + base).reshape(-1)       # [n·m]
+        sums = jnp.stack([jax.ops.segment_sum(x.reshape(-1), seg,
+                                              num_segments=m * c)
+                          for x in comps], axis=-1)
         counts = jax.ops.segment_sum(jnp.ones_like(seg, jnp.float32), seg,
                                      num_segments=m * c)[:, None]
         newc = jnp.where(counts > 0, sums / jnp.maximum(counts, 1.0),
@@ -318,43 +331,55 @@ def _encode(residuals, codebooks, m: int):
 def _decode_slab(codes, centroids, codebooks, ids):
     """Decode packed codes → bf16 reconstruction slab + exact f32 ‖x̂‖².
 
-    Chunked over lists (lax.map) so the f32 intermediate never exceeds a
-    ~256-list block; pad entries (id < 0) get ‖x̂‖² = +inf so the L2
-    search path masks them for free.
+    A block of ~2^24 f32 elements of lists at a time, each written in
+    place into the one output slab, so the program holds the slab once
+    (the last block starts early and rewrites a few lists with the same
+    values); pad entries (id < 0) get ‖x̂‖² = +inf so the L2 search path
+    masks them for free.  Stacking blocks (``lax.map``) held the slab
+    twice and more: 14 GB for a 10M-row slab of 2.9 GB, compiled for v5e.
     """
     L, cap, mc = codes.shape
     m = codebooks.shape[0]  # logical sub-code count (mc = ceil(m/2) packed)
     d = centroids.shape[1]
     block = max(1, min(L, max(1, (1 << 24) // max(cap * d, 1))))
-    pad = (-L) % block
-    codes_p = jnp.pad(codes, ((0, pad), (0, 0), (0, 0)))
-    cent_p = jnp.pad(centroids, ((0, pad), (0, 0)))
-    ids_p = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
-    sub = jnp.arange(m)
+    c, ds = codebooks.shape[1], codebooks.shape[2]
+    # element e of a decoded row is codebook entry (e // ds, code, e % ds):
+    # one scalar gather a row element, as [.., d] and never [.., m, ds],
+    # whose ds-wide minor dimension the TPU pads to 128 lanes
+    flat_cb = codebooks.reshape(-1)
+    sub = jnp.arange(d) // ds
+    lane = jnp.arange(d) % ds
 
-    def decode_block(args):
-        cb_codes, cb_cent, cb_ids = args
+    def decode_block(i, out):
+        rec_out, norms_out = out
+        lo = jnp.minimum(i * block, L - block)
+        cb_codes = jax.lax.dynamic_slice_in_dim(codes, lo, block)
+        cb_cent = jax.lax.dynamic_slice_in_dim(centroids, lo, block)
+        cb_ids = jax.lax.dynamic_slice_in_dim(ids, lo, block)
         if mc != m:  # 4-bit packed: unpack one block at a time
             cb_codes = _unpack_codes4(cb_codes, m)
-        g = codebooks[sub[None, None, :], cb_codes.astype(jnp.int32)]
-        rec = (g.reshape(cb_codes.shape[0], cap, d).astype(jnp.float32)
+        code = cb_codes.astype(jnp.int32)[:, :, sub]      # [block, cap, d]
+        g = flat_cb[(sub * c + code) * ds + lane]
+        rec = (g.astype(jnp.float32)
                + cb_cent[:, None, :].astype(jnp.float32))
-        rec_b = rec.astype(jnp.bfloat16)
         # norms of the *rounded* slab: the search dot sees bf16 x̂, so a
         # consistent ‖x̂‖² makes the score the exact distance to the stored
-        # point (an inconsistent f32 norm injects rank noise ~2ε‖q‖‖x̂‖)
-        rec_f = rec_b.astype(jnp.float32)
+        # point (an inconsistent f32 norm injects rank noise ~2ε‖q‖‖x̂‖).
+        # The rounding is reduce_precision, which the compiler keeps: the
+        # TPU's dropped an f32 → bf16 → f32 round trip here as excess
+        # precision, and its norms were of the unrounded x̂ (on a v5e up
+        # to 0.24% off the slab's own, against 2e-7 on the CPU)
+        rec_f = jax.lax.reduce_precision(rec, exponent_bits=8,
+                                         mantissa_bits=7)
         norms = jnp.sum(rec_f * rec_f, axis=2)
         norms = jnp.where(cb_ids >= 0, norms, jnp.inf)
-        return rec_b, norms
+        return (jax.lax.dynamic_update_slice_in_dim(
+                    rec_out, rec_f.astype(jnp.bfloat16), lo, 0),
+                jax.lax.dynamic_update_slice_in_dim(norms_out, norms, lo, 0))
 
-    rec, norms = jax.lax.map(
-        decode_block,
-        (codes_p.reshape(-1, block, cap, mc),
-         cent_p.reshape(-1, block, d),
-         ids_p.reshape(-1, block, cap)),
-    )
-    return (rec.reshape(-1, cap, d)[:L], norms.reshape(-1, cap)[:L])
+    out = (jnp.zeros((L, cap, d), jnp.bfloat16),
+           jnp.zeros((L, cap), jnp.float32))
+    return jax.lax.fori_loop(0, -(-L // block), decode_block, out)
 
 
 @jax.jit
@@ -395,6 +420,44 @@ def _adc_tables(codes, centroids, codebooks, code_norms):
     return clut, anorms.reshape(-1, cap)[:L]
 
 
+def search_tier(index: IvfPqIndex, params: IvfPqSearchParams) -> str:
+    """The tier a search runs: ``params.mode``, with ``"auto"`` the recon
+    tier where the slab is materialized and the LUT tier otherwise."""
+    if params.mode != "auto":
+        return params.mode
+    return "recon" if index.recon is not None else "lut"
+
+
+def count_search(tier: str, refine: bool) -> None:
+    """Count one compiled serving program in
+    ``raft_ivf_pq_search_total{tier,refine}`` (called from the program's
+    trace, which runs once per compiled program)."""
+    from ..obs.metrics import registry
+
+    registry().counter(
+        "raft_ivf_pq_search_total",
+        "IVF-PQ serving programs compiled, by tier and exact re-rank",
+    ).inc(tier=tier, refine="1" if refine else "0")
+
+
+def _stage(name: str, fn, *args, **kwargs):
+    """One build stage as the ``tracing`` range ``ivf_pq.build:<name>``,
+    its seconds (to its results being ready on the device) in the gauge
+    ``raft_index_build_seconds{family="ivf_pq",stage}``."""
+    import time
+
+    from ..obs.metrics import registry
+
+    t = time.monotonic()
+    with tracing.range("ivf_pq.build:%s", name):
+        out = jax.block_until_ready(fn(*args, **kwargs))  # jaxlint: disable=JX05 a stage's seconds end when its results are ready; a build is not on the dispatch path
+    registry().gauge(
+        "raft_index_build_seconds",
+        "seconds of the last index build's stages",
+    ).set(time.monotonic() - t, family="ivf_pq", stage=name)
+    return out
+
+
 # 4-bit code packing moved to the quantized-scan sub-API (shared with the
 # 1-bit RaBitQ codes); these aliases keep the historical private names
 from ..ops.blocked_scan import (  # noqa: E402
@@ -417,34 +480,48 @@ def build(dataset, params: Optional[IvfPqIndexParams] = None, *,
     c = 1 << p.pq_bits
     cap = max(1, int(np.ceil(p.list_cap_ratio * n / p.n_lists)))
 
-    # coarse quantizer (shared shape with IVF-Flat build)
-    n_train = min(n, max(p.n_lists * 4, int(n * p.kmeans_trainset_fraction)))
     key = jax.random.PRNGKey(p.seed)
-    sel = (jax.random.permutation(key, n)[:n_train] if n_train < n
-           else jnp.arange(n))
-    kp = KMeansParams(n_clusters=p.n_lists, max_iter=p.kmeans_n_iters, seed=p.seed)
-    centroids, _, _ = kmeans_balanced_fit(x[sel], kp)
-    labels, _ = capped_assign(x, centroids, cap)
 
-    # PQ codebooks on training residuals
-    res_train = x[sel] - centroids[jnp.argmin(sq_l2(x[sel], centroids), axis=1)]
-    codebooks = _train_codebooks(res_train, jax.random.fold_in(key, 7), m, c,
-                                 p.pq_kmeans_n_iters)
+    def train():
+        # coarse quantizer (shared shape with IVF-Flat build)
+        n_train = min(n, max(p.n_lists * 4,
+                             int(n * p.kmeans_trainset_fraction)))
+        sel = (jax.random.permutation(key, n)[:n_train] if n_train < n
+               else jnp.arange(n))
+        kp = KMeansParams(n_clusters=p.n_lists, max_iter=p.kmeans_n_iters,
+                          seed=p.seed)
+        centroids, _, _ = kmeans_balanced_fit(x[sel], kp)
+        # PQ codebooks on training residuals
+        res_train = x[sel] - centroids[_nearest_lists(x[sel], centroids)]
+        return centroids, _train_codebooks(
+            res_train, jax.random.fold_in(key, 7), m, c, p.pq_kmeans_n_iters)
+
+    centroids, codebooks = _stage("train", train)
+    labels, _ = _stage("assign", capped_assign, x, centroids, cap)
 
     # encode the full dataset against its assigned centroid
-    residuals = x - centroids[jnp.clip(labels, 0, p.n_lists - 1)]
-    codes, cnorms = _encode(residuals, codebooks, m)
+    def encode():
+        residuals = x - centroids[jnp.clip(labels, 0, p.n_lists - 1)]
+        return _encode(residuals, codebooks, m)
+
+    codes, cnorms = _stage("encode", encode)
 
     # pack lists on device (jitted sort+scatter)
     ids = (jnp.asarray(source_ids, jnp.int32) if source_ids is not None
            else jnp.arange(n, dtype=jnp.int32))
-    (pk_codes, pk_norms, pk_ids), counts = pack_lists(
-        labels, (codes, cnorms, ids),
+    (pk_codes, pk_norms, pk_ids), counts = _stage(
+        "pack", pack_lists, labels, (codes, cnorms, ids),
         n_lists=p.n_lists, cap=cap, fills=(0, 0.0, -1))
 
     index = IvfPqIndex(centroids, codebooks, pk_codes, pk_norms, pk_ids,
                        counts, p.metric)
-    index = index.with_adc_luts()  # hoisted-ADC tables, while codes are unpacked
+    return _stage("decode", _derived_tiers, index, p)
+
+
+def _derived_tiers(index: IvfPqIndex, p: IvfPqIndexParams) -> IvfPqIndex:
+    """The hoisted-ADC tables (while the codes are unpacked), the recon
+    slab if ``p.store_recon``, then the 4-bit packing if asked for."""
+    index = index.with_adc_luts()
     index = index.with_recon() if p.store_recon else index
     return index.with_packed_codes() if p.pack_codes else index
 
@@ -541,7 +618,7 @@ def _pq_train_chunked(dataset, p: IvfPqIndexParams, n: int, m: int, c: int):
     kp = KMeansParams(n_clusters=p.n_lists, max_iter=p.kmeans_n_iters,
                       seed=p.seed)
     centroids, _, _ = kmeans_balanced_fit(xt, kp)
-    res_train = xt - centroids[jnp.argmin(sq_l2(xt, centroids), axis=1)]
+    res_train = xt - centroids[_nearest_lists(xt, centroids)]
     key = jax.random.PRNGKey(p.seed)
     codebooks = _train_codebooks(res_train, jax.random.fold_in(key, 7), m, c,
                                  p.pq_kmeans_n_iters)
@@ -670,16 +747,17 @@ def build_chunked(dataset, params: Optional[IvfPqIndexParams] = None, *,
     cap = max(1, int(np.ceil(p.list_cap_ratio * n / p.n_lists)))
     chunk_rows = resolve_chunk_rows(chunk_rows, n, d, "ivf_pq")
 
-    centroids, codebooks = _pq_train_chunked(dataset, p, n, m, c)
-    codes, cnorms, ids_slab, counts = _pq_stream_pipelined(
-        dataset, centroids, codebooks, p, n, m, cap, chunk_rows, source_ids,
+    centroids, codebooks = _stage("train", _pq_train_chunked, dataset, p,
+                                  n, m, c)
+    # assign, encode and pack run fused, one program a chunk
+    codes, cnorms, ids_slab, counts = _stage(
+        "stream", _pq_stream_pipelined, dataset, centroids, codebooks, p, n,
+        m, cap, chunk_rows, source_ids,
         heartbeat=build_heartbeat("ivf_pq.build_chunked", n))
 
     index = IvfPqIndex(centroids, codebooks, codes, cnorms, ids_slab,
                        counts, p.metric)
-    index = index.with_adc_luts()  # hoisted-ADC tables, while codes are unpacked
-    index = index.with_recon() if p.store_recon else index
-    return index.with_packed_codes() if p.pack_codes else index
+    return _stage("decode", _derived_tiers, index, p)
 
 
 def _build_chunked_perop(dataset, params: Optional[IvfPqIndexParams] = None,
@@ -705,9 +783,7 @@ def _build_chunked_perop(dataset, params: Optional[IvfPqIndexParams] = None,
         dataset, centroids, codebooks, p, n, m, cap, chunk_rows, source_ids)
     index = IvfPqIndex(centroids, codebooks, codes, cnorms, ids_slab,
                        counts, p.metric)
-    index = index.with_adc_luts()
-    index = index.with_recon() if p.store_recon else index
-    return index.with_packed_codes() if p.pack_codes else index
+    return _derived_tiers(index, p)
 
 
 # ---------------------------------------------------------------------------
@@ -888,9 +964,7 @@ def search(index: IvfPqIndex, queries, k: int,
     keep = as_keep_mask(filter, nq=q.shape[0])  # indexes source ids
     if keep is not None:
         check_filter_covers_ids(keep, index.ids)
-    mode = p.mode
-    if mode == "auto":
-        mode = "recon" if index.recon is not None else "lut"
+    mode = search_tier(index, p)
     if mode == "recon":
         expects(index.recon is not None,
                 "mode='recon' needs the reconstruction slab — call "
@@ -949,9 +1023,7 @@ def searcher(index: IvfPqIndex, k: int,
                 "serving filters are shared bitsets (1-D); per-query "
                 "bitmaps can't ride a fixed operand across buckets")
         check_filter_covers_ids(keep, index.ids)
-    mode = p.mode
-    if mode == "auto":
-        mode = "recon" if index.recon is not None else "lut"
+    mode = search_tier(index, p)
     if mode == "recon":
         expects(index.recon is not None,
                 "mode='recon' needs the reconstruction slab — call "
@@ -1378,9 +1450,7 @@ def search_sharded(index: IvfPqIndex, queries, k: int,
     keep = as_keep_mask(filter, nq=q.shape[0])
     if keep is not None:
         check_filter_covers_ids(keep, index.ids)
-    mode = p.mode
-    if mode == "auto":
-        mode = "recon" if index.recon is not None else "lut"
+    mode = search_tier(index, p)
     if mode == "recon":
         expects(index.recon is not None,
                 "mode='recon' needs the reconstruction slab — call "

@@ -9,8 +9,9 @@ most of the recall PQ compression loses.
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
-from typing import Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,37 @@ from ..core.array import wrap_array
 from ..core.errors import expects
 from ..matrix.select_k import select_k
 
-__all__ = ["refine"]
+__all__ = ["Refined", "refine", "refined_searcher"]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Refined:
+    """An index served with exact re-ranking: the family's search runs at
+    ``k·ratio`` candidates and :func:`refine` re-ranks them over
+    ``dataset`` in the same program.
+
+    The index's stored ids must be row numbers of ``dataset`` (the
+    default ids of every IVF build).  ``index`` may be a
+    ``mutation.Tombstoned`` view, whose filter still holds.  ``dataset``
+    rides as one more searcher operand and is never copied.  A pytree,
+    like ``Tombstoned``; ``ratio`` is static."""
+
+    index: Any
+    dataset: jax.Array
+    ratio: int = dataclasses.field(metadata=dict(static=True))
+
+    def __post_init__(self):
+        expects(isinstance(self.ratio, int) and self.ratio >= 1,
+                f"refine ratio must be an int >= 1, got {self.ratio!r}")
+
+    @property
+    def dim(self) -> int:
+        return int(self.dataset.shape[1])
+
+    @property
+    def size(self) -> int:
+        return int(self.index.size)
 
 
 @partial(jax.jit, static_argnames=("k", "metric"))
@@ -58,3 +89,16 @@ def refine(dataset, queries, candidates, k: int, *,
     expects(c.ndim == 2 and c.shape[0] == q.shape[0], "candidates shape mismatch")
     expects(k <= c.shape[1], "k exceeds candidate count")
     return _refine_impl(d, q, c, int(k), metric)
+
+
+def refined_searcher(fn, operands, dataset, k: int, metric: str):
+    """Wrap a family searcher ``(fn, operands)`` that returns ``k·ratio``
+    candidates into one that re-ranks them exactly over ``dataset``:
+    ``(fn', (dataset, *operands))``, the ``raft_tpu.serve`` contract,
+    equal to the family's search followed by :func:`refine`."""
+
+    def refined(q, data, *ops):
+        _, cand = fn(q, *ops)
+        return _refine_impl(data, q, cand, int(k), metric)
+
+    return refined, (dataset,) + tuple(operands)

@@ -50,7 +50,10 @@ def reference_points(index, m: int = 256, seed: int = 0):
     import jax
 
     from ..neighbors.mutation import Tombstoned
+    from ..neighbors.refine import Refined
 
+    if isinstance(index, Refined):
+        index = index.index
     if isinstance(index, Tombstoned):
         index = index.index
     if hasattr(index, "centroids"):                    # ivf_flat / ivf_pq

@@ -136,15 +136,23 @@ def oracle_database(index):
     * ``cagra`` — the dataset, ids = row numbers;
     * ``mutation.Tombstoned`` — the wrapped index's corpus with deleted
       source ids removed (a tombstoned id must never count as a miss
-      against results that correctly exclude it).
+      against results that correctly exclude it);
+    * ``refine.Refined`` — its dataset, ids = row numbers (the re-rank is
+      exact over it), less the ids a ``Tombstoned`` index inside deletes.
     """
     import numpy as np
 
     import jax
 
     from ..neighbors.mutation import Tombstoned
+    from ..neighbors.refine import Refined
 
     keep = None
+    if isinstance(index, Refined):
+        inner = index.index
+        index = index.dataset
+        if isinstance(inner, Tombstoned):
+            index = Tombstoned(index, inner.keep)
     if isinstance(index, Tombstoned):
         keep = np.asarray(jax.device_get(index.keep.to_bool_array()))  # jaxlint: disable=JX01 one-time oracle corpus extraction, off the hot path
         index = index.index

@@ -366,6 +366,7 @@ def make_fleet_searcher(index, k: int, params=None, *, mesh: Mesh,
     expects(0.0 < effort_scale <= 1.0,
             f"effort_scale must be in (0, 1], got {effort_scale}")
     expects(axis in mesh.axis_names, f"axis {axis!r} not in mesh")
+    _refuse_refined(index)
     index, keep = unwrap_tombstones(index)
     if keep is not None and filter is not None:
         from ..neighbors.mutation import _combined_keep
@@ -464,10 +465,20 @@ def make_fleet_searcher(index, k: int, params=None, *, mesh: Mesh,
         "cagra fan-out)")
 
 
+def _refuse_refined(index) -> None:
+    from ..neighbors.refine import Refined
+
+    expects(not isinstance(index, Refined),
+            "the fleet serves no refine.Refined view: exact re-ranking is "
+            "one-device only (fleet IVF-PQ is not built yet); serve it "
+            "through SearchServer")
+
+
 def _fleet_slices_for(index, mesh: Mesh, axis: str):
     """Family-dispatched ``fleet_slices`` for a (possibly Tombstoned)
     index view — the brute family folds the tombstone mask into its
     sharded validity mask; the IVF families carry it replicated."""
+    _refuse_refined(index)
     base, keep = unwrap_tombstones(index)
     fam = family_of(base)
     if fam == "brute_force":

@@ -15,6 +15,12 @@ and (b) the per-family *effort knob* a degradation level shrinks:
 
 Scaled knobs are floored so a degraded searcher still returns k valid
 results (``n_probes >= 1``, ``itopk >= k``, ``cand >= k``).
+
+Two views wrap an index and serve transparently: ``mutation.Tombstoned``
+(its keep-mask becomes the searcher's prefilter) and ``refine.Refined``
+(the family searches ``k·ratio`` candidates and the same program
+re-ranks them exactly over the view's dataset).  A ``Refined`` view may
+hold a ``Tombstoned`` one, never the other way round.
 """
 
 from __future__ import annotations
@@ -28,7 +34,7 @@ from ..core.errors import expects
 
 __all__ = ["BruteForceSearchParams", "family_of", "make_searcher",
            "index_dim", "index_size", "query_dtype_of",
-           "unwrap_tombstones"]
+           "unwrap_refined", "unwrap_tombstones"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,14 +51,31 @@ class BruteForceSearchParams:
     refine_precision: str = "highest"
 
 
+def unwrap_refined(index):
+    """Split a ``refine.Refined`` view into ``(index, dataset, ratio)``
+    — ``(index, None, 1)`` for anything else."""
+    from ..neighbors.refine import Refined
+
+    if isinstance(index, Refined):
+        return index.index, index.dataset, index.ratio
+    return index, None, 1
+
+
 def unwrap_tombstones(index):
     """Split a ``mutation.Tombstoned`` view into ``(index, keep_bitset)``
     — ``(index, None)`` for a plain index.  The serve layer does this at
     every entry point so tombstoned views serve transparently (the mask
-    becomes the searcher's shared prefilter operand)."""
+    becomes the searcher's shared prefilter operand).  A
+    ``refine.Refined`` view around it is looked through."""
     from ..neighbors.mutation import Tombstoned
 
+    from ..neighbors.refine import Refined
+
+    index, _, _ = unwrap_refined(index)
     if isinstance(index, Tombstoned):
+        expects(not isinstance(index.index, Refined),
+                "a Tombstoned view cannot hold a Refined one: wrap it as "
+                "Refined(Tombstoned(index), dataset, ratio)")
         return index.index, index.keep
     return index, None
 
@@ -85,6 +108,9 @@ def family_of(index) -> str:
 
 
 def index_dim(index) -> int:
+    _, dataset, _ = unwrap_refined(index)
+    if dataset is not None:
+        return int(dataset.shape[1])
     index, _ = unwrap_tombstones(index)
     return int(index.shape[1]) if family_of(index) == "brute_force" \
         else int(index.dim)
@@ -99,7 +125,8 @@ def index_size(index) -> int:
 def query_dtype_of(index):
     """The dtype warm-up should precompile for — the dtype the stored
     vectors expect queries in (requests with another dtype compile their
-    own bucket set on first use)."""
+    own bucket set on first use).  A ``Refined`` view takes queries in
+    the dtype of its family's index."""
     index, _ = unwrap_tombstones(index)
     fam = family_of(index)
     if fam == "brute_force":
@@ -130,9 +157,29 @@ def make_searcher(index, k: int, params=None, *, effort_scale: float = 1.0,
     A ``mutation.Tombstoned`` view is unwrapped here: its keep-mask
     becomes the family searcher's shared ``filter=`` operand (deleted
     ids report as −1/±inf sentinels, never as results), composed with an
-    explicit ``filter`` by AND when both are present."""
+    explicit ``filter`` by AND when both are present.
+
+    A ``refine.Refined`` view is unwrapped first: the family's searcher
+    runs at ``k·ratio`` candidates and ``refine``'s re-rank follows in the
+    same program, with the view's dataset as one more operand.
+    ``effort_scale`` still scales only the family's knob."""
     expects(0.0 < effort_scale <= 1.0,
             f"effort_scale must be in (0, 1], got {effort_scale}")
+    index, dataset, ratio = unwrap_refined(index)
+    if dataset is not None:
+        from ..neighbors.refine import refined_searcher
+
+        fn, operands = _family_searcher(index, k * ratio, params,
+                                        effort_scale, seed, filter,
+                                        refine=True)
+        metric = getattr(unwrap_tombstones(index)[0], "metric",
+                         "sqeuclidean")
+        return refined_searcher(fn, operands, dataset, k, metric)
+    return _family_searcher(index, k, params, effort_scale, seed, filter)
+
+
+def _family_searcher(index, k: int, params, effort_scale: float, seed: int,
+                     filter, refine: bool = False):
     index, keep = unwrap_tombstones(index)
     if keep is not None and filter is not None:
         from ..neighbors.mutation import _combined_keep
@@ -167,7 +214,14 @@ def make_searcher(index, k: int, params=None, *, effort_scale: float = 1.0,
             p = dataclasses.replace(
                 p, n_probes=_scaled(min(p.n_probes, index.n_lists),
                                     effort_scale, 1))
-        return ivf_pq.searcher(index, k, p, filter=filter)
+        fn, operands = ivf_pq.searcher(index, k, p, filter=filter)
+        tier = ivf_pq.search_tier(index, p)
+
+        def counted(q, *ops):
+            ivf_pq.count_search(tier, refine)
+            return fn(q, *ops)
+
+        return counted, operands
     if fam == "ivf_rabitq":
         from ..neighbors import ivf_rabitq
 

@@ -23,7 +23,11 @@ def within_group_rank(groups, scores, k: int):
     edge builder (:mod:`raft_tpu.neighbors.cagra`).
     """
     n = groups.shape[0]
-    perm = jnp.lexsort((scores, groups))
+    # lexsort((scores, groups))'s order as two stable one-key sorts, the
+    # minor key first: the TPU compiler takes ~200 s over the two-key
+    # sort at 65,536 rows and ~23 s over a one-key one (compiled for v5e)
+    by_score = jnp.argsort(scores, stable=True)
+    perm = by_score[jnp.argsort(groups[by_score], stable=True)]
     counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), groups,
                                  num_segments=k)
     starts = jnp.cumsum(counts) - counts

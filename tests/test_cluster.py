@@ -158,3 +158,24 @@ def test_kmeans_uniform_small_weights_match_unweighted():
     np.testing.assert_allclose(np.asarray(c1), np.asarray(c0), rtol=1e-4,
                                atol=1e-5)
     np.testing.assert_allclose(float(i1), 0.01 * float(i0), rtol=1e-4)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.int32])
+def test_within_group_rank_is_the_lexsort_rank(dtype):
+    """Ranks within each group by ascending score, ties by position: the
+    same as a stable two-key lexsort, with ties, +inf and empty groups."""
+    import jax.numpy as jnp
+
+    from raft_tpu.utils.segment import within_group_rank
+
+    rs = np.random.default_rng(5)
+    groups = rs.integers(0, 40, 3000).astype(np.int32)
+    scores = rs.integers(0, 50, 3000).astype(dtype)
+    if dtype == np.float32:
+        scores[rs.random(3000) < 0.1] = np.inf
+    perm = np.lexsort((scores, groups))
+    starts = np.searchsorted(groups[perm], np.arange(41))
+    want = np.empty(3000, np.int32)
+    want[perm] = np.arange(3000) - starts[groups[perm]]
+    got = within_group_rank(jnp.asarray(groups), jnp.asarray(scores), 41)
+    np.testing.assert_array_equal(np.asarray(got), want)

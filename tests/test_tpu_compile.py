@@ -234,3 +234,79 @@ def test_ivf_flat_grouped_search_compiles_for_one_v5e(one_chip, monkeypatch,
             and "get-tuple-element" not in line]
     if cap % 8 == 0:
         assert not made, made
+
+
+def _deep10m_ivf_pq():
+    """The DEEP-10M-class IVF-PQ deployment of the benchmark's
+    ``deep10m-ivf_pq`` configuration."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "deep10m-ivf_pq.json")) as f:
+        return json.load(f)
+
+
+def test_ivf_pq_decode_fits_one_v5e(one_chip):
+    """The build's decode step at DEEP-10M-class size: 4096 lists of 3663
+    slots, 48 sub-codes a row, into the 2.9 GB bf16 slab.  Stacking the
+    decoded blocks held 14 GB, more than fits beside the 3.84 GB base."""
+    from raft_tpu.neighbors import ivf_pq
+
+    cfg = _deep10m_ivf_pq()
+    n, d = cfg["data"]["rows"], cfg["data"]["dim"]
+    lists, m = cfg["index"]["n_lists"], cfg["index"]["pq_dim"]
+    cap = int(np.ceil(ivf_pq.IvfPqIndexParams().list_cap_ratio * n / lists))
+    compiled = ivf_pq._decode_slab.lower(
+        _spec((lists, cap, m), jnp.uint8, one_chip),
+        _spec((lists, d), jnp.float32, one_chip),
+        _spec((m, 256, d // m), jnp.float32, one_chip),
+        _spec((lists, cap), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) + 4 * n * d < 16e9
+
+
+def test_served_ivf_pq_with_refine_fits_one_v5e(one_chip, monkeypatch):
+    """The served 512-row program of the ``deep10m-ivf_pq.batch`` cell:
+    the recon-tier PQ scan at 40 candidates and their exact re-rank over
+    the 10M × 96 f32 base, in one program, under one chip's 16 GB with
+    the index and the base as its operands."""
+    from raft_tpu.neighbors import ivf_pq
+    from raft_tpu.neighbors.refine import Refined
+    from raft_tpu.neighbors import _packing
+    from raft_tpu.ops import blocked_scan
+    from raft_tpu.ops.pallas import gate
+    from raft_tpu.serve import make_searcher
+
+    monkeypatch.setattr(gate, "on_tpu", lambda: True)
+    monkeypatch.setattr(_packing, "_probe_block_table", lambda: {})
+    monkeypatch.setattr(_packing, "_probe_block_cache", {})
+    monkeypatch.setattr(blocked_scan, "_scan_kernel_table", lambda: {})
+    cfg = _deep10m_ivf_pq()
+    n, d, k = (cfg["data"][key] for key in ("rows", "dim", "k"))
+    lists, m = cfg["index"]["n_lists"], cfg["index"]["pq_dim"]
+    cap = int(np.ceil(ivf_pq.IvfPqIndexParams().list_cap_ratio * n / lists))
+
+    def s(shape, dtype):
+        return _spec(shape, dtype, one_chip)
+
+    index = ivf_pq.IvfPqIndex(
+        s((lists, d), jnp.float32), s((m, 256, d // m), jnp.float32),
+        s((lists, cap, m), jnp.uint8), s((lists, cap), jnp.float32),
+        s((lists, cap), jnp.int32), s((lists,), jnp.int32), "sqeuclidean",
+        recon=s((lists, cap, d), jnp.bfloat16),
+        recon_norms=s((lists, cap), jnp.float32),
+        centroid_lut=s((lists, m, 256), jnp.float32),
+        adc_norms=s((lists, cap), jnp.float32))
+    view = Refined(index, s((n, d), jnp.float32), cfg["refine_ratio"])
+    fn, ops = make_searcher(view, k, ivf_pq.IvfPqSearchParams(
+        **cfg["search"]))
+    compiled = jax.jit(fn).lower(s((512, d), jnp.float32), *ops).compile()
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes)
+        # every operand is an argument: the base, the slabs, the tables
+        assert 7.5e9 < total < 16e9, total
