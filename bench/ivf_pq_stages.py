@@ -101,7 +101,7 @@ def stage_checks(index, base, rows, seed):
 
     xhat, _ = ref.decode(codes, lst, cent, cb)
     slab = np.asarray(index.recon[jnp.asarray(lst), jnp.asarray(slt)],
-                      np.float64)
+                      np.float64)[:, :index.dim]
     half_ulp = np.maximum(np.abs(xhat), 1e-30) * 2.0 ** -8
     gap = np.abs(slab - xhat) / half_ulp
     norms = np.asarray(index.recon_norms[jnp.asarray(lst), jnp.asarray(slt)],
@@ -135,8 +135,8 @@ def scan_check(index, queries, k_cand, params, li, sl):
         cand = ids[lists].reshape(-1)
         live = cand >= 0
         cand = cand[live]
-        slab = np.asarray(index.recon[jnp.asarray(lists)],
-                          np.float64).reshape(-1, q.shape[1])[live]
+        slab = np.asarray(index.recon[jnp.asarray(lists)], np.float64)[
+            ..., :q.shape[1]].reshape(-1, q.shape[1])[live]
         d = ((slab - q[r]) ** 2).sum(1)
         top = set(cand[np.argsort(d, kind="stable")[:k_cand]].tolist())
         got = di[r][di[r] >= 0]

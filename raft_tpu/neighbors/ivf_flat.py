@@ -444,12 +444,13 @@ GROUPED_MAX_K = 128
 def grouped_takes(cap: int, dim: int, k: int, dtype,
                   keep_ndim: int = 0) -> bool:
     """Whether the grouped kernel takes a search over an index's
-    ``[L, cap, dim]`` slab of ``dtype``: f32 slabs whose lists fit its
-    VMEM block, k ≤ ``GROUPED_MAX_K``, and no per-query filter bitmap
-    (``keep_ndim`` 2)."""
-    return not (jnp.dtype(dtype) != jnp.float32 or keep_ndim == 2
+    ``[L, cap, dim]`` slab of ``dtype``: f32 or bf16 slabs whose lists
+    fit its VMEM block at their own itemsize, k ≤ ``GROUPED_MAX_K``, and
+    no per-query filter bitmap (``keep_ndim`` 2)."""
+    dt = jnp.dtype(dtype)
+    return not (dt not in (jnp.float32, jnp.bfloat16) or keep_ndim == 2
                 or k > GROUPED_MAX_K
-                or cap * dim * 4 > GROUPED_MAX_LIST_BYTES)
+                or cap * dim * dt.itemsize > GROUPED_MAX_LIST_BYTES)
 
 
 def grouped_batch(nq: int, n_probes: int, n_lists: int) -> bool:
@@ -463,25 +464,38 @@ def grouped_batch(nq: int, n_probes: int, n_lists: int) -> bool:
 
 def resolve_scan(requested: str, index: IvfFlatIndex, k: int,
                  probe_block: int, keep=None) -> str:
-    """The probe scan a search over ``index`` runs.  ``"auto"`` takes the
-    grouped scan on a TPU where :func:`grouped_takes` allows it, else
-    what ``ops.blocked_scan.resolve_scan_kernel`` picks.  Off a TPU the
-    kernel runs interpreted, slower than the XLA gather, so there the
-    grouped scan runs only when asked for by name.  ``"grouped"``,
-    ``"xla"`` and ``"fused"`` as given.  A ``"grouped"`` program scans a
-    batch too small for :func:`grouped_batch` query-major."""
+    """The probe scan a search over ``index`` runs (:func:`pick_scan`).
+    IVF-Flat scores f32 queries at ``precision=HIGHEST``, so only its f32
+    slabs take the grouped kernel."""
+    takes = (index.data.dtype == jnp.float32
+             and grouped_takes(index.list_cap, index.dim, int(k),
+                               index.data.dtype,
+                               0 if keep is None else keep.ndim))
+    return pick_scan(requested, "ivf_flat", takes,
+                     probe_block * index.list_cap, k)
+
+
+def pick_scan(requested: str, family: str, takes: bool, n_candidates: int,
+              k: int) -> str:
+    """The probe scan of an IVF ``family``'s search.  ``"auto"`` takes the
+    grouped scan on a TPU where the kernel ``takes`` the index
+    (:func:`grouped_takes`), else what
+    ``ops.blocked_scan.resolve_scan_kernel`` picks for ``n_candidates`` a
+    query-major block.  Off a TPU the kernel runs interpreted, slower
+    than the XLA gather, so there the grouped scan runs only when asked
+    for by name.  ``"grouped"``, ``"xla"`` and ``"fused"`` as given.  A
+    ``"grouped"`` program scans a batch too small for
+    :func:`grouped_batch` query-major."""
     from ..ops.blocked_scan import resolve_scan_kernel
     from ..ops.pallas.gate import on_tpu
 
-    takes = grouped_takes(index.list_cap, index.dim, int(k),
-                          index.data.dtype, 0 if keep is None else keep.ndim)
     if requested == "grouped":
-        expects(takes, "the grouped scan takes f32 lists of at most "
+        expects(takes, "the grouped scan takes IVF-Flat's f32 lists or the "
+                f"IVF-PQ recon tier's bf16 lists of at most "
                 f"{GROUPED_MAX_LIST_BYTES} bytes, k <= {GROUPED_MAX_K} and "
                 "no per-query bitmap")
         return "grouped"
-    kernel = resolve_scan_kernel(requested, "ivf_flat",
-                                 probe_block * index.list_cap, int(k))
+    kernel = resolve_scan_kernel(requested, family, n_candidates, int(k))
     return "grouped" if requested == "auto" and takes and on_tpu() else kernel
 
 
@@ -501,12 +515,14 @@ def count_scan_path(path: str) -> None:
 def slot_bias(norms, ids, counts, metric: str, keep=None):
     """``[L, 1, cap]`` per-slot offset of the grouped scan: the stored
     squared norm (L2 metrics) or 0 (inner product) where the slot holds
-    a live row (below ``counts``, id ≥ 0, kept by ``keep``), ``+inf``
-    where it does not — the masks of the query-major scan."""
+    a live row (below ``counts`` where given, id ≥ 0, kept by ``keep``),
+    ``+inf`` where it does not — the masks of the query-major scan."""
     from ._packing import keep_lookup
 
     cap = ids.shape[1]
-    live = (jnp.arange(cap)[None, :] < counts[:, None]) & (ids >= 0)
+    live = ids >= 0
+    if counts is not None:
+        live = live & (jnp.arange(cap)[None, :] < counts[:, None])
     if keep is not None:
         live = live & keep_lookup(keep, ids)
     base = (jnp.zeros(norms.shape, jnp.float32) if metric == "inner_product"

@@ -10,12 +10,18 @@ configs (``BASELINE.json``: ivf_pq on DEEP-10M) and standard IVF-PQ
 
   - ``mode="recon"`` (default): at build time the codes are decoded once
     into a bf16 *reconstruction slab* ``[n_lists, cap, d]`` (x̂ = c + r̂,
-    with exact f32 ‖x̂‖² kept separately).  Search gathers each probed
-    list's slab and scores it with one batched MXU dot —
-    ``‖q−x̂‖² = ‖q‖² − 2⟨q,x̂⟩ + ‖x̂‖²`` — so the hot loop is a dense
-    bf16 contraction, the shape TPUs are built for.  The slab is
-    *derived* state: it is rebuilt from the codes on load and never
-    serialized, so the persisted index stays PQ-compressed.
+    rows zero-padded to whole lanes, :func:`recon_lanes`; exact f32 ‖x̂‖²
+    kept separately).  Search scores the probed lists with one bf16 MXU
+    pass — ``‖q−x̂‖² = ‖q‖² − 2⟨q,x̂⟩ + ‖x̂‖²`` — so the hot loop is a
+    dense bf16 contraction, the shape TPUs are built for.  On a TPU a
+    batch takes the list-major *grouped* scan (:func:`grouped_recon_batch`):
+    the (query, list) pairs are sorted by list and one Pallas kernel
+    scores each tile of 16 queries against its list in one product and
+    keeps its top-k, so a list is read once per run of tiles
+    (``blocked_scan.scan_topk_grouped``).  Other batches gather each
+    query's probed lists (query-major).  The slab is *derived* state: it
+    is rebuilt from the codes on load and never serialized, so the
+    persisted index stays PQ-compressed.
   - ``mode="lut"``: classic ADC from the uint8 codes via lookup tables,
     with the table algebra split so NOTHING query×probe-dependent is
     recomputed inside the probe loop:
@@ -37,8 +43,11 @@ configs (``BASELINE.json``: ivf_pq on DEEP-10M) and standard IVF-PQ
   the measured ``_probe_block_table`` (``bench/tune_probe_block.py``).
 
 * Lists reuse the IVF-Flat padded-slab layout (device-packed via
-  :mod:`._packing`); optional exact re-ranking lives in
-  :mod:`raft_tpu.neighbors.refine`.
+  :mod:`._packing`).  A list holds at most ``⌈list_cap_ratio·n/L⌉`` rows;
+  every per-slot array stores that many slots rounded up to bf16's
+  sublane tile (:func:`slab_slots`), so a TPU stores the recon slab
+  list-major and no search program relayouts it.  Optional exact
+  re-ranking lives in :mod:`raft_tpu.neighbors.refine`.
 """
 
 from __future__ import annotations
@@ -58,6 +67,8 @@ from ..core.compat import shard_map
 from ..core.errors import expects
 from ..distance.pairwise import sq_l2
 from ._packing import chunked_filtered_queries, pack_lists
+from .ivf_flat import (grouped_batch, grouped_takes, pick_scan,
+                       slab_capacity, slot_bias)
 
 __all__ = [
     "IvfPqIndexParams",
@@ -104,9 +115,10 @@ class IvfPqSearchParams:
     # table via bench/tune_probe_block.py, else a working-set heuristic).
     # Bit-identical results at every value — a pure speed knob.
     probe_block: int = 0
-    # recon-tier scan kernel: "auto" | "xla" | "fused" — same contract as
-    # IvfFlatSearchParams.scan_kernel.  The LUT tier has no distance
-    # einsum to fuse and always runs the XLA scan.
+    # recon-tier scan kernel: "auto" | "grouped" | "xla" | "fused" — same
+    # contract as IvfFlatSearchParams.scan_kernel, over the bf16 slab.
+    # The LUT tier has no distance einsum to fuse and always runs the
+    # XLA scan.
     scan_kernel: str = "auto"
 
 
@@ -121,7 +133,8 @@ class IvfPqIndex:
     counts: jax.Array        # [L]
     metric: str = dataclasses.field(metadata=dict(static=True))
     # Derived tier (never serialized; rebuilt from codes via with_recon()):
-    recon: Optional[jax.Array] = None        # [L, cap, d] bf16 x̂ slab
+    # [L, cap, recon_lanes(d)] bf16 x̂ slab, zero columns past d
+    recon: Optional[jax.Array] = None
     recon_norms: Optional[jax.Array] = None  # [L, cap] f32 ‖x̂‖², +inf pads
     # 4-bit packed storage (pq_bits ≤ 4): codes hold TWO sub-codes per
     # byte, [L, cap, ceil(m/2)] — half the HBM/disk of byte codes
@@ -327,9 +340,22 @@ def _encode(residuals, codebooks, m: int):
             norms.reshape(-1)[:n])
 
 
+def recon_lanes(d: int) -> int:
+    """Stored width of the recon slab's rows: ``d`` rounded up to the
+    TPU's 128 lanes, the extra columns zero.  A TPU stores a
+    ``[L, cap, 96]`` bf16 slab with the list axis minor (its default
+    layout spares the lane padding), so every list-major read of it
+    first relayouts the whole slab; ``[L, cap, 128]`` is stored
+    list-major.  The zero columns change no dot and no norm."""
+    return -(-int(d) // 128) * 128
+
+
 @jax.jit
 def _decode_slab(codes, centroids, codebooks, ids):
     """Decode packed codes → bf16 reconstruction slab + exact f32 ‖x̂‖².
+
+    The slab is ``[L, cap, recon_lanes(d)]``: rows zero-padded to whole
+    lanes (:func:`recon_lanes`).
 
     A block of ~2^24 f32 elements of lists at a time, each written in
     place into the one output slab, so the program holds the slab once
@@ -373,11 +399,13 @@ def _decode_slab(codes, centroids, codebooks, ids):
                                          mantissa_bits=7)
         norms = jnp.sum(rec_f * rec_f, axis=2)
         norms = jnp.where(cb_ids >= 0, norms, jnp.inf)
-        return (jax.lax.dynamic_update_slice_in_dim(
-                    rec_out, rec_f.astype(jnp.bfloat16), lo, 0),
+        rec_b = jnp.pad(rec_f.astype(jnp.bfloat16),
+                        ((0, 0), (0, 0), (0, lanes - d)))
+        return (jax.lax.dynamic_update_slice_in_dim(rec_out, rec_b, lo, 0),
                 jax.lax.dynamic_update_slice_in_dim(norms_out, norms, lo, 0))
 
-    out = (jnp.zeros((L, cap, d), jnp.bfloat16),
+    lanes = recon_lanes(d)
+    out = (jnp.zeros((L, cap, lanes), jnp.bfloat16),
            jnp.zeros((L, cap), jnp.float32))
     return jax.lax.fori_loop(0, -(-L // block), decode_block, out)
 
@@ -420,6 +448,15 @@ def _adc_tables(codes, centroids, codebooks, code_norms):
     return clut, anorms.reshape(-1, cap)[:L]
 
 
+def slab_slots(cap: int) -> int:
+    """Stored slots of a list that holds at most ``cap`` rows: ``cap``
+    rounded up to the bf16 recon slab's sublane tile
+    (``ivf_flat.slab_capacity``), so a TPU stores the slab list-major.
+    Every per-slot array (codes, norms, ids, recon) has this many; the
+    extra slots are padding (id −1, ``+inf`` recon norm)."""
+    return slab_capacity(cap, jnp.bfloat16)
+
+
 def search_tier(index: IvfPqIndex, params: IvfPqSearchParams) -> str:
     """The tier a search runs: ``params.mode``, with ``"auto"`` the recon
     tier where the slab is materialized and the LUT tier otherwise."""
@@ -438,6 +475,52 @@ def count_search(tier: str, refine: bool) -> None:
         "raft_ivf_pq_search_total",
         "IVF-PQ serving programs compiled, by tier and exact re-rank",
     ).inc(tier=tier, refine="1" if refine else "0")
+
+
+def count_scan_path(path: str) -> None:
+    """Count one lowering of the recon tier's probe scan in
+    ``raft_ivf_pq_scan_path_total{path}``, the sibling of IVF-Flat's
+    ``raft_ivf_scan_path_total`` (one count per compiled program)."""
+    from ..obs.metrics import registry
+
+    registry().counter(
+        "raft_ivf_pq_scan_path_total",
+        "IVF-PQ recon-tier probe-scan lowerings by path",
+    ).inc(path=path)
+
+
+#: elements (rows × stored lanes) of the largest list the TPU's compiler
+#: gathers whole.  Past it, the query-major scan's gather is cut into
+#: pieces that each first slice the whole slab, at every scan step
+#: (compiled for v5e: 2048 × 128 gathers whole, 2064 × 128 and
+#: 2048 × 256 in pieces)
+GATHER_WHOLE_ELEMS = 2048 * 128
+
+
+def grouped_recon_batch(nq: int, n_probes: int, n_lists: int, cap: int,
+                        lanes: int) -> bool:
+    """Whether a recon-tier batch of ``nq`` rows over a
+    ``[n_lists, cap, lanes]`` slab takes the grouped scan: where it
+    probes each list half a time or more (``ivf_flat.grouped_batch``),
+    and at any size where a list is too large for the TPU to gather
+    whole (:data:`GATHER_WHOLE_ELEMS`): there the query-major scan
+    copies the slab at every step (on a v5e over 4096 lists of
+    3664 × 128, 187 ms a search at 1 row against 2.3 ms grouped)."""
+    return (grouped_batch(nq, n_probes, n_lists)
+            or cap * lanes > GATHER_WHOLE_ELEMS)
+
+
+def recon_scan(requested: str, index: IvfPqIndex, k: int, probe_block: int,
+               keep=None) -> str:
+    """The recon tier's probe scan (``ivf_flat.pick_scan``): ``"auto"``
+    takes the grouped scan on a TPU where the kernel takes the bf16 slab
+    (``ivf_flat.grouped_takes``); a program whose batch
+    :func:`grouped_recon_batch` leaves out scans query-major."""
+    takes = grouped_takes(index.list_cap, index.dim, int(k),
+                          index.recon.dtype,
+                          0 if keep is None else keep.ndim)
+    return pick_scan(requested, "ivf_pq", takes,
+                     probe_block * index.list_cap, k)
 
 
 def _stage(name: str, fn, *args, **kwargs):
@@ -511,7 +594,7 @@ def build(dataset, params: Optional[IvfPqIndexParams] = None, *,
            else jnp.arange(n, dtype=jnp.int32))
     (pk_codes, pk_norms, pk_ids), counts = _stage(
         "pack", pack_lists, labels, (codes, cnorms, ids),
-        n_lists=p.n_lists, cap=cap, fills=(0, 0.0, -1))
+        n_lists=p.n_lists, cap=slab_slots(cap), fills=(0, 0.0, -1))
 
     index = IvfPqIndex(centroids, codebooks, pk_codes, pk_norms, pk_ids,
                        counts, p.metric)
@@ -593,7 +676,8 @@ def extend(index: IvfPqIndex, new_vectors, new_ids=None, *,
         added = jax.ops.segment_sum(jnp.ones_like(labels, jnp.int32),
                                     labels, num_segments=L)
         need = int(jnp.max(index.counts + added))  # jaxlint: disable=JX01 slab capacity must be a host int at extend time (static shapes)
-        new_cap = max(need, cap + (cap + 1) // 2)  # geometric headroom
+        # geometric headroom
+        new_cap = slab_slots(max(need, cap + (cap + 1) // 2))
         pad = new_cap - cap
         grown = (jnp.pad(index.codes, ((0, 0), (0, pad), (0, 0))),
                  jnp.pad(index.code_norms, ((0, 0), (0, pad))),
@@ -632,7 +716,8 @@ def _pq_step_impl(slabs, counts, centroids, codebooks, xc, idc, *,
     with no host round-trip for ``counts``.  Pad rows (``idc < 0``) never
     request a list, never consume capacity, and scatter-drop via label −1
     — the padded fixed-shape stream is bit-identical to the unpadded
-    per-op loop.
+    per-op loop.  ``cap`` bounds each list's rows; the slabs may store
+    more slots (:func:`slab_slots`).
 
     Two jitted forms: :func:`_pq_chunk_step` donates the slabs (build
     loops own their buffers); :func:`_pq_chunk_step_cow` leaves the inputs
@@ -648,7 +733,7 @@ def _pq_step_impl(slabs, counts, centroids, codebooks, xc, idc, *,
     ch_codes, ch_norms = _encode(residuals, codebooks, m)
     return _scatter_append_impl(slabs, counts, labels,
                                 (ch_codes, ch_norms, idc),
-                                n_lists=n_lists, cap=cap)
+                                n_lists=n_lists, cap=slabs[0].shape[1])
 
 
 _pq_chunk_step = partial(jax.jit, static_argnames=("n_lists", "cap", "m"),
@@ -666,9 +751,10 @@ def _pq_stream_pipelined(dataset, centroids, codebooks,
     :func:`_pq_chunk_step` — one executable, one dispatch per chunk."""
     from ._packing import device_full, prefetch_chunks_padded
 
-    codes = device_full((p.n_lists, cap, m), 0, jnp.uint8)
-    cnorms = device_full((p.n_lists, cap), 0, jnp.float32)
-    ids_slab = device_full((p.n_lists, cap), -1, jnp.int32)
+    slots = slab_slots(cap)
+    codes = device_full((p.n_lists, slots, m), 0, jnp.uint8)
+    cnorms = device_full((p.n_lists, slots), 0, jnp.float32)
+    ids_slab = device_full((p.n_lists, slots), -1, jnp.int32)
     counts = device_full((p.n_lists,), 0, jnp.int32)
     for lo, hi, xc, idc in prefetch_chunks_padded(dataset, chunk_rows,
                                                   source_ids):
@@ -690,9 +776,10 @@ def _pq_stream_perop(dataset, centroids, codebooks, p: IvfPqIndexParams,
     from ..cluster.kmeans import capped_assign_room
     from ._packing import prefetch_chunks, scatter_append
 
-    codes = jnp.zeros((p.n_lists, cap, m), jnp.uint8)
-    cnorms = jnp.zeros((p.n_lists, cap), jnp.float32)
-    ids_slab = jnp.full((p.n_lists, cap), -1, jnp.int32)
+    slots = slab_slots(cap)
+    codes = jnp.zeros((p.n_lists, slots, m), jnp.uint8)
+    cnorms = jnp.zeros((p.n_lists, slots), jnp.float32)
+    ids_slab = jnp.full((p.n_lists, slots), -1, jnp.int32)
     counts = jnp.zeros((p.n_lists,), jnp.int32)
     for lo, hi, xc_h, idc_h in prefetch_chunks(dataset, chunk_rows,
                                                source_ids):
@@ -703,7 +790,7 @@ def _pq_stream_perop(dataset, centroids, codebooks, p: IvfPqIndexParams,
         ch_codes, ch_norms = _encode(residuals, codebooks, m)
         (codes, cnorms, ids_slab), counts = scatter_append(
             (codes, cnorms, ids_slab), counts, labels,
-            (ch_codes, ch_norms, idc), n_lists=p.n_lists, cap=cap)
+            (ch_codes, ch_norms, idc), n_lists=p.n_lists, cap=slots)
     return codes, cnorms, ids_slab, counts
 
 
@@ -800,12 +887,26 @@ def _search_recon_impl(centroids, recon, recon_norms, ids, q,
     from ._packing import blocked_probe_plan
 
     nq, d = q.shape
-    cap = recon.shape[1]
+    n_lists, cap = recon.shape[0], recon.shape[1]
+    if scan_kernel == "grouped" and not grouped_recon_batch(
+            nq, n_probes, n_lists, cap, recon.shape[2]):
+        scan_kernel = _scan.resolve_scan_kernel("auto", "ivf_pq",
+                                                probe_block * cap, k)
+    count_scan_path("grouped" if scan_kernel == "grouped" else "query_major")
     qf = q.astype(jnp.float32)
     qn = _scan.row_sq_norms(qf)
-    qb = q.astype(jnp.bfloat16)
+    dp = recon.shape[2]                           # recon_lanes(d)
+    qb = jnp.pad(q.astype(jnp.bfloat16), ((0, 0), (0, dp - d)))
     cd = sq_l2(q, centroids)                      # [nq, L]
     _, probes = jax.lax.top_k(-cd, n_probes)
+    if scan_kernel == "grouped":
+        # list-major: each probed list read once per run of 16-query
+        # tiles; recon_norms' +inf pads mask L2, ids and keep mask both
+        bias = slot_bias(recon_norms, ids, None, metric, keep)
+        bv, bi = _scan.scan_topk_grouped(
+            qb, qn, recon, bias, ids, probes, k,
+            l2=metric != "inner_product", clamp=False)
+        return _finish(bv, bi, metric)
     lists_xs, pvalid = blocked_probe_plan(probes, probe_block)
 
     def gather(inp):
@@ -833,7 +934,7 @@ def _search_recon_impl(centroids, recon, recon_norms, ids, q,
             else:
                 # recon_norms carries +inf on pad entries — they self-mask
                 base = recon_norms[lists].reshape(nq, bcap)
-            return (slab.reshape(nq, bcap, d), mask(base, lists, pv, vids),
+            return (slab.reshape(nq, bcap, dp), mask(base, lists, pv, vids),
                     vids, _scan.list_slab_ptr(lists, cap))
 
         rescore = _scan.l2_rescorer(recon, recon_norms, qb, qn, metric,
@@ -858,6 +959,12 @@ def _search_recon_impl(centroids, recon, recon_norms, ids, q,
             return mask(dist, lists, pv, vids), vids
 
         bv, bi = _scan.scan_topk(score, (lists_xs, pvalid), nq, k)
+    return _finish(bv, bi, metric)
+
+
+def _finish(bv, bi, metric: str):
+    """Scan distances to the metric's: √ for euclidean, − for inner
+    product."""
     if metric == "euclidean":
         bv = jnp.sqrt(jnp.maximum(bv, 0.0))
     elif metric == "inner_product":
@@ -934,11 +1041,7 @@ def _search_lut_impl(centroids, codebooks, codes, adc_norms, ids, counts, q,
     from ..ops.blocked_scan import scan_topk
 
     bv, bi = scan_topk(score, (lists_xs, pvalid), nq, k)
-    if metric == "euclidean":
-        bv = jnp.sqrt(jnp.maximum(bv, 0.0))
-    elif metric == "inner_product":
-        bv = -bv
-    return bv, bi
+    return _finish(bv, bi, metric)
 
 
 @tracing.annotate("ivf_pq.search")
@@ -969,11 +1072,7 @@ def search(index: IvfPqIndex, queries, k: int,
         expects(index.recon is not None,
                 "mode='recon' needs the reconstruction slab — call "
                 "index.with_recon() (e.g. after load_index)")
-        from ..ops.blocked_scan import resolve_scan_kernel
-
-        scan_kernel = resolve_scan_kernel(p.scan_kernel, "ivf_pq",
-                                          probe_block * index.list_cap,
-                                          int(k))
+        scan_kernel = recon_scan(p.scan_kernel, index, k, probe_block, keep)
         impl = lambda qc, kc: _search_recon_impl(
             index.centroids, index.recon, index.recon_norms, index.ids,
             qc, int(k), int(n_probes), index.metric, kc, probe_block,
@@ -1028,11 +1127,7 @@ def searcher(index: IvfPqIndex, k: int,
         expects(index.recon is not None,
                 "mode='recon' needs the reconstruction slab — call "
                 "index.with_recon() (e.g. after load_index)")
-        from ..ops.blocked_scan import resolve_scan_kernel
-
-        scan_kernel = resolve_scan_kernel(p.scan_kernel, "ivf_pq",
-                                          probe_block * index.list_cap,
-                                          int(k))
+        scan_kernel = recon_scan(p.scan_kernel, index, k, probe_block, keep)
         if keep is not None:
 
             def fn(q, centroids, recon, recon_norms, ids, kp):
@@ -1130,7 +1225,7 @@ def _sharded_encode_program(mesh, axis: str, n_orig: int, per: int,
         codes, cnorms = _encode(residuals, codebooks, m)
         (pk_codes, pk_norms, pk_ids), counts = pack_lists(
             labels, (codes, cnorms, gid),
-            n_lists=n_lists_local, cap=cap, fills=(0, 0.0, -1))
+            n_lists=n_lists_local, cap=slab_slots(cap), fills=(0, 0.0, -1))
         if store_recon:
             rec, rnorms = _decode_slab(pk_codes, c_l, codebooks, pk_ids)
         else:  # static-shape placeholders dropped by the caller
@@ -1246,7 +1341,8 @@ def _sharded_chunk_step_program(mesh, axis: str, n_lists_local: int,
         ch_codes, ch_norms = _encode(residuals, cb, m)
         (codes_l, cn_l, ids_l), counts_l = _scatter_append_impl(
             (codes_l, cn_l, ids_l), counts_l, labels,
-            (ch_codes, ch_norms, idc_l), n_lists=n_lists_local, cap=cap)
+            (ch_codes, ch_norms, idc_l), n_lists=n_lists_local,
+            cap=codes_l.shape[1])
         return codes_l, cn_l, ids_l, counts_l
 
     return jax.jit(shard_map(
@@ -1337,10 +1433,11 @@ def build_chunked_sharded(dataset, mesh,
         m, cc, p.pq_kmeans_n_iters)
     codebooks = jax.device_put(codebooks, NamedSharding(mesh, P()))
 
-    L = n_dev * n_lists_local
-    codes = jax.device_put(jnp.zeros((L, cap, m), jnp.uint8), sharding)
-    cnorms = jax.device_put(jnp.zeros((L, cap), jnp.float32), sharding)
-    ids_slab = jax.device_put(jnp.full((L, cap), -1, jnp.int32), sharding)
+    L, slots = n_dev * n_lists_local, slab_slots(cap)
+    codes = jax.device_put(jnp.zeros((L, slots, m), jnp.uint8), sharding)
+    cnorms = jax.device_put(jnp.zeros((L, slots), jnp.float32), sharding)
+    ids_slab = jax.device_put(jnp.full((L, slots), -1, jnp.int32),
+                              sharding)
     counts = jax.device_put(jnp.zeros((L,), jnp.int32), sharding)
     step = _sharded_chunk_step_program(mesh, axis, n_lists_local, cap, m)
     heartbeat = build_heartbeat("ivf_pq.build_chunked_sharded", n)

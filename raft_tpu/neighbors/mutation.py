@@ -232,7 +232,9 @@ def compact(index, *, headroom: float = 2.0):
     # compaction, never on the search path
     new_cap = max(1, int(float(headroom) *
                          int(jax.device_get(jnp.max(live)))))  # jaxlint: disable=JX01 static slab shape: one explicit transfer per compaction, never on the search path
-    if not (is_pq or is_rabitq):
+    if is_pq:
+        new_cap = ivf_pq.slab_slots(new_cap)
+    elif not is_rabitq:
         new_cap = ivf_flat.slab_capacity(new_cap, base.data.dtype)
     if is_pq:
         flat = (base.codes.reshape(L * cap, -1),

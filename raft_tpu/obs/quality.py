@@ -177,7 +177,7 @@ def oracle_database(index):
     elif hasattr(index, "codes"):                      # ivf_pq
         idx = index.with_recon() if index.recon is None else index
         vecs = np.asarray(jax.device_get(idx.recon),  # jaxlint: disable=JX01 one-time oracle corpus extraction, off the hot path
-                          dtype=np.float32).reshape(-1, idx.dim)
+                          dtype=np.float32)[..., :idx.dim].reshape(-1, idx.dim)
         ids = np.asarray(jax.device_get(idx.ids), dtype=np.int64).reshape(-1)  # jaxlint: disable=JX01 one-time oracle corpus extraction, off the hot path
     elif hasattr(index, "data"):                       # ivf_flat
         vecs = np.asarray(jax.device_get(index.data),  # jaxlint: disable=JX01 one-time oracle corpus extraction, off the hot path

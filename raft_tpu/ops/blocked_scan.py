@@ -29,9 +29,10 @@ module owns the contract:
   Approximate-partial: the candidate *set* is recall-gated, not
   bit-pinned (a true neighbor is shed only on a ≥3-way lane-bucket
   collision within one slab block).
-* :func:`scan_topk_grouped` — IVF-Flat's list-major scan for batches that
-  share lists: :func:`grouped_plan` sorts the (query, list) pairs by list
-  into tiles of :data:`GROUPED_TILE` query slots, and one Pallas kernel
+* :func:`scan_topk_grouped` — the list-major scan of IVF-Flat and of the
+  IVF-PQ recon tier, for batches that share lists: :func:`grouped_plan`
+  sorts the (query, list) pairs by list into tiles of
+  :data:`GROUPED_TILE` query slots, and one Pallas kernel
   (``ops/pallas/grouped_scan.py``) scores each tile with one MXU product
   against its list and keeps each row's exact top-k.  The cross-block
   bit-invariance contract of :func:`slab_dots` is the query-major path's;
@@ -411,19 +412,22 @@ def grouped_plan(lists, n_lists: int, qt: int, pair_valid=None):
 
 
 def scan_topk_grouped(qf, qn, data, bias, ids, lists, k: int, *, l2: bool,
-                      pair_valid=None, qt: int = GROUPED_TILE
+                      clamp: bool = True, pair_valid=None,
+                      qt: int = GROUPED_TILE
                       ) -> Tuple[jax.Array, jax.Array]:
-    """List-major probe scan (the grouped path of IVF-Flat).
+    """List-major probe scan (IVF-Flat's and the IVF-PQ recon tier's).
 
     ``lists`` [nq, P]: each query's probed slab rows; ``data`` [L, cap, d]
-    f32 and ``ids`` [L, cap]; ``bias`` [L, 1, cap]: the stored squared
-    norm (``l2``) or 0 at a live slot, ``+inf`` elsewhere.  One
-    :func:`grouped_plan`, one ``ivf_grouped_scan`` kernel (each tile's
-    exact top-k of its list), the tiles' rows scattered back to
+    of ``qf``'s dtype and ``ids`` [L, cap]; ``bias`` [L, 1, cap]: the
+    stored squared norm (``l2``) or 0 at a live slot, ``+inf`` elsewhere.
+    One :func:`grouped_plan`, one ``ivf_grouped_scan`` kernel (each
+    tile's exact top-k of its list), the tiles' rows scattered back to
     ``[nq, P·k]`` through the plan, and one ranked selection per query.
 
     The candidate set is the query-major scan's: every live row of every
-    probed list, scored by the same algebra at ``precision=HIGHEST``.
+    probed list, scored by the same algebra at the same precision
+    (``precision=HIGHEST`` for f32, one bf16 pass for bf16), with L2
+    floored at 0 under ``clamp`` as :func:`l2_rescorer` floors it.
     Only the dot's accumulation order differs (one MXU product per tile,
     not a per-query mat-vec), so distances agree within a few f32 ulps
     and ids agree wherever no two candidates tie that closely.  Results
@@ -441,15 +445,17 @@ def scan_topk_grouped(qf, qn, data, bias, ids, lists, k: int, *, l2: bool,
     qs = qf[slot_query].reshape(n_tiles, qt, qf.shape[1])
     qns = qn[slot_query].reshape(n_tiles, qt, 1)
     vals, slots = grouped_list_topk(tile_list, n_used, qs, qns, data, bias,
-                                    k, l2=l2)
+                                    k, l2=l2, clamp=clamp)
     row = jnp.minimum(pair_pos.reshape(-1), n_tiles * qt - 1)
     pv = vals.reshape(n_tiles * qt, k)[row].reshape(nq, n_probes, k)
     ps = slots.reshape(n_tiles * qt, k)[row].reshape(nq, n_probes, k)
     live = jnp.isfinite(pv) & (pair_pos < n_tiles * qt)[..., None]
-    vids = ids.reshape(-1)[jnp.maximum(lists[..., None] * cap + ps, 0)]
     pv = jnp.where(live, pv, jnp.inf).reshape(nq, n_probes * k)
-    vids = jnp.where(live, vids, -1).reshape(nq, n_probes * k)
-    return ranked_finish(pv, vids, k)
+    # select on slab pointers and look up the k winners' ids only: an
+    # id gather per candidate is nq·P·k scalar reads
+    ptr = jnp.where(live, lists[..., None] * cap + ps, -1)
+    bv, bp = ranked_finish(pv, ptr.reshape(nq, n_probes * k), k)
+    return bv, jnp.where(bp >= 0, ids.reshape(-1)[jnp.maximum(bp, 0)], -1)
 
 
 def list_slab_ptr(lists, cap: int):

@@ -6,10 +6,13 @@ probed by many queries of a batch is copied and read once per query.
 Here the (query, list) pairs are grouped by list
 (``ops/blocked_scan.grouped_plan``): each grid step is one *tile* of
 ``Qt`` query slots that all probe the same list, and scores them as one
-``[Qt, d] · [d, cap]`` product at ``precision=HIGHEST`` with f32
-accumulation.  The tile → list table is scalar-prefetched into the
-slab's ``index_map``, so consecutive tiles of one list reuse the block
-already in VMEM and nothing of size ``[tiles, cap, d]`` reaches HBM.
+``[Qt, d] · [d, cap]`` product with f32 accumulation.  The product's
+precision follows the operands' dtype: f32 tiles (IVF-Flat) take
+``precision=HIGHEST``, bf16 tiles (the IVF-PQ recon slab) one bf16 MXU
+pass, the recon tier's query-major contract.  The tile → list table is
+scalar-prefetched into the slab's ``index_map``, so consecutive tiles of
+one list reuse the block already in VMEM and nothing of size
+``[tiles, cap, d]`` reaches HBM.
 
 Each tile row keeps its exact top-k by ``k`` min-extraction passes in
 VMEM (the passes of ``ops/pallas/select_k.py``), so only ``[Qt, k]``
@@ -37,18 +40,21 @@ _LANES = 128
 
 
 def _kernel(tile_list_ref, n_used_ref, q_ref, qn_ref, x_ref, b_ref,
-            val_ref, idx_ref, *, k: int, l2: bool):
+            val_ref, idx_ref, *, k: int, l2: bool, clamp: bool):
     del tile_list_ref  # consumed by the index maps
     t = pl.program_id(0)
 
     @pl.when(t < n_used_ref[0])
     def _score():
+        exact = q_ref.dtype == jnp.float32
         dots = jax.lax.dot_general(                      # (Qt, cap)
             q_ref[...], x_ref[...], (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
+            precision=jax.lax.Precision.HIGHEST if exact else None,
             preferred_element_type=jnp.float32)
         if l2:  # the query-major scan's order: (‖y‖² − 2·dot) + ‖q‖²
-            dist = jnp.maximum(b_ref[...] - 2.0 * dots + qn_ref[...], 0.0)
+            dist = b_ref[...] - 2.0 * dots + qn_ref[...]
+            if clamp:
+                dist = jnp.maximum(dist, 0.0)
         else:
             dist = b_ref[...] - dots
         qt, cap = dist.shape
@@ -79,9 +85,9 @@ def _kernel(tile_list_ref, n_used_ref, q_ref, qn_ref, x_ref, b_ref,
         idx_ref[...] = jnp.full(idx_ref.shape, -1, jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "l2", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "l2", "clamp", "interpret"))
 def grouped_scan(tile_list, n_used, q, qn, data, bias, k: int, l2: bool,
-                 interpret: bool):
+                 clamp: bool, interpret: bool):
     n_tiles, qt, d = q.shape
     cap = data.shape[1]
     kpad = max(_LANES, -(-k // _LANES) * _LANES)
@@ -100,7 +106,7 @@ def grouped_scan(tile_list, n_used, q, qn, data, bias, k: int, l2: bool,
         out_specs=(out_spec, out_spec),
     )
     return pl.pallas_call(
-        functools.partial(_kernel, k=k, l2=l2),
+        functools.partial(_kernel, k=k, l2=l2, clamp=clamp),
         grid_spec=grid_spec,
         out_shape=(out, out_idx),
         compiler_params=pltpu.CompilerParams(
@@ -111,22 +117,27 @@ def grouped_scan(tile_list, n_used, q, qn, data, bias, k: int, l2: bool,
 
 
 def grouped_list_topk(tile_list, n_used, q, qn, data, bias, k: int, *,
-                      l2: bool) -> Tuple[jax.Array, jax.Array]:
+                      l2: bool, clamp: bool = True
+                      ) -> Tuple[jax.Array, jax.Array]:
     """Per tile row, the exact ``k`` smallest distances to the tile's list
     and their slots in it.
 
     ``tile_list`` [T] int32: the slab row (list) of each tile; ``n_used``
     [1] int32: tiles past it are idle and return ``+inf``/``-1``.  ``q``
-    [T, Qt, d] f32 and ``qn`` [T, Qt, 1] f32: the queries of each tile's
-    slots and their squared norms.  ``data`` [L, cap, d] f32: the list
-    slab, read in its stored layout.  ``bias`` [L, 1, cap] f32: the
-    stored squared norm (``l2``) or 0 where a slot is live, ``+inf``
-    where it is not.  Distance ``max(bias − 2·dot + qn, 0)`` (``l2``) or
-    ``bias − dot``.  Returns ``(values, slots)`` of ``[T, Qt, k]``,
-    ascending; a row with fewer than ``k`` live slots ends in ``+inf``.
+    [T, Qt, d] and ``qn`` [T, Qt, 1] f32: the queries of each tile's
+    slots and their squared norms.  ``data`` [L, cap, d] of ``q``'s
+    dtype, f32 (scored at ``precision=HIGHEST``) or bf16 (one bf16
+    pass): the list slab, read in its stored layout.  ``bias``
+    [L, 1, cap] f32: the stored squared norm (``l2``) or 0 where a slot
+    is live, ``+inf`` where it is not.  Distance ``bias − 2·dot + qn``
+    (``l2``; floored at 0 with ``clamp``, as IVF-Flat's are and the
+    recon tier's are not) or ``bias − dot``.  Returns ``(values,
+    slots)`` of ``[T, Qt, k]``, ascending; a row with fewer than ``k``
+    live slots ends in ``+inf``.
     """
     from .gate import interpret
 
     vals, slots = grouped_scan(tile_list, n_used, q, qn, data, bias, int(k),
-                               bool(l2), interpret("ivf_grouped_scan"))
+                               bool(l2), bool(clamp),
+                               interpret("ivf_grouped_scan"))
     return vals[..., :k], slots[..., :k]

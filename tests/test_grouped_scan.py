@@ -1,4 +1,5 @@
-"""List-major ("grouped") IVF-Flat probe scan against the query-major scan.
+"""List-major ("grouped") IVF probe scan against the query-major scan:
+IVF-Flat's f32 slab, and the IVF-PQ recon tier's bf16 slab.
 
 The grouped scan sorts a batch's (query, list) pairs by list, cuts each
 list's run into tiles of query slots, and scores a tile with one MXU
@@ -7,7 +8,7 @@ here, with the product the chip runs).  Its candidate set is the query-major sca
 accumulation order differs.  So: ids equal wherever no two candidates
 tie within a few ulps, and distances within a few f32 ulps of
 ‖q‖² + ‖y‖².  The plan's tile count never exceeds its static bound, and
-the path counter counts one lowering per compiled program.
+the path counters count one lowering per compiled program.
 """
 
 from __future__ import annotations
@@ -182,19 +183,35 @@ def test_grouped_plan_stays_within_bound(qt):
                 np.zeros((4, 3), bool))
 
 
-def test_scan_path_rule():
+def test_scan_path_rule(monkeypatch):
     """The rule at the SIFT-1M-class cell's index, 1024 lists of 1960 ×
     128 f32 rows, 32 probes, k = 10: the kernel takes the index, and a
-    batch takes the grouped scan from 16 rows (nq·P ≥ L / 2)."""
+    batch takes the grouped scan from 16 rows (nq·P ≥ L / 2).  The kernel
+    takes bf16 lists too (the IVF-PQ recon slab), at their own itemsize,
+    but IVF-Flat, which scores f32 queries at HIGHEST, keeps a bf16 slab
+    query-major."""
+    from raft_tpu.ops.pallas import gate
+
     takes = lambda **kw: ivf_flat.grouped_takes(
         kw.get("cap", 1960), 128, kw.get("k", 10),
         kw.get("dtype", jnp.float32), kw.get("keep", 0))
     assert takes() and takes(keep=1)
     assert not takes(dtype=jnp.uint8)
-    assert not takes(dtype=jnp.bfloat16)
+    assert takes(dtype=jnp.bfloat16)
     assert not takes(keep=2)
     assert not takes(k=256)
     assert not takes(cap=16384)
+    assert takes(cap=16384, dtype=jnp.bfloat16)      # 4 MiB at 2 bytes
+    assert not takes(cap=16400, dtype=jnp.bfloat16)
+    monkeypatch.setattr(gate, "on_tpu", lambda: True)
+    lists, d = 8, 128
+    bf16 = ivf_flat.IvfFlatIndex(
+        jnp.zeros((lists, d)), jnp.zeros((lists, 16, d), jnp.bfloat16),
+        jnp.zeros((lists, 16), jnp.int32), jnp.zeros((lists,), jnp.int32),
+        jnp.zeros((lists, 16)), "sqeuclidean")
+    assert ivf_flat.resolve_scan("auto", bf16, 10, 1) != "grouped"
+    with pytest.raises(Exception, match="grouped scan takes"):
+        ivf_flat.resolve_scan("grouped", bf16, 10, 1)
     assert [ivf_flat.grouped_batch(b, 32, 1024)
             for b in (1, 8, 15, 16, 32, 64, 512)] == [
         False, False, False, True, True, True, True]
@@ -287,3 +304,197 @@ def test_slab_capacity_pads_storage_only(data):
     chunked = ivf_flat.build_chunked(x[:1000], p, chunk_rows=256)
     assert chunked.list_cap == index.list_cap
     assert np.asarray(chunked.counts).max() <= cap
+
+
+# ---------------------------------------------------------------------------
+# IVF-PQ recon tier: the grouped kernel over the bf16 reconstruction slab
+# ---------------------------------------------------------------------------
+
+PQ_METRICS = ("sqeuclidean", "inner_product")
+
+
+@pytest.fixture(scope="module")
+def pq_indexes(data):
+    from raft_tpu.neighbors import ivf_pq
+
+    x, _ = data
+    return {m: ivf_pq.build(x, ivf_pq.IvfPqIndexParams(
+        n_lists=N_LISTS, pq_dim=8, metric=m, seed=3)) for m in PQ_METRICS}
+
+
+def _pq_search(index, q, kernel, filt=None, k=K):
+    from raft_tpu.neighbors import ivf_pq
+
+    p = ivf_pq.IvfPqSearchParams(n_probes=N_PROBES, scan_kernel=kernel)
+    d, i = ivf_pq.search(index, q, k, p, filter=filt)
+    return np.asarray(d), np.asarray(i)
+
+
+def _recon_rows(index):
+    """Each source id's bf16 reconstruction, as f32 (the scale of the
+    recon tier's distances)."""
+    ids = np.asarray(index.ids).reshape(-1)
+    rec = np.asarray(index.recon.astype(jnp.float32)).reshape(ids.size, -1)
+    out = np.zeros((ids.max() + 1, rec.shape[1]), np.float32)
+    out[ids[ids >= 0]] = rec[ids >= 0]
+    return out
+
+
+@pytest.mark.parametrize("filtered", [False, True])
+@pytest.mark.parametrize("metric", PQ_METRICS)
+def test_ivf_pq_grouped_matches_query_major(data, pq_indexes, metric,
+                                            filtered):
+    """The recon tier's grouped scan over the bf16 slab: the query-major
+    scan's candidates, distances within a few f32 ulps, with and without
+    a shared keep filter."""
+    x, q = data
+    index = pq_indexes[metric]
+    assert index.recon.dtype == jnp.bfloat16
+    keep = None
+    if filtered:
+        keep = np.ones(len(x), bool)
+        keep[::3] = False
+    ref = _pq_search(index, q, "xla", keep)
+    got = _pq_search(index, q, "grouped", keep)
+    if filtered:
+        assert not np.isin(got[1][got[1] >= 0], np.flatnonzero(~keep)).any()
+    _assert_agree(_recon_rows(index), q, ref, got, metric)
+
+
+@pytest.mark.parametrize("case", ["short_and_empty", "worst_skew"])
+def test_ivf_pq_grouped_short_empty_and_skewed_lists(data, pq_indexes, case):
+    """Lists cut short and emptied (pad slots: id −1, ``+inf`` norm), and
+    a batch whose every query probes the same lists."""
+    x, q = data
+    index = pq_indexes["sqeuclidean"]
+    if case == "short_and_empty":
+        ids = np.asarray(index.ids).copy()
+        counts = np.asarray(index.counts).copy()
+        counts[:6] = 0
+        counts[6:20] //= 3
+        dead = np.arange(index.list_cap)[None, :] >= counts[:, None]
+        gone = ids[dead & (ids >= 0)]
+        ids[dead] = -1
+        norms = np.where(dead, np.inf, np.asarray(index.recon_norms))
+        index = dataclasses.replace(
+            index, ids=jnp.asarray(ids), counts=jnp.asarray(counts),
+            recon_norms=jnp.asarray(norms, jnp.float32))
+    else:
+        q = np.repeat(q[:1], 48, axis=0) + np.linspace(
+            0, 1e-2, 48, dtype=np.float32)[:, None]
+    ref = _pq_search(index, q, "xla")
+    got = _pq_search(index, q, "grouped")
+    if case == "short_and_empty":
+        assert not np.isin(got[1], gone).any()
+    _assert_agree(_recon_rows(pq_indexes["sqeuclidean"]), q, ref, got,
+                  "sqeuclidean")
+
+
+def test_ivf_pq_grouped_refined_searcher(data, pq_indexes):
+    """The served program of a ``refine.Refined`` view: the recon tier at
+    k·ratio = 40 candidates, then the exact re-rank, grouped as
+    query-major."""
+    from raft_tpu.neighbors import ivf_pq
+    from raft_tpu.neighbors.refine import Refined
+    from raft_tpu.serve import make_searcher
+
+    x, q = data
+    view = Refined(pq_indexes["sqeuclidean"], jnp.asarray(x), 4)
+    out = {}
+    for kernel in ("xla", "grouped"):
+        fn, ops = make_searcher(view, K, ivf_pq.IvfPqSearchParams(
+            n_probes=N_PROBES, scan_kernel=kernel))
+        out[kernel] = [np.asarray(a) for a in jax.jit(fn)(q, *ops)]
+    _assert_agree(x, q, out["xla"], out["grouped"], "sqeuclidean")
+
+
+def test_ivf_pq_scan_path_rule_and_counter(data, pq_indexes, monkeypatch):
+    """``"auto"`` resolves to the grouped scan on a TPU only; its program
+    then scans a bucket grouped where nq·P ≥ L / 2 (2 rows and up here,
+    11 probes over 37 lists) and query-major below, one count per
+    compiled bucket in ``raft_ivf_pq_scan_path_total``.  Lists too large
+    for the TPU to gather whole take the grouped scan at every size."""
+    from raft_tpu.neighbors import ivf_pq
+    from raft_tpu.obs.metrics import registry
+    from raft_tpu.ops.pallas import gate
+
+    _, q = data
+    index = pq_indexes["sqeuclidean"]
+    p = ivf_pq.IvfPqSearchParams(n_probes=N_PROBES)
+    assert ivf_pq.recon_scan("auto", index, 40, 1) == "xla"
+    assert ivf_pq.recon_scan("auto", index, 200, 1) == "xla"
+    rule = ivf_pq.grouped_recon_batch
+    assert [rule(b, 64, 4096, 2048, 128) for b in (1, 8, 16, 32, 512)] == [
+        False, False, False, True, True]
+    assert all(rule(b, 64, 4096, 3664, 128) for b in (1, 8, 16, 32, 512))
+    assert rule(1, 64, 4096, 2048, 256) and not rule(1, 64, 4096, 4096, 64)
+    keep = np.ones((3, len(data[0])), bool)            # per-query bitmap
+    with monkeypatch.context() as m:
+        m.setattr(gate, "on_tpu", lambda: True)
+        assert ivf_pq.recon_scan("auto", index, 40, 1) == "grouped"
+        assert ivf_pq.recon_scan("auto", index, 200, 1) == "xla"
+        assert ivf_pq.recon_scan("auto", index, 40, 1, keep) == "xla"
+        fn, ops = ivf_pq.searcher(index, 40, p)     # resolved on a "TPU"
+    was = set_registry(MetricRegistry())
+    try:
+        def paths():
+            return {lb["path"]: v for lb, v in registry().counter(
+                "raft_ivf_pq_scan_path_total").samples()}
+
+        prog = jax.jit(fn)
+        for rows in (1, 2, 8, 8):
+            jax.block_until_ready(prog(q[:rows], *ops))
+        assert [ivf_flat.grouped_batch(r, N_PROBES, N_LISTS)
+                for r in (1, 2)] == [False, True]
+        assert paths() == {"query_major": 1.0, "grouped": 2.0}
+        ref = _pq_search(index, q[:8], "xla", k=40)
+        got = [np.asarray(a) for a in prog(q[:8], *ops)]
+        _assert_agree(_recon_rows(index), q[:8], ref, got, "sqeuclidean")
+    finally:
+        set_registry(was)
+
+
+@pytest.mark.parametrize("entry", ["build", "build_chunked"])
+def test_ivf_pq_build_pads_storage_only(data, entry, tmp_path):
+    """The IVF-PQ build stores ``slab_capacity(cap, bfloat16)`` slots per
+    list: a list holds at most ``cap`` rows, and ``build`` puts in each
+    list the rows the capped assignment gives it; pad slots are id −1
+    with a ``+inf`` recon norm; ``save``/``load`` round-trips."""
+    from raft_tpu.cluster.kmeans import capped_assign
+    from raft_tpu.neighbors import ivf_pq, load_index, save_index
+
+    x, q = data
+    n = 1000
+    p = ivf_pq.IvfPqIndexParams(n_lists=7, pq_dim=8, list_cap_ratio=1.05,
+                                seed=3)
+    cap = int(np.ceil(1.05 * n / 7))
+    index = (ivf_pq.build(x[:n], p) if entry == "build"
+             else ivf_pq.build_chunked(x[:n], p, chunk_rows=256))
+    slots = ivf_flat.slab_capacity(cap, jnp.bfloat16)
+    assert slots > cap and ivf_pq.slab_slots(cap) == slots
+    for a in (index.codes, index.code_norms, index.ids, index.recon,
+              index.recon_norms, index.adc_norms):
+        assert a.shape[:2] == (7, slots)
+    counts, ids = np.asarray(index.counts), np.asarray(index.ids)
+    assert counts.max() <= cap and counts.sum() == n
+    pad = np.arange(slots)[None, :] >= counts[:, None]
+    assert np.all(ids[pad] == -1) and np.all(ids[~pad] >= 0)
+    assert np.all(np.isinf(np.asarray(index.recon_norms)[pad]))
+    if entry == "build":
+        assert counts.max() == cap                   # lists at cap
+        labels = np.asarray(capped_assign(jnp.asarray(x[:n]),
+                                          index.centroids, cap)[0])
+        for j in range(7):
+            np.testing.assert_array_equal(
+                np.sort(ids[j][~pad[j]]), np.flatnonzero(labels == j))
+    save_index(tmp_path / "pq", index)
+    back = load_index(tmp_path / "pq").with_recon()
+    assert back.list_cap == slots
+    for a, b in ((index.ids, back.ids), (index.codes, back.codes),
+                 (index.recon_norms, back.recon_norms)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for kernel in ("xla", "grouped"):
+        want = _pq_search(index, q, kernel)
+        got = _pq_search(back, q, kernel)
+        np.testing.assert_array_equal(want[1], got[1])
+        np.testing.assert_array_equal(want[0], got[0])

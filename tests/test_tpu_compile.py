@@ -248,15 +248,17 @@ def _deep10m_ivf_pq():
 
 
 def test_ivf_pq_decode_fits_one_v5e(one_chip):
-    """The build's decode step at DEEP-10M-class size: 4096 lists of 3663
-    slots, 48 sub-codes a row, into the 2.9 GB bf16 slab.  Stacking the
-    decoded blocks held 14 GB, more than fits beside the 3.84 GB base."""
+    """The build's decode step at DEEP-10M-class size: 4096 lists of 3664
+    slots, 48 sub-codes a row, into the 3.8 GB bf16 slab (rows padded to
+    128 lanes).  Stacking the decoded blocks held 14 GB, more than fits
+    beside the 3.84 GB base."""
     from raft_tpu.neighbors import ivf_pq
 
     cfg = _deep10m_ivf_pq()
     n, d = cfg["data"]["rows"], cfg["data"]["dim"]
     lists, m = cfg["index"]["n_lists"], cfg["index"]["pq_dim"]
-    cap = int(np.ceil(ivf_pq.IvfPqIndexParams().list_cap_ratio * n / lists))
+    cap = ivf_pq.slab_slots(int(np.ceil(
+        ivf_pq.IvfPqIndexParams().list_cap_ratio * n / lists)))
     compiled = ivf_pq._decode_slab.lower(
         _spec((lists, cap, m), jnp.uint8, one_chip),
         _spec((lists, d), jnp.float32, one_chip),
@@ -268,11 +270,13 @@ def test_ivf_pq_decode_fits_one_v5e(one_chip):
                 + mem.temp_size_in_bytes) + 4 * n * d < 16e9
 
 
-def test_served_ivf_pq_with_refine_fits_one_v5e(one_chip, monkeypatch):
-    """The served 512-row program of the ``deep10m-ivf_pq.batch`` cell:
-    the recon-tier PQ scan at 40 candidates and their exact re-rank over
-    the 10M × 96 f32 base, in one program, under one chip's 16 GB with
-    the index and the base as its operands."""
+def _served_ivf_pq(one_chip, monkeypatch, rows):
+    """The served ``rows``-row program of the ``deep10m-ivf_pq.batch``
+    cell, compiled for one v5e: the recon-tier scan at 40 candidates over
+    4096 lists of the build's 3664 slots (3663 rows a list, padded to
+    bf16's sublane tile) × 96 (stored as 128 lanes), 64 probes, and the
+    exact re-rank over the 10M × 96 f32 base, with the index and the base
+    as its operands."""
     from raft_tpu.neighbors import ivf_pq
     from raft_tpu.neighbors.refine import Refined
     from raft_tpu.neighbors import _packing
@@ -287,7 +291,9 @@ def test_served_ivf_pq_with_refine_fits_one_v5e(one_chip, monkeypatch):
     cfg = _deep10m_ivf_pq()
     n, d, k = (cfg["data"][key] for key in ("rows", "dim", "k"))
     lists, m = cfg["index"]["n_lists"], cfg["index"]["pq_dim"]
-    cap = int(np.ceil(ivf_pq.IvfPqIndexParams().list_cap_ratio * n / lists))
+    cap = ivf_pq.slab_slots(int(np.ceil(
+        ivf_pq.IvfPqIndexParams().list_cap_ratio * n / lists)))
+    assert cap == 3664
 
     def s(shape, dtype):
         return _spec(shape, dtype, one_chip)
@@ -296,17 +302,45 @@ def test_served_ivf_pq_with_refine_fits_one_v5e(one_chip, monkeypatch):
         s((lists, d), jnp.float32), s((m, 256, d // m), jnp.float32),
         s((lists, cap, m), jnp.uint8), s((lists, cap), jnp.float32),
         s((lists, cap), jnp.int32), s((lists,), jnp.int32), "sqeuclidean",
-        recon=s((lists, cap, d), jnp.bfloat16),
+        recon=s((lists, cap, ivf_pq.recon_lanes(d)), jnp.bfloat16),
         recon_norms=s((lists, cap), jnp.float32),
         centroid_lut=s((lists, m, 256), jnp.float32),
         adc_norms=s((lists, cap), jnp.float32))
     view = Refined(index, s((n, d), jnp.float32), cfg["refine_ratio"])
     fn, ops = make_searcher(view, k, ivf_pq.IvfPqSearchParams(
         **cfg["search"]))
-    compiled = jax.jit(fn).lower(s((512, d), jnp.float32), *ops).compile()
+    return jax.jit(fn).lower(s((rows, d), jnp.float32), *ops).compile()
+
+
+def test_served_ivf_pq_with_refine_fits_one_v5e(one_chip, monkeypatch):
+    """The served 512-row program of the ``deep10m-ivf_pq.batch`` cell:
+    the recon-tier PQ scan at 40 candidates and their exact re-rank over
+    the 10M × 96 f32 base, in one program, under one chip's 16 GB with
+    the index and the base as its operands."""
+    compiled = _served_ivf_pq(one_chip, monkeypatch, 512)
     mem = compiled.memory_analysis()
     if mem is not None:
         total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                  + mem.temp_size_in_bytes)
         # every operand is an argument: the base, the slabs, the tables
         assert 7.5e9 < total < 16e9, total
+
+
+@pytest.mark.parametrize("rows", [512, 1])
+def test_ivf_pq_grouped_search_compiles_for_one_v5e(one_chip, monkeypatch,
+                                                    rows):
+    """The served IVF-PQ program takes the list-major scan of the bf16
+    recon slab at 512 rows and, its lists being too large to gather
+    whole, at 1 row too: lowered through Mosaic as ``ivf_grouped_scan``.
+    The slab, stored at 3664 slots of 128 lanes, is read in place:
+    neither program copies or slices the whole of it."""
+    text = _served_ivf_pq(one_chip, monkeypatch, rows).as_text()
+    kernel = [line.lstrip() for line in text.splitlines()
+              if "tpu_custom_call" in line and "ivf_grouped_scan" in line]
+    assert kernel and kernel[0].startswith("%ivf_grouped_scan."), kernel
+    assert "mini-gather-slice" not in text
+    slab = "bf16[4096,3664,128]"
+    made = [line.split(" = ")[0].strip() for line in text.splitlines()
+            if f"= {slab}" in line and "parameter(" not in line
+            and "get-tuple-element" not in line]
+    assert not made, made
