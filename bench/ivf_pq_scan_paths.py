@@ -7,8 +7,8 @@ configuration's Gaussian mixture, and queries from the same mixture.  No
 index is built: the scan's cost depends on the slab's shape and on how
 the batch's probes spread over the lists, not on the codes.  Per batch
 size (the serving buckets 1, 8, 64, 512 rows, and 16 and 32 about the
-rule ``ivf_flat.grouped_batch``) it reports which scan the served
-program lowers (``raft_ivf_pq_scan_path_total``), and times the recon
+rule's batch clause) it reports which scan the served program lowers
+(``raft_ivf_scan_path_total{family="ivf_pq"}``), and times the recon
 tier's search at 40 candidates and 64 probes with the query-major scan
 (``scan_kernel="xla"``) and with the grouped scan whatever the rule
 says, each the median of ``--reps`` calls that end in
@@ -110,9 +110,10 @@ def main(argv=None) -> int:
         return 3
 
     from benchmark import mixture
-    from raft_tpu.neighbors import ivf_flat, ivf_pq
+    from raft_tpu.neighbors import ivf_pq
     from raft_tpu.neighbors._packing import resolve_probe_block
     from raft_tpu.obs.metrics import registry
+    from raft_tpu.ops.blocked_scan import scan_path
 
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "deep10m-ivf_pq.json")) as f:
@@ -148,7 +149,8 @@ def main(argv=None) -> int:
 
     def paths():
         return {lb["path"]: n for lb, n in registry().counter(
-            "raft_ivf_pq_scan_path_total").samples()}
+            "raft_ivf_scan_path_total").samples()
+            if lb["family"] == "ivf_pq"}
 
     for b in BUCKETS:
         q = queries[:b]
@@ -158,7 +160,10 @@ def main(argv=None) -> int:
         emit({"what": "served", "rows": b, "lowered": {
             p: after[p] - before.get(p, 0) for p in after
             if after[p] > before.get(p, 0)},
-            "rule_grouped": ivf_flat.grouped_batch(b, n_probes, n_lists)})
+            "rule": scan_path("auto", b, n_probes, n_lists, CAP,
+                              recon.shape[2], recon.dtype, k,
+                              grouped_dtype=jnp.bfloat16,
+                              gather_whole=True)})
         for path, call in (
                 ("query_major", lambda q=q: ivf_pq._search_recon_impl(
                     *ops, q, k, n_probes, "sqeuclidean", None, probe_block,
@@ -186,7 +191,7 @@ def main(argv=None) -> int:
     emit({"what": "counters", **{
         name: {",".join(f"{k}={v}" for k, v in labels.items()): n
                for labels, n in registry().counter(name).samples()}
-        for name in ("raft_ivf_pq_scan_path_total",
+        for name in ("raft_ivf_scan_path_total",
                      "raft_pallas_dispatch_total")}})
     if args.out:
         with open(args.out, "w") as f:

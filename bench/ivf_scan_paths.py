@@ -3,10 +3,10 @@
 Builds the SIFT-1M-class index of ``benchmark/configs/sift1m-ivf_flat.json``
 (1M × 128 f32 Gaussian-mixture rows, 1024 lists, 32 probes).  Per batch
 size (the serving buckets 1, 8, 64, 512 rows, and 16 and 32 about the
-rule ``ivf_flat.grouped_batch``) it reports which scan the served program
-lowered, and times one search with the query-major scan
-(``scan_kernel="xla"``) and one with the list-major grouped scan whatever
-the rule says, each the median of ``--reps`` calls that end in
+rule ``blocked_scan.scan_path``) it reports which scan the served program
+lowered (``raft_ivf_scan_path_total{family="ivf_flat"}``), and times one
+search with the query-major scan (``scan_kernel="xla"``) and one with
+the list-major grouped scan whatever the rule says, each the median of ``--reps`` calls that end in
 ``jax.block_until_ready``.  At 512 rows it also times the grouped scan at
 several tile heights,
 and checks the grouped answers against the query-major ones: ids equal
@@ -56,20 +56,20 @@ def _median_seconds(fn, reps: int) -> float:
 
 def _paths(registry) -> dict:
     return {labels["path"]: n for labels, n in
-            registry().counter("raft_ivf_scan_path_total").samples()}
+            registry().counter("raft_ivf_scan_path_total").samples()
+            if labels["family"] == "ivf_flat"}
 
 
 def _grouped_at(qt: int, k: int, n_probes: int):
     """The grouped search program at tile height ``qt`` (L2)."""
     from raft_tpu.distance.pairwise import sq_l2
-    from raft_tpu.neighbors.ivf_flat import slot_bias
     from raft_tpu.ops import blocked_scan as bs
 
     @jax.jit
     def run(q, centroids, data, ids, counts, norms):
         qn = bs.row_sq_norms(q)
         _, probes = jax.lax.top_k(-sq_l2(q, centroids), n_probes)
-        bias = slot_bias(norms, ids, counts, "sqeuclidean")
+        bias = bs.slot_bias(norms, ids, counts, "sqeuclidean")
         return bs.scan_topk_grouped(q, qn, data, bias, ids, probes, k,
                                     l2=True, qt=qt)
     return run

@@ -64,104 +64,10 @@ def kernel_sha() -> str:
                 "raft_tpu/ops/bin_select.py",
                 "raft_tpu/matrix/select_k.py",
                 "raft_tpu/ops/blocked_scan.py",
-                "raft_tpu/ops/pallas/fused_scan.py",
                 "raft_tpu/ops/pallas/gate.py"):
         with open(os.path.join(root, rel), "rb") as f:
             h.update(f.read())
     return h.hexdigest()[:16]
-
-
-# blocked-scan fused-arm sweep: candidates-per-block × k shape classes of
-# the IVF engines (probe_block · list_cap candidate lanes per scan step)
-SCAN_FAMILIES = ["ivf_flat", "ivf_pq"]
-SCAN_CANDS = [1024, 4096, 16384]
-SCAN_K = [8, 32, 128]
-
-
-def tune_fused_scan(quick: bool) -> None:
-    """Time the shared-core XLA slab scan against the fused Pallas arm
-    (``scan_topk_fused``) per family : candidates-per-block : k bucket and
-    write ``raft_tpu/ops/_scan_kernel_table.json`` —
-    ``blocked_scan.resolve_scan_kernel`` consults it (sha-scoped) when an
-    engine's ``scan_kernel="auto"``.  Off-TPU the fused arm runs the
-    interpret/fallback path, so the table lands in a backend-suffixed file
-    the production resolver never reads (and ``auto`` is gate-closed off
-    hardware anyway) — the sweep still exercises both arms as CI smoke."""
-    from raft_tpu.ops import blocked_scan as _scan
-
-    backend = jax.default_backend()
-    on_tpu = backend == "tpu"
-    nq, nblocks, d = (16, 2, 64) if not on_tpu else (256, 8, 128)
-    cands = [SCAN_CANDS[0]] if quick or not on_tpu else SCAN_CANDS
-    ks = SCAN_K[:2] if quick or not on_tpu else SCAN_K
-    key0 = jax.random.PRNGKey(1)
-    entries = {}
-    for family in SCAN_FAMILIES:
-        exact = family == "ivf_flat"
-        for c in cands:
-            data = jax.random.normal(key0, (nblocks * c, d), jnp.float32)
-            if not exact:  # recon tier scores a bf16 slab
-                data = data.astype(jnp.bfloat16)
-            q = jax.random.normal(key0, (nq, d), jnp.float32)
-            if not exact:
-                q = q.astype(jnp.bfloat16)
-            qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=1)
-            norms = jnp.sum(data.astype(jnp.float32) ** 2, axis=1)
-            rescore = _scan.l2_rescorer(data, norms, q, qn, "sqeuclidean",
-                                        exact=exact)
-            blocks_xs = jnp.arange(nblocks, dtype=jnp.int32)
-            lane = jnp.arange(c, dtype=jnp.int32)
-
-            # per-step gather from the shared slab — the engines' real
-            # dataflow (a pre-broadcast [nblocks, nq, c, d] would be tens
-            # of GB at production shapes)
-            def gather(blk):
-                vid = jnp.broadcast_to(blk * c + lane, (nq, c))
-                return data[vid], norms[vid], vid
-
-            for k in ks:
-                def run_xla():
-                    def score(blk):
-                        vecs, base, vid = gather(blk)
-                        dots = _scan.slab_dots(vecs[:, None], q,
-                                               exact=exact)
-                        return (base - 2.0 * dots.reshape(nq, c), vid)
-
-                    return _scan.scan_topk(score, blocks_xs, nq, k)
-
-                def run_fused():
-                    def slab_step(blk):
-                        vecs, base, vid = gather(blk)
-                        return vecs, base, vid, vid
-
-                    return _scan.scan_topk_fused(q, slab_step, blocks_xs,
-                                                 rescore, nq, k)
-
-                try:
-                    t_f = _time(run_fused)
-                except Exception as e:  # noqa: BLE001 — keep the xla arm
-                    print(f"  fused {family} c={c} k={k}: failed "
-                          f"({type(e).__name__})", file=sys.stderr)
-                    t_f = float("inf")
-                t_x = _time(run_xla)
-                key = f"{family}:{c.bit_length()}:{k.bit_length()}"
-                entries[key] = "fused" if t_f < t_x else "xla"
-                print(f"scan {family:8s} cands={c:6d} k={k:4d} → "
-                      f"{entries[key]} (xla {t_x * 1e3:.2f} ms, "
-                      f"fused {t_f * 1e3:.2f} ms)")
-    out = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                       "raft_tpu", "ops", "_scan_kernel_table.json")
-    if not on_tpu and "--force" not in sys.argv:
-        out = out.replace(".json", f".{backend}.json")
-        print(f"non-TPU backend: writing to {os.path.basename(out)} "
-              f"(--force overrides)", file=sys.stderr)
-    with open(out, "w") as f:
-        json.dump({"kernel_sha": _scan.scan_kernel_sha(),
-                   "backend": backend, "entries": entries},
-                  f, indent=1, sort_keys=True)
-        f.write("\n")
-    print(f"wrote {len(entries)} scan-kernel entries → "
-          f"{os.path.normpath(out)}")
 
 
 def main() -> None:
@@ -259,7 +165,6 @@ def main() -> None:
     except OSError:
         pass
     print(f"wrote {len(table)} entries → {os.path.normpath(out)}")
-    tune_fused_scan(quick)
 
 
 if __name__ == "__main__":

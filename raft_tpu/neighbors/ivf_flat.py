@@ -16,21 +16,21 @@ TPU-first design:
   (:func:`slab_capacity`), so a TPU stores the slab list-major and no
   search program relayouts it.
 * **Search**: query→centroid distances on the MXU, ``top_k`` probe pick,
-  then one of two probe scans, chosen from the platform and the index's
-  static shapes (:func:`resolve_scan`, ``scan_kernel="auto"``):
+  then one of two probe scans, chosen per compiled program from the
+  platform and its static shapes (``ops.blocked_scan.scan_path``,
+  ``scan_kernel="auto"``):
 
   - **grouped** (list-major) on a TPU for batches that probe a list
-    half a time or more on average (:func:`grouped_batch`), wherever
-    its kernel takes the input (:func:`grouped_takes`): the (query,
-    list) pairs are sorted by list and cut into tiles of 16 query slots;
-    one Pallas kernel scores each tile with one ``[16, d]·[d, cap]`` MXU
-    product at ``precision=HIGHEST`` and keeps its exact top-k, so a
-    list is read once per run of tiles instead of once per query
-    (``ops.blocked_scan.scan_topk_grouped``).  Same candidate set as
-    the query-major scan; distances within a few ulps.
+    half a time or more on average, wherever its kernel takes the f32
+    slab: the (query, list) pairs are sorted by list and cut into tiles
+    of 16 query slots; one Pallas kernel scores each tile with one
+    ``[16, d]·[d, cap]`` MXU product at ``precision=HIGHEST`` and keeps
+    its exact top-k, so a list is read once per run of tiles instead of
+    once per query (``ops.blocked_scan.scan_topk_grouped``).  Same
+    candidate set as the query-major scan; distances within a few ulps.
   - **query-major** off a TPU, for smaller batches, 8-bit and bf16
     slabs, per-query filter bitmaps, k > 128, lists too large for VMEM,
-    ``scan_kernel="xla"|"fused"`` and :func:`search_sharded`: one scan
+    ``scan_kernel="xla"`` and :func:`search_sharded`: one scan
     iteration per **probe block** of B probe ranks: one
     ``[nq, B·cap, d]`` slab gather, one batched dot, pads masked, ONE
     merge into the running top-k via ``select_k`` — ⌈n_probes/B⌉ merges
@@ -105,15 +105,12 @@ class IvfFlatSearchParams:
     # pure latency/throughput knob (docs/tuning_guide.md); the grouped
     # scan takes no probe blocks.
     probe_block: int = 0
-    # probe-scan engine: "auto" | "grouped" | "xla" | "fused".  "grouped"
-    # is the list-major scan (one MXU product per tile of queries sharing
-    # a list); "xla" is the bit-exact query-major two-pass scan; "fused"
-    # runs the Pallas distance+partial top-k kernel per block with an
-    # exact re-score of 4k finalists (recall-gated, not bit-pinned).
-    # "auto" takes "grouped" on a TPU wherever grouped_takes() allows it,
-    # else resolves through ops.blocked_scan.resolve_scan_kernel (tuned
-    # table), which is "xla" where no table says otherwise.  A "grouped"
-    # program scans batches too small for grouped_batch() query-major.
+    # probe-scan engine: "auto" | "grouped" | "xla".  "xla" is the
+    # bit-exact query-major scan; "grouped" forces the list-major scan
+    # (one MXU product per tile of queries sharing a list) on any
+    # platform; "auto" takes it on a TPU only.  Either way each compiled
+    # program decides by ops.blocked_scan.scan_path, and a batch too
+    # small for the grouped scan scans query-major.
     scan_kernel: str = "auto"
 
 
@@ -428,142 +425,36 @@ def extend(index: IvfFlatIndex, new_vectors, new_ids=None, *,
                         index.metric)
 
 
-#: the grouped scan's least share of probes per list in one batch,
-#: nq·P / L.  Measured on a v5e at 1024 lists × 32 probes (one search):
-#: the query-major scan is faster at 8 rows, 0.25 a list (1.58 against
-#: 1.94 ms), the grouped one from 16 rows, 0.5 a list (2.77 against
-#: 3.43 ms; 4.70 against 10.59 at 64 rows).  Below that most tiles hold
-#: a single live query slot.
-GROUPED_MIN_PROBES_PER_LIST = 0.5
-#: the grouped kernel keeps one whole list in VMEM, double-buffered
-GROUPED_MAX_LIST_BYTES = 4 << 20
-#: the grouped kernel's k min-extraction passes run per tile row
-GROUPED_MAX_K = 128
-
-
-def grouped_takes(cap: int, dim: int, k: int, dtype,
-                  keep_ndim: int = 0) -> bool:
-    """Whether the grouped kernel takes a search over an index's
-    ``[L, cap, dim]`` slab of ``dtype``: f32 or bf16 slabs whose lists
-    fit its VMEM block at their own itemsize, k ≤ ``GROUPED_MAX_K``, and
-    no per-query filter bitmap (``keep_ndim`` 2)."""
-    dt = jnp.dtype(dtype)
-    return not (dt not in (jnp.float32, jnp.bfloat16) or keep_ndim == 2
-                or k > GROUPED_MAX_K
-                or cap * dim * dt.itemsize > GROUPED_MAX_LIST_BYTES)
-
-
-def grouped_batch(nq: int, n_probes: int, n_lists: int) -> bool:
-    """Whether a batch of ``nq`` rows takes the grouped scan: where it
-    probes the index's ``n_lists`` lists ``GROUPED_MIN_PROBES_PER_LIST``
-    times or more a list on average.  A fleet
-    shard passes the whole index's ``n_lists`` and so decides as the
-    single-device program does."""
-    return nq * n_probes >= GROUPED_MIN_PROBES_PER_LIST * n_lists
-
-
-def resolve_scan(requested: str, index: IvfFlatIndex, k: int,
-                 probe_block: int, keep=None) -> str:
-    """The probe scan a search over ``index`` runs (:func:`pick_scan`).
-    IVF-Flat scores f32 queries at ``precision=HIGHEST``, so only its f32
-    slabs take the grouped kernel."""
-    takes = (index.data.dtype == jnp.float32
-             and grouped_takes(index.list_cap, index.dim, int(k),
-                               index.data.dtype,
-                               0 if keep is None else keep.ndim))
-    return pick_scan(requested, "ivf_flat", takes,
-                     probe_block * index.list_cap, k)
-
-
-def pick_scan(requested: str, family: str, takes: bool, n_candidates: int,
-              k: int) -> str:
-    """The probe scan of an IVF ``family``'s search.  ``"auto"`` takes the
-    grouped scan on a TPU where the kernel ``takes`` the index
-    (:func:`grouped_takes`), else what
-    ``ops.blocked_scan.resolve_scan_kernel`` picks for ``n_candidates`` a
-    query-major block.  Off a TPU the kernel runs interpreted, slower
-    than the XLA gather, so there the grouped scan runs only when asked
-    for by name.  ``"grouped"``, ``"xla"`` and ``"fused"`` as given.  A
-    ``"grouped"`` program scans a batch too small for
-    :func:`grouped_batch` query-major."""
-    from ..ops.blocked_scan import resolve_scan_kernel
-    from ..ops.pallas.gate import on_tpu
-
-    if requested == "grouped":
-        expects(takes, "the grouped scan takes IVF-Flat's f32 lists or the "
-                f"IVF-PQ recon tier's bf16 lists of at most "
-                f"{GROUPED_MAX_LIST_BYTES} bytes, k <= {GROUPED_MAX_K} and "
-                "no per-query bitmap")
-        return "grouped"
-    kernel = resolve_scan_kernel(requested, family, n_candidates, int(k))
-    return "grouped" if requested == "auto" and takes and on_tpu() else kernel
-
-
-def count_scan_path(path: str) -> None:
-    """Count one lowering of ``path`` in
-    ``raft_ivf_scan_path_total{path}`` (the program's trace runs once per
-    compiled program, so a chip run shows which buckets took which
-    path)."""
-    from ..obs.metrics import registry
-
-    registry().counter(
-        "raft_ivf_scan_path_total",
-        "IVF-Flat probe-scan lowerings by path",
-    ).inc(path=path)
-
-
-def slot_bias(norms, ids, counts, metric: str, keep=None):
-    """``[L, 1, cap]`` per-slot offset of the grouped scan: the stored
-    squared norm (L2 metrics) or 0 (inner product) where the slot holds
-    a live row (below ``counts`` where given, id ≥ 0, kept by ``keep``),
-    ``+inf`` where it does not — the masks of the query-major scan."""
-    from ._packing import keep_lookup
-
-    cap = ids.shape[1]
-    live = ids >= 0
-    if counts is not None:
-        live = live & (jnp.arange(cap)[None, :] < counts[:, None])
-    if keep is not None:
-        live = live & keep_lookup(keep, ids)
-    base = (jnp.zeros(norms.shape, jnp.float32) if metric == "inner_product"
-            else norms.astype(jnp.float32))
-    return jnp.where(live, base, jnp.inf)[:, None, :]
-
-
 def _probe_scan(q, qn, data, ids, counts, norms, probes, k: int, metric: str,
-                keep=None, probe_block: int = 1, scan_kernel: str = "xla"):
+                keep=None, probe_block: int = 1, path: str = "query_major"):
     """Scan the probed lists through the shared ``ops.blocked_scan`` core.
 
-    q: [nq, d]; probes: [nq, P].  ``scan_kernel="grouped"`` runs the
-    list-major scan (``blocked_scan.scan_topk_grouped``): pairs sorted by
-    list, one MXU product per tile of queries sharing a list, each list
-    read once per run of tiles; distances within a few ulps of the
-    query-major scan's, the same candidate set, and ``probe_block``
-    unused.
+    q: [nq, d]; probes: [nq, P].  ``path="grouped"`` runs the list-major
+    scan (``blocked_scan.scan_topk_grouped``): pairs sorted by list, one
+    MXU product per tile of queries sharing a list, each list read once
+    per run of tiles; distances within a few ulps of the query-major
+    scan's, the same candidate set, and ``probe_block`` unused.
 
-    The query-major scans gather, per iteration, the next B probed lists
-    of every query (one ``[nq, B·cap, d]`` slab), score it with
+    The query-major scan gathers, per iteration, the next B probed lists
+    of every query (one ``[nq, B·cap, d]`` slab), scores it with
     ``slab_dots`` (B pinned in the einsum's batch dims — the
     bit-invariance contract: results identical across block sizes) and
-    fold it into the running top-k — ⌈P/B⌉ merges instead of P.  Pad
+    folds it into the running top-k — ⌈P/B⌉ merges instead of P.  Pad
     probes (P not divisible by B) are masked to +inf, never duplicated.
-    ``keep``: optional bool prefilter by source id.  ``scan_kernel``:
-    ``"xla"`` (bit-exact two-pass) or ``"fused"`` (Pallas distance+partial
-    top-k in one kernel, exact re-score of 4k finalists — recall-gated,
-    not bit-pinned)."""
+    ``keep``: optional bool prefilter by source id."""
     from ..ops import blocked_scan as _scan
     from ._packing import blocked_probe_plan
 
     nq = q.shape[0]
     cap = data.shape[1]
-    if scan_kernel == "grouped":
-        bias = slot_bias(norms, ids, counts, metric, keep)
+    if path == "grouped":
+        bias = _scan.slot_bias(norms, ids, counts, metric, keep)
         return _scan.scan_topk_grouped(
             q.astype(jnp.float32), qn, data, bias, ids, probes, k,
             l2=metric != "inner_product")
     lists_xs, pvalid = blocked_probe_plan(probes, probe_block)
 
-    def gather(inp):
+    def score(inp):
         lists, pv = inp                           # [nq, B], [B]
         bcap = lists.shape[1] * cap
         vecs = data[lists]                        # [nq, B, cap, d] gather
@@ -575,26 +466,6 @@ def _probe_scan(q, qn, data, ids, counts, norms, probes, k: int, metric: str,
             from ._packing import keep_lookup
 
             valid = valid & keep_lookup(keep, vids)
-        return lists, vecs, vids, valid
-
-    if scan_kernel == "fused":
-        def slab_step(inp):
-            lists, vecs, vids, valid = gather(inp)
-            bcap = vids.shape[1]
-            if metric == "inner_product":
-                base = jnp.zeros((nq, bcap), jnp.float32)
-            else:
-                base = norms[lists].reshape(nq, bcap)
-            return (vecs.reshape(nq, bcap, vecs.shape[-1]),
-                    jnp.where(valid, base, jnp.inf), vids,
-                    _scan.list_slab_ptr(lists, cap))
-
-        rescore = _scan.l2_rescorer(data, norms, q, qn, metric)
-        return _scan.scan_topk_fused(q, slab_step, (lists_xs, pvalid),
-                                     rescore, nq, k)
-
-    def score(inp):
-        lists, vecs, vids, valid = gather(inp)
         dots = _scan.slab_dots(vecs, q).reshape(nq, -1)
         if metric == "inner_product":
             dist = -dots
@@ -612,20 +483,22 @@ def _probe_scan(q, qn, data, ids, counts, norms, probes, k: int, metric: str,
 def _search_impl(centroids, data, ids, counts, norms, q, k: int,
                  n_probes: int, metric: str, keep=None,
                  probe_block: int = 1, scan_kernel: str = "xla"):
-    from ..ops.blocked_scan import resolve_scan_kernel, row_sq_norms
+    from ..ops.blocked_scan import count_scan_path, row_sq_norms, scan_path
 
-    if scan_kernel == "grouped" and not grouped_batch(q.shape[0], n_probes,
-                                                      data.shape[0]):
-        scan_kernel = resolve_scan_kernel("auto", "ivf_flat",
-                                          probe_block * data.shape[1], k)
-    count_scan_path("grouped" if scan_kernel == "grouped" else "query_major")
+    # IVF-Flat scores at precision HIGHEST, which the grouped kernel
+    # keeps only for f32 lists
+    path = scan_path(scan_kernel, q.shape[0], n_probes, data.shape[0],
+                     data.shape[1], data.shape[2], data.dtype, k,
+                     0 if keep is None else keep.ndim,
+                     grouped_dtype=jnp.float32)
+    count_scan_path("ivf_flat", path)
     qf = q.astype(jnp.float32)
     qn = row_sq_norms(qf)   # dot-contraction: rounds the same in the
     # fleet's SPMD executable (serve bit-identity, ops.blocked_scan doc)
     cd = sq_l2(q, centroids)                      # [nq, L] MXU block
     _, probes = jax.lax.top_k(-cd, n_probes)      # nearest lists
     bv, bi = _probe_scan(q, qn, data, ids, counts, norms, probes, k, metric,
-                         keep, probe_block, scan_kernel)
+                         keep, probe_block, path)
     if metric == "euclidean":
         bv = jnp.sqrt(jnp.maximum(bv, 0.0))
     elif metric == "inner_product":
@@ -656,12 +529,11 @@ def search(index: IvfFlatIndex, queries, k: int,
     keep = as_keep_mask(filter, nq=q.shape[0])  # indexes source ids
     if keep is not None:
         check_filter_covers_ids(keep, index.ids)
-    scan_kernel = resolve_scan(p.scan_kernel, index, k, probe_block, keep)
 
     impl = lambda qc, kc: _search_impl(
         index.centroids, index.data, index.ids, index.counts,
         index.norms, qc, int(k), int(n_probes), index.metric, kc,
-        probe_block, scan_kernel)
+        probe_block, p.scan_kernel)
     dv, di = chunked_filtered_queries(impl, q, int(p.query_chunk), keep)
     if keep is not None:  # sub-k survivors: sentinel tail, not real ids
         di = sentinel_filtered_ids(dv, di)
@@ -694,7 +566,6 @@ def searcher(index: IvfFlatIndex, k: int,
                                       index.list_cap, "ivf_flat")
     metric = index.metric
     keep = as_keep_mask(filter)
-    scan_kernel = resolve_scan(p.scan_kernel, index, k, probe_block, keep)
     if keep is not None:
         expects(keep.ndim == 1,
                 "serving filters are shared bitsets (1-D); per-query "
@@ -704,7 +575,7 @@ def searcher(index: IvfFlatIndex, k: int,
         def fn(q, centroids, data, ids, counts, norms, kp):
             dv, di = _search_impl(centroids, data, ids, counts, norms, q,
                                   int(k), n_probes, metric, kp, probe_block,
-                                  scan_kernel)
+                                  p.scan_kernel)
             return dv, sentinel_filtered_ids(dv, di)
 
         return fn, (index.centroids, index.data, index.ids, index.counts,
@@ -713,7 +584,7 @@ def searcher(index: IvfFlatIndex, k: int,
     def fn(q, centroids, data, ids, counts, norms):
         return _search_impl(centroids, data, ids, counts, norms, q,
                             int(k), n_probes, metric, None, probe_block,
-                            scan_kernel)
+                            p.scan_kernel)
 
     return fn, (index.centroids, index.data, index.ids, index.counts,
                 index.norms)

@@ -14,7 +14,7 @@ configs (``BASELINE.json``: ivf_pq on DEEP-10M) and standard IVF-PQ
     kept separately).  Search scores the probed lists with one bf16 MXU
     pass — ``‖q−x̂‖² = ‖q‖² − 2⟨q,x̂⟩ + ‖x̂‖²`` — so the hot loop is a
     dense bf16 contraction, the shape TPUs are built for.  On a TPU a
-    batch takes the list-major *grouped* scan (:func:`grouped_recon_batch`):
+    batch takes the list-major *grouped* scan (``blocked_scan.scan_path``):
     the (query, list) pairs are sorted by list and one Pallas kernel
     scores each tile of 16 queries against its list in one product and
     keeps its top-k, so a list is read once per run of tiles
@@ -67,8 +67,7 @@ from ..core.compat import shard_map
 from ..core.errors import expects
 from ..distance.pairwise import sq_l2
 from ._packing import chunked_filtered_queries, pack_lists
-from .ivf_flat import (grouped_batch, grouped_takes, pick_scan,
-                       slab_capacity, slot_bias)
+from .ivf_flat import slab_capacity
 
 __all__ = [
     "IvfPqIndexParams",
@@ -115,10 +114,9 @@ class IvfPqSearchParams:
     # table via bench/tune_probe_block.py, else a working-set heuristic).
     # Bit-identical results at every value — a pure speed knob.
     probe_block: int = 0
-    # recon-tier scan kernel: "auto" | "grouped" | "xla" | "fused" — same
-    # contract as IvfFlatSearchParams.scan_kernel, over the bf16 slab.
-    # The LUT tier has no distance einsum to fuse and always runs the
-    # XLA scan.
+    # recon-tier scan kernel: "auto" | "grouped" | "xla" — same contract
+    # as IvfFlatSearchParams.scan_kernel, over the bf16 slab.  The LUT
+    # tier always runs the query-major XLA scan.
     scan_kernel: str = "auto"
 
 
@@ -475,52 +473,6 @@ def count_search(tier: str, refine: bool) -> None:
         "raft_ivf_pq_search_total",
         "IVF-PQ serving programs compiled, by tier and exact re-rank",
     ).inc(tier=tier, refine="1" if refine else "0")
-
-
-def count_scan_path(path: str) -> None:
-    """Count one lowering of the recon tier's probe scan in
-    ``raft_ivf_pq_scan_path_total{path}``, the sibling of IVF-Flat's
-    ``raft_ivf_scan_path_total`` (one count per compiled program)."""
-    from ..obs.metrics import registry
-
-    registry().counter(
-        "raft_ivf_pq_scan_path_total",
-        "IVF-PQ recon-tier probe-scan lowerings by path",
-    ).inc(path=path)
-
-
-#: elements (rows × stored lanes) of the largest list the TPU's compiler
-#: gathers whole.  Past it, the query-major scan's gather is cut into
-#: pieces that each first slice the whole slab, at every scan step
-#: (compiled for v5e: 2048 × 128 gathers whole, 2064 × 128 and
-#: 2048 × 256 in pieces)
-GATHER_WHOLE_ELEMS = 2048 * 128
-
-
-def grouped_recon_batch(nq: int, n_probes: int, n_lists: int, cap: int,
-                        lanes: int) -> bool:
-    """Whether a recon-tier batch of ``nq`` rows over a
-    ``[n_lists, cap, lanes]`` slab takes the grouped scan: where it
-    probes each list half a time or more (``ivf_flat.grouped_batch``),
-    and at any size where a list is too large for the TPU to gather
-    whole (:data:`GATHER_WHOLE_ELEMS`): there the query-major scan
-    copies the slab at every step (on a v5e over 4096 lists of
-    3664 × 128, 187 ms a search at 1 row against 2.3 ms grouped)."""
-    return (grouped_batch(nq, n_probes, n_lists)
-            or cap * lanes > GATHER_WHOLE_ELEMS)
-
-
-def recon_scan(requested: str, index: IvfPqIndex, k: int, probe_block: int,
-               keep=None) -> str:
-    """The recon tier's probe scan (``ivf_flat.pick_scan``): ``"auto"``
-    takes the grouped scan on a TPU where the kernel takes the bf16 slab
-    (``ivf_flat.grouped_takes``); a program whose batch
-    :func:`grouped_recon_batch` leaves out scans query-major."""
-    takes = grouped_takes(index.list_cap, index.dim, int(k),
-                          index.recon.dtype,
-                          0 if keep is None else keep.ndim)
-    return pick_scan(requested, "ivf_pq", takes,
-                     probe_block * index.list_cap, k)
 
 
 def _stage(name: str, fn, *args, **kwargs):
@@ -887,78 +839,53 @@ def _search_recon_impl(centroids, recon, recon_norms, ids, q,
     from ._packing import blocked_probe_plan
 
     nq, d = q.shape
-    n_lists, cap = recon.shape[0], recon.shape[1]
-    if scan_kernel == "grouped" and not grouped_recon_batch(
-            nq, n_probes, n_lists, cap, recon.shape[2]):
-        scan_kernel = _scan.resolve_scan_kernel("auto", "ivf_pq",
-                                                probe_block * cap, k)
-    count_scan_path("grouped" if scan_kernel == "grouped" else "query_major")
+    n_lists, cap, dp = recon.shape                # dp = recon_lanes(d)
+    # the recon tier's contract is one bf16 MXU pass over its bf16 slab;
+    # a list too large to gather whole scans grouped at any batch size
+    path = _scan.scan_path(scan_kernel, nq, n_probes, n_lists, cap, dp,
+                           recon.dtype, k, 0 if keep is None else keep.ndim,
+                           grouped_dtype=jnp.bfloat16, gather_whole=True)
+    _scan.count_scan_path("ivf_pq", path)
     qf = q.astype(jnp.float32)
     qn = _scan.row_sq_norms(qf)
-    dp = recon.shape[2]                           # recon_lanes(d)
     qb = jnp.pad(q.astype(jnp.bfloat16), ((0, 0), (0, dp - d)))
     cd = sq_l2(q, centroids)                      # [nq, L]
     _, probes = jax.lax.top_k(-cd, n_probes)
-    if scan_kernel == "grouped":
+    if path == "grouped":
         # list-major: each probed list read once per run of 16-query
         # tiles; recon_norms' +inf pads mask L2, ids and keep mask both
-        bias = slot_bias(recon_norms, ids, None, metric, keep)
+        bias = _scan.slot_bias(recon_norms, ids, None, metric, keep)
         bv, bi = _scan.scan_topk_grouped(
             qb, qn, recon, bias, ids, probes, k,
             l2=metric != "inner_product", clamp=False)
         return _finish(bv, bi, metric)
     lists_xs, pvalid = blocked_probe_plan(probes, probe_block)
 
-    def gather(inp):
+    def score(inp):
         lists, pv = inp                           # [nq, B], [B]
         bcap = lists.shape[1] * cap
         slab = recon[lists]                       # one [nq, B, cap, d] gather
         vids = ids[lists].reshape(nq, bcap)
-        return lists, pv, slab, vids
-
-    def mask(dist, lists, pv, vids):
+        # B stays in slab_dots' *batch* dims so the inner [cap, d]·[d]
+        # contraction — and with it the f32 accumulation order — is
+        # identical for every probe_block (the bit-parity contract);
+        # exact=False keeps the recon tier's single bf16 MXU pass.
+        dots = _scan.slab_dots(slab, qb, exact=False).reshape(nq, bcap)
+        if metric == "inner_product":
+            dist = jnp.where(vids >= 0, -dots, jnp.inf)
+        else:
+            # recon_norms carries +inf on pad entries — they self-mask
+            dist = qn[:, None] - 2.0 * dots + recon_norms[lists].reshape(
+                nq, bcap)
         # pad probes (n_probes % B != 0) contribute nothing
         dist = jnp.where(jnp.repeat(pv, cap)[None, :], dist, jnp.inf)
         if keep is not None:  # prefilter by source id (True = keep)
             from ._packing import keep_lookup
 
             dist = jnp.where(keep_lookup(keep, vids), dist, jnp.inf)
-        return dist
+        return dist, vids
 
-    if scan_kernel == "fused":
-        def slab_step(inp):
-            lists, pv, slab, vids = gather(inp)
-            bcap = vids.shape[1]
-            if metric == "inner_product":
-                base = jnp.where(vids >= 0, 0.0, jnp.inf)
-            else:
-                # recon_norms carries +inf on pad entries — they self-mask
-                base = recon_norms[lists].reshape(nq, bcap)
-            return (slab.reshape(nq, bcap, dp), mask(base, lists, pv, vids),
-                    vids, _scan.list_slab_ptr(lists, cap))
-
-        rescore = _scan.l2_rescorer(recon, recon_norms, qb, qn, metric,
-                                    exact=False, clamp=False)
-        bv, bi = _scan.scan_topk_fused(qb, slab_step, (lists_xs, pvalid),
-                                       rescore, nq, k)
-    else:
-        def score(inp):
-            lists, pv, slab, vids = gather(inp)
-            # B stays in slab_dots' *batch* dims so the inner [cap, d]·[d]
-            # contraction — and with it the f32 accumulation order — is
-            # identical for every probe_block (the bit-parity contract);
-            # exact=False keeps the recon tier's single bf16 MXU pass.
-            dots = _scan.slab_dots(slab, qb, exact=False).reshape(
-                nq, vids.shape[1])
-            if metric == "inner_product":
-                dist = jnp.where(vids >= 0, -dots, jnp.inf)
-            else:
-                # recon_norms carries +inf on pad entries — they self-mask
-                dist = qn[:, None] - 2.0 * dots + recon_norms[lists].reshape(
-                    nq, dots.shape[1])
-            return mask(dist, lists, pv, vids), vids
-
-        bv, bi = _scan.scan_topk(score, (lists_xs, pvalid), nq, k)
+    bv, bi = _scan.scan_topk(score, (lists_xs, pvalid), nq, k)
     return _finish(bv, bi, metric)
 
 
@@ -1072,11 +999,10 @@ def search(index: IvfPqIndex, queries, k: int,
         expects(index.recon is not None,
                 "mode='recon' needs the reconstruction slab — call "
                 "index.with_recon() (e.g. after load_index)")
-        scan_kernel = recon_scan(p.scan_kernel, index, k, probe_block, keep)
         impl = lambda qc, kc: _search_recon_impl(
             index.centroids, index.recon, index.recon_norms, index.ids,
             qc, int(k), int(n_probes), index.metric, kc, probe_block,
-            scan_kernel)
+            p.scan_kernel)
     else:
         # legacy/hand-built indexes without the hoisted-ADC tables:
         # derive them here (per call — materialize with with_adc_luts()
@@ -1127,14 +1053,13 @@ def searcher(index: IvfPqIndex, k: int,
         expects(index.recon is not None,
                 "mode='recon' needs the reconstruction slab — call "
                 "index.with_recon() (e.g. after load_index)")
-        scan_kernel = recon_scan(p.scan_kernel, index, k, probe_block, keep)
         if keep is not None:
 
             def fn(q, centroids, recon, recon_norms, ids, kp):
                 dv, di = _search_recon_impl(centroids, recon, recon_norms,
                                             ids, q, int(k), n_probes,
                                             metric, kp, probe_block,
-                                            scan_kernel)
+                                            p.scan_kernel)
                 return dv, sentinel_filtered_ids(dv, di)
 
             return fn, (index.centroids, index.recon, index.recon_norms,
@@ -1143,7 +1068,7 @@ def searcher(index: IvfPqIndex, k: int,
         def fn(q, centroids, recon, recon_norms, ids):
             return _search_recon_impl(centroids, recon, recon_norms, ids,
                                       q, int(k), n_probes, metric, None,
-                                      probe_block, scan_kernel)
+                                      probe_block, p.scan_kernel)
 
         return fn, (index.centroids, index.recon, index.recon_norms,
                     index.ids)

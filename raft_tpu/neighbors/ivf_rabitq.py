@@ -47,9 +47,7 @@ sign code rank; ``sabs ≤ 0`` degenerates to the centroid distance).
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
-from functools import lru_cache, partial
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -96,18 +94,15 @@ class IvfRabitqSearchParams:
     n_probes: int = 32
     # exact-rerank candidate count: the estimator scan keeps this many
     # best estimates (unsorted fold), survivors re-score exactly.  0 =
-    # auto (recall-gated tuned table via bench/tune_rabitq.py, else a
-    # heuristic).  rerank_k = index.size makes results bit-identical to
-    # brute force; this is THE recall knob (docs/tuning_guide.md).
+    # auto (max(32, 8·k)).  rerank_k = index.size makes results
+    # bit-identical to brute force; this is THE recall knob
+    # (docs/tuning_guide.md).
     rerank_k: int = 0
     query_chunk: int = 4096  # cap on the per-dispatch gather working set
-    # probes gathered+scored+merged per scan step; 0 = auto (rabitq
-    # tuned table, else the shared probe_block table/heuristic).
-    # Results are bit-identical for every value — pure speed knob.
+    # probes gathered+scored+merged per scan step; 0 = auto (the shared
+    # probe_block table/heuristic).  Results are bit-identical for every
+    # value — pure speed knob.
     probe_block: int = 0
-    # blocked-scan engine hook: "auto" | "xla".  The estimator scan has
-    # no fused Pallas arm yet (ROADMAP A5), so "fused" is refused.
-    scan_kernel: str = "auto"
 
 
 @jax.tree_util.register_dataclass
@@ -512,22 +507,18 @@ def _estimate_scan(q, qf, qn, cd, centroids, rotation, codes, sabs,
                                      sabs, res_norms, code_cdots, ids,
                                      counts, probes, rerank_k, metric,
                                      keep, probe_block)
-    rescore = _scan.l2_rescorer(data, None, q, qn, metric)
+    rescore = _scan.l2_rescorer(data, q, qn, metric)
     dist = rescore(bp, bi)
     dist = jnp.where(jnp.isfinite(bv) & (bi >= 0), dist, jnp.inf)
     return _scan.ranked_finish(dist, bi, k)
 
 
 @partial(jax.jit, static_argnames=("k", "n_probes", "rerank_k", "metric",
-                                   "probe_block", "scan_kernel"))
+                                   "probe_block"))
 def _search_impl(centroids, rotation, codes, sabs, res_norms, code_cdots,
                  data, ids, counts, q, k: int, n_probes: int,
                  rerank_k: int, metric: str, keep=None,
-                 probe_block: int = 1, scan_kernel: str = "xla"):
-    # scan_kernel rides the static signature so a future fused estimator
-    # kernel slots in without an API change; both "xla" and "fused"
-    # dispatch the XLA estimator scan today (gate.py resolves cleanly).
-    del scan_kernel
+                 probe_block: int = 1):
     qf = q.astype(jnp.float32)
     qn = _scan.row_sq_norms(qf)   # dot-contraction; bit-stable across
     # the single-device and fleet SPMD executables (serve bit-identity)
@@ -543,89 +534,34 @@ def _search_impl(centroids, rotation, codes, sabs, res_norms, code_cdots,
     return bv, bi
 
 
-@lru_cache(maxsize=1)
-def _rabitq_tune_table():
-    """Recall-gated (rerank_k, probe_block) table written by
-    ``bench/tune_rabitq.py``.  Canonical name first; a
-    ``.{backend}.json`` suffix holds off-TPU measurements.  A table
-    whose ``kernel_sha`` doesn't match the current scan sources is stale
-    and ignored (the estimator path lives in ``ops/blocked_scan.py``)."""
-    base = os.path.join(os.path.dirname(__file__), "_rabitq_tune_table")
-    for path in (base + ".json", base + f".{jax.default_backend()}.json"):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            continue
-        if doc.get("kernel_sha") != _scan.scan_kernel_sha():
-            from ..core.logging import default_logger
-
-            default_logger().info(
-                "rabitq tune table %s is sha-stale (table %s, sources %s); "
-                "falling back to heuristics", os.path.basename(path),
-                doc.get("kernel_sha"), _scan.scan_kernel_sha())
-            continue
-        return doc.get("entries", {})
-    return {}
-
-
-def _tune_entry(k: int, n_probes: int, cap: int) -> dict:
-    return _rabitq_tune_table().get(
-        f"ivf_rabitq:{int(k).bit_length()}:{int(n_probes).bit_length()}"
-        f":{int(cap).bit_length()}", {})
-
-
 def resolve_rerank_k(requested: int, k: int, n_probes: int,
                      cap: int) -> int:
     """Static exact-rerank width.  ``requested > 0`` wins (must be ≥ k);
-    ``0`` = auto: the recall-gated tuned table (log2-bucketed by
-    ``(k, n_probes, cap)``, written by ``bench/tune_rabitq.py``), else a
-    ``8·k`` heuristic.  Unlike ``probe_block`` this knob changes RESULTS
-    (it gates the candidate set) — which is why the tuner behind the
-    table is recall-gated, exactly the ``resolve_cagra_search`` model.
-    Clamped to the probed-candidate total.  Pure host-int arithmetic."""
+    ``0`` = auto: ``max(32, 8·k)``.  Unlike ``probe_block`` this knob
+    changes RESULTS (it gates the candidate set).  Clamped to the
+    probed-candidate total.  Pure host-int arithmetic."""
     total = max(1, int(n_probes) * int(cap))
     if requested:
         expects(int(requested) >= int(k),
                 f"rerank_k ({requested}) must be >= k ({k})")
         return max(int(k), min(int(requested), total))
-    entry = _tune_entry(k, n_probes, cap).get("rerank_k")
-    if entry is None:
-        entry = max(32, 8 * int(k))
-    return max(int(k), min(int(entry), total))
+    return max(int(k), min(max(32, 8 * int(k)), total))
 
 
-def _resolve_probe_block(requested: int, n_probes: int, cap: int,
-                         k: int) -> int:
-    """probe_block with the rabitq tuned table consulted first (the
-    packed-code gather moves 32× fewer bytes per probe, so the speed
-    optimum differs from the flat families'), falling back to the shared
-    :func:`~._packing.resolve_probe_block` table/heuristic."""
-    from ._packing import resolve_probe_block
-
-    if requested:
-        return resolve_probe_block(requested, n_probes, cap, "ivf_rabitq")
-    entry = _tune_entry(k, n_probes, cap).get("probe_block")
-    if entry is not None:
-        return max(1, min(int(entry), max(1, n_probes)))
-    return resolve_probe_block(0, n_probes, cap, "ivf_rabitq")
-
-
-def _resolved_static(index, k: int, p) -> Tuple[int, int, int, str]:
+def _resolved_static(index, k: int, p) -> Tuple[int, int, int]:
     """The shared search/searcher static-knob resolution: (n_probes,
-    probe_block, rerank_k, scan_kernel).  Also serves the out-of-core
-    tier (:mod:`~raft_tpu.neighbors.ooc`), whose device half is this
+    probe_block, rerank_k).  Also serves the out-of-core tier
+    (:mod:`~raft_tpu.neighbors.ooc`), whose device half is this
     family's estimator scan — ``index`` only needs ``n_lists`` and
     ``list_cap``."""
+    from ._packing import resolve_probe_block
+
     n_probes = int(min(p.n_probes, index.n_lists))
-    probe_block = _resolve_probe_block(p.probe_block, n_probes,
-                                       index.list_cap, int(k))
+    probe_block = resolve_probe_block(p.probe_block, n_probes,
+                                      index.list_cap, "ivf_rabitq")
     rerank_k = resolve_rerank_k(p.rerank_k, int(k), n_probes,
                                 index.list_cap)
-    expects(p.scan_kernel in ("auto", "xla"),
-            f"ivf_rabitq has no fused estimator scan kernel; scan_kernel "
-            f"must be auto|xla, got {p.scan_kernel!r}")
-    return n_probes, probe_block, rerank_k, "xla"
+    return n_probes, probe_block, rerank_k
 
 
 @tracing.annotate("ivf_rabitq.search")
@@ -642,8 +578,7 @@ def search(index: IvfRabitqIndex, queries, k: int,
     p = params or IvfRabitqSearchParams()
     q = wrap_array(queries, ndim=2, name="queries")
     expects(q.shape[1] == index.dim, "query dim mismatch")
-    n_probes, probe_block, rerank_k, scan_kernel = _resolved_static(
-        index, k, p)
+    n_probes, probe_block, rerank_k = _resolved_static(index, k, p)
     keep = as_keep_mask(filter, nq=q.shape[0])  # indexes source ids
     if keep is not None:
         check_filter_covers_ids(keep, index.ids)
@@ -652,7 +587,7 @@ def search(index: IvfRabitqIndex, queries, k: int,
         index.centroids, index.rotation, index.codes, index.sabs,
         index.res_norms, index.code_cdots, index.data, index.ids,
         index.counts, qc, int(k), n_probes, rerank_k, index.metric, kc,
-        probe_block, scan_kernel)
+        probe_block)
     dv, di = chunked_filtered_queries(impl, q, int(p.query_chunk), keep)
     if keep is not None:  # sub-k survivors: sentinel tail, not real ids
         di = sentinel_filtered_ids(dv, di)
@@ -673,8 +608,7 @@ def searcher(index: IvfRabitqIndex, k: int,
 
     p = params or IvfRabitqSearchParams()
     expects(k >= 1, "k must be >= 1")
-    n_probes, probe_block, rerank_k, scan_kernel = _resolved_static(
-        index, k, p)
+    n_probes, probe_block, rerank_k = _resolved_static(index, k, p)
     metric = index.metric
     keep = as_keep_mask(filter)
     if keep is not None:
@@ -688,7 +622,7 @@ def searcher(index: IvfRabitqIndex, k: int,
             dv, di = _search_impl(centroids, rotation, codes, sabs,
                                   res_norms, code_cdots, data, ids, counts,
                                   q, int(k), n_probes, rerank_k, metric,
-                                  kp, probe_block, scan_kernel)
+                                  kp, probe_block)
             return dv, sentinel_filtered_ids(dv, di)
 
         return fn, (index.centroids, index.rotation, index.codes,
@@ -699,8 +633,7 @@ def searcher(index: IvfRabitqIndex, k: int,
            data, ids, counts):
         return _search_impl(centroids, rotation, codes, sabs, res_norms,
                             code_cdots, data, ids, counts, q, int(k),
-                            n_probes, rerank_k, metric, None, probe_block,
-                            scan_kernel)
+                            n_probes, rerank_k, metric, None, probe_block)
 
     return fn, (index.centroids, index.rotation, index.codes, index.sabs,
                 index.res_norms, index.code_cdots, index.data, index.ids,

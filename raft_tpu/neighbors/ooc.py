@@ -108,14 +108,12 @@ class OocIndexParams:
 class OocSearchParams:
     n_probes: int = 32
     # exact-rerank candidate count (estimator survivors fetched from the
-    # host store per query).  0 = auto (the rabitq recall-gated tuned
-    # table, else heuristic).  rerank_k = index.size is bit-identical to
+    # host store per query).  0 = auto (ivf_rabitq's max(32, 8·k)).  rerank_k = index.size is bit-identical to
     # brute force.  Unlike ivf_rabitq this is ALSO the bus knob: each
     # query moves rerank_k · d · itemsize bytes up per search.
     rerank_k: int = 0
     query_chunk: int = 1024   # queries per cooperative step (cap)
     probe_block: int = 0      # 0 = auto; bit-identical for every value
-    scan_kernel: str = "auto"  # "auto" | "xla" (no fused estimator scan)
     # hard cap on ONE staged rerank slab, in bytes: the query chunk
     # shrinks until chunk · rerank_k · d · itemsize fits.  Peak device
     # memory = codes slabs + (overlap+1) slabs under this budget — the
@@ -410,15 +408,11 @@ def _build_chunked_perop(dataset, params: Optional[OocIndexParams] = None,
 
 
 @partial(jax.jit, static_argnames=("n_probes", "rerank_k", "metric",
-                                   "probe_block", "scan_kernel"))
+                                   "probe_block"))
 def _survivors_impl(centroids, rotation, codes, sabs, res_norms,
                     code_cdots, ids, counts, q, n_probes: int,
                     rerank_k: int, metric: str, keep=None,
-                    probe_block: int = 1, scan_kernel: str = "xla"):
-    # scan_kernel rides the static signature exactly like ivf_rabitq's
-    # _search_impl; both arms dispatch the XLA estimator scan today (the
-    # "fused" request is counted at resolve time)
-    del scan_kernel
+                    probe_block: int = 1):
     qf = q.astype(jnp.float32)
     cd = sq_l2(q, centroids)
     _, probes = jax.lax.top_k(-cd, n_probes)
@@ -440,7 +434,7 @@ def _rerank_core(slab, bv, bi, q, k: int, metric: str):
     flat = slab.reshape(nq * rk, slab.shape[-1])
     qf = q.astype(jnp.float32)
     qn = _scan.row_sq_norms(qf)
-    rescore = _scan.l2_rescorer(flat, None, q, qn, metric)
+    rescore = _scan.l2_rescorer(flat, q, qn, metric)
     ptr = jnp.arange(nq * rk, dtype=jnp.int32).reshape(nq, rk)
     dist = rescore(ptr, bi)
     dist = jnp.where(jnp.isfinite(bv) & (bi >= 0), dist, jnp.inf)
@@ -457,9 +451,8 @@ _rerank_impl = partial(jax.jit, static_argnames=("k", "metric"))(
 
 
 def _resolved_static(index: OocIndex, k: int, p: OocSearchParams):
-    """(n_probes, probe_block, rerank_k, scan_kernel) — ivf_rabitq's
-    resolution verbatim (same estimator scan, same tuned table, and the
-    same refusal of a "fused" request)."""
+    """(n_probes, probe_block, rerank_k) — ivf_rabitq's resolution
+    verbatim (same estimator scan)."""
     return _rq._resolved_static(index, k, p)
 
 
@@ -495,8 +488,7 @@ def search(index: OocIndex, queries, k: int,
     expects(k >= 1, "k must be >= 1")
     q = wrap_array(queries, ndim=2, name="queries")
     expects(q.shape[1] == index.dim, "query dim mismatch")
-    n_probes, probe_block, rerank_k, scan_kernel = _resolved_static(
-        index, k, p)
+    n_probes, probe_block, rerank_k = _resolved_static(index, k, p)
     keep = as_keep_mask(filter)
     if keep is not None:
         expects(keep.ndim == 1,
@@ -526,8 +518,7 @@ def search(index: OocIndex, queries, k: int,
             bv, bi = _survivors_impl(
                 index.centroids, index.rotation, index.codes, index.sabs,
                 index.res_norms, index.code_cdots, index.ids, index.counts,
-                qd, n_probes, rerank_k, metric, keep, probe_block,
-                scan_kernel)
+                qd, n_probes, rerank_k, metric, keep, probe_block)
             bi_h = np.asarray(jax.device_get(bi))  # jaxlint: disable=JX01 phase boundary: survivor ids must reach the host to address the shard store
         with rec.span("ooc.fetch", rows=int(bi_h.size)):
             slab_h = pool.acquire((chunk, rerank_k, d), store.dtype)
@@ -588,8 +579,7 @@ def searcher(index: OocIndex, k: int,
 
     p = params or OocSearchParams()
     expects(k >= 1, "k must be >= 1")
-    n_probes, probe_block, rerank_k, scan_kernel = _resolved_static(
-        index, k, p)
+    n_probes, probe_block, rerank_k = _resolved_static(index, k, p)
     metric = index.metric
     store = index.store
     d = index.dim
@@ -612,7 +602,7 @@ def searcher(index: OocIndex, k: int,
         bv, bi = _survivors_impl(centroids, rotation, codes, sabs,
                                  res_norms, code_cdots, ids, counts, q,
                                  n_probes, rerank_k, metric, kp,
-                                 probe_block, scan_kernel)
+                                 probe_block)
         slab = jax.pure_callback(
             fetch_host,
             jax.ShapeDtypeStruct((q.shape[0], rerank_k, d), store.dtype),

@@ -19,16 +19,9 @@ module owns the contract:
   the per-item reference engines for ANY block size).
 * :func:`fold_topk` / :func:`fold_topk_payload` — the
   ``select_k(sorted=False)`` fold, without and with payload lanes
-  (CAGRA's explored flags, the fused path's slab pointers).
+  (CAGRA's explored flags, IVF-RaBitQ's slab pointers).
 * :func:`scan_topk` — the ``scan(carry, slab) -> carry`` driver: carry
   init, per-block fold, ranked exit selection.
-* :func:`scan_topk_fused` — the same contract with the distance tile and
-  an approximate partial top-k fused into ONE Pallas kernel
-  (``ops/pallas/fused_scan.py``, TPU-KNN's PartialReduce scheme), plus an
-  exact re-score of 4k finalists so reported distances stay f32-exact.
-  Approximate-partial: the candidate *set* is recall-gated, not
-  bit-pinned (a true neighbor is shed only on a ≥3-way lane-bucket
-  collision within one slab block).
 * :func:`scan_topk_grouped` — the list-major scan of IVF-Flat and of the
   IVF-PQ recon tier, for batches that share lists: :func:`grouped_plan`
   sorts the (query, list) pairs by list into tiles of
@@ -40,6 +33,9 @@ module owns the contract:
   within a few f32 ulps of the query-major ones (only the dot's
   accumulation order differs), and results independent of
   ``probe_block``.
+* :func:`scan_path` — the ONE rule that picks between the two probe
+  scans, made while a search program is traced, from its static shapes
+  and the platform.
 
 Quantized-scan sub-API
 ----------------------
@@ -71,10 +67,7 @@ owned by the scan core, and ``ops`` must not import from ``neighbors``.
 
 from __future__ import annotations
 
-import json
-import os
-from functools import lru_cache
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -84,10 +77,9 @@ __all__ = ["int8_tier_eligible", "exact_gathered_dots", "slab_dots",
            "unpack_sign_bits", "packed_sign_dots",
            "row_sq_norms",
            "fold_topk", "fold_topk_payload", "topk_carry", "ranked_finish",
-           "scan_topk", "scan_topk_fused", "grouped_plan",
-           "grouped_tile_bound", "scan_topk_grouped", "list_slab_ptr",
-           "l2_rescorer",
-           "resolve_scan_kernel", "scan_kernel_sha"]
+           "scan_topk", "grouped_plan", "grouped_tile_bound",
+           "scan_topk_grouped", "slot_bias", "scan_path",
+           "count_scan_path", "list_slab_ptr", "l2_rescorer"]
 
 
 def int8_tier_eligible(a, b, d: int) -> bool:
@@ -252,7 +244,7 @@ def fold_topk(best_val, best_idx, tile_val, tile_idx, k: int, *,
 def fold_topk_payload(best_val, best_idx, best_payload: Sequence,
                       tile_val, tile_idx, tile_payload: Sequence, k: int):
     """:func:`fold_topk` with payload lanes riding the selection (CAGRA's
-    explored flags, the fused path's slab pointers, build's counts).
+    explored flags, IVF-RaBitQ's slab pointers, build's counts).
 
     Selects by *concat position*, then gathers ids and every payload lane
     through the winning positions — bit-identical to the direct
@@ -307,56 +299,6 @@ def scan_topk(score_step: Callable, xs, nq: int, k: int, *,
 
     (bv, bi), _ = jax.lax.scan(step, topk_carry(nq, k, id_fill=id_fill), xs)
     return ranked_finish(bv, bi, k)
-
-
-#: finalists :func:`scan_topk_fused` carries per requested neighbour.  Its
-#: fold ranks by the kernel's bf16 surrogate; carrying only k finalists
-#: kept 0.902 of the exact scan's ids on SIFT-1M-class blobs (norms ~65;
-#: my chip run, PR 21), because bf16 error there spans several neighbours'
-#: distance gaps.  The exact re-score of 4k finalists restores the order.
-FUSED_FINALISTS = 4
-
-
-def scan_topk_fused(q, slab_step: Callable, xs, rescore: Callable,
-                    nq: int, k: int, *, shortlist_block: int = 512,
-                    interpret: Optional[bool] = None
-                    ) -> Tuple[jax.Array, jax.Array]:
-    """Fused-kernel blocked scan: each block's distance tile and an
-    approximate partial top-k run INSIDE one Pallas kernel
-    (:func:`raft_tpu.ops.pallas.fused_scan.fused_slab_topk`), so the
-    ``[nq, L]`` distance block never materializes in HBM.
-
-    ``slab_step(slab_inputs) -> (vecs [nq, C, d], base [nq, C],
-    vids [nq, C], ptr [nq, C])`` gathers the slab and computes the
-    surrogate base (``‖y‖²``-like per-candidate offset; invalid lanes
-    ``+inf``); the kernel scores ``base − 2·⟨q, vec⟩``.  ``ptr`` is an
-    engine-defined storage pointer payload lane carried through the fold
-    so ``rescore(ptr [nq, kc], vids [nq, kc]) -> dist [nq, kc]`` can
-    re-gather the ``kc = FUSED_FINALISTS·k`` finalists and re-score them
-    exactly before the top k are ranked — reported values match the
-    engine's exact metric; only the candidate *set* is approximate
-    (recall-gated, not bit-pinned)."""
-    kc = FUSED_FINALISTS * k
-
-    def step(carry, inp):
-        from .pallas.fused_scan import fused_slab_topk
-
-        bv, bi, bp = carry
-        vecs, base, vids, ptr = slab_step(inp)
-        sv, spos = fused_slab_topk(vecs, base, q, bn=shortlist_block,
-                                   interpret=interpret)
-        svids = jnp.take_along_axis(vids, spos, axis=1)
-        sptr = jnp.take_along_axis(ptr, spos, axis=1)
-        mv, mi, (mp,) = fold_topk_payload(bv, bi, (bp,), sv, svids, (sptr,),
-                                          kc)
-        return (mv, mi, mp), None
-
-    bv0, bi0 = topk_carry(nq, kc)
-    bp0 = jnp.zeros((nq, kc), jnp.int32)
-    (bv, bi, bp), _ = jax.lax.scan(step, (bv0, bi0, bp0), xs)
-    dist = rescore(bp, bi)
-    dist = jnp.where(jnp.isfinite(bv) & (bi >= 0), dist, jnp.inf)
-    return ranked_finish(dist, bi, k)
 
 
 #: query slots of one list-major tile: the rows of one MXU product
@@ -427,7 +369,7 @@ def scan_topk_grouped(qf, qn, data, bias, ids, lists, k: int, *, l2: bool,
     The candidate set is the query-major scan's: every live row of every
     probed list, scored by the same algebra at the same precision
     (``precision=HIGHEST`` for f32, one bf16 pass for bf16), with L2
-    floored at 0 under ``clamp`` as :func:`l2_rescorer` floors it.
+    floored at 0 under ``clamp`` as IVF-Flat's query-major scan floors it.
     Only the dot's accumulation order differs (one MXU product per tile,
     not a per-query mat-vec), so distances agree within a few f32 ulps
     and ids agree wherever no two candidates tie that closely.  Results
@@ -458,111 +400,141 @@ def scan_topk_grouped(qf, qn, data, bias, ids, lists, k: int, *, l2: bool,
     return bv, jnp.where(bp >= 0, ids.reshape(-1)[jnp.maximum(bp, 0)], -1)
 
 
+#: the grouped scan's least share of probes per list in one batch,
+#: nq·P / L.  Measured on a v5e at 1024 lists × 32 probes (one search):
+#: the query-major scan is faster at 8 rows, 0.25 a list (1.58 against
+#: 1.94 ms), the grouped one from 16 rows, 0.5 a list (2.77 against
+#: 3.43 ms; 4.70 against 10.59 at 64 rows).  Below that most tiles hold
+#: a single live query slot.
+GROUPED_MIN_PROBES_PER_LIST = 0.5
+#: the grouped kernel keeps one whole list in VMEM, double-buffered
+GROUPED_MAX_LIST_BYTES = 4 << 20
+#: the grouped kernel's k min-extraction passes run per tile row
+GROUPED_MAX_K = 128
+#: elements (rows × stored lanes) of the largest list the TPU's compiler
+#: gathers whole.  Past it, the query-major scan's gather is cut into
+#: pieces that each first slice the whole slab, at every scan step
+#: (compiled for v5e: 2048 × 128 gathers whole, 2064 × 128 and
+#: 2048 × 256 in pieces)
+GATHER_WHOLE_ELEMS = 2048 * 128
+#: the values of an IVF search's ``scan_kernel``
+SCAN_KERNELS = ("auto", "grouped", "xla")
+
+
+def slot_bias(norms, ids, counts, metric: str, keep=None):
+    """``[L, 1, cap]`` per-slot offset of the grouped scan: the stored
+    squared norm (L2 metrics) or 0 (inner product) where the slot holds
+    a live row (below ``counts`` where given, id ≥ 0, kept by the 1-D
+    ``keep`` over source ids), ``+inf`` where it does not — the masks of
+    the query-major scan."""
+    cap = ids.shape[1]
+    live = ids >= 0
+    if counts is not None:
+        live = live & (jnp.arange(cap)[None, :] < counts[:, None])
+    if keep is not None:
+        live = live & keep[jnp.maximum(ids, 0)]
+    base = (jnp.zeros(norms.shape, jnp.float32) if metric == "inner_product"
+            else norms.astype(jnp.float32))
+    return jnp.where(live, base, jnp.inf)[:, None, :]
+
+
+def scan_path(requested: str, nq: int, n_probes: int, n_lists: int,
+              cap: int, lanes: int, dtype, k: int, keep_ndim: int = 0, *,
+              grouped_dtype, gather_whole: bool = False) -> str:
+    """The probe scan of one compiled IVF search program: ``"grouped"``
+    (:func:`scan_topk_grouped`) or ``"query_major"``.  Called while the
+    program is traced, where the batch ``nq`` and every shape are static.
+
+    The grouped kernel takes a ``[n_lists, cap, lanes]`` slab of
+    ``grouped_dtype`` — the one dtype the caller's grouped scan scores
+    as its query-major scan does (IVF-Flat: f32 at ``HIGHEST``; the
+    IVF-PQ recon tier: its bf16 slab) — whose list fits
+    ``GROUPED_MAX_LIST_BYTES``, with k ≤ ``GROUPED_MAX_K`` and no
+    per-query filter bitmap (``keep_ndim`` 2).  Where it takes the
+    slab, a batch scans grouped if it probes each list
+    ``GROUPED_MIN_PROBES_PER_LIST`` times or more on average, or, under
+    ``gather_whole``, at any size where a list exceeds
+    ``GATHER_WHOLE_ELEMS``: there the query-major gather copies the slab
+    at every step (on a v5e over 4096 bf16 lists of 3664 × 128, 187 ms a
+    search at 1 row against 2.3 ms grouped).  A fleet shard passes the
+    whole index's ``n_lists`` and so decides as one device does.
+
+    ``requested``: ``"auto"`` takes the grouped scan only on a TPU (off
+    it the kernel runs interpreted, slower than the XLA gather);
+    ``"grouped"`` takes it on any platform and raises where the kernel
+    cannot take the slab; ``"xla"`` always scans query-major."""
+    from ..core.errors import expects
+    from .pallas.gate import on_tpu
+
+    expects(requested in SCAN_KERNELS,
+            f"scan_kernel must be auto|grouped|xla, got {requested!r}")
+    if requested == "xla":
+        return "query_major"
+    dt = jnp.dtype(dtype)
+    takes = (dt == jnp.dtype(grouped_dtype) and keep_ndim != 2
+             and k <= GROUPED_MAX_K
+             and cap * lanes * dt.itemsize <= GROUPED_MAX_LIST_BYTES)
+    if requested == "grouped":
+        expects(takes, "the grouped scan takes IVF-Flat's f32 lists or the "
+                f"IVF-PQ recon tier's bf16 lists of at most "
+                f"{GROUPED_MAX_LIST_BYTES} bytes, k <= {GROUPED_MAX_K} and "
+                "no per-query bitmap")
+    elif not (takes and on_tpu()):
+        return "query_major"
+    grouped = (nq * n_probes >= GROUPED_MIN_PROBES_PER_LIST * n_lists
+               or (gather_whole and cap * lanes > GATHER_WHOLE_ELEMS))
+    return "grouped" if grouped else "query_major"
+
+
+def count_scan_path(family: str, path: str) -> None:
+    """Count one lowering of an IVF ``family``'s probe scan in
+    ``raft_ivf_scan_path_total{family,path}`` (a program's trace runs
+    once per compiled program, so a chip run shows which buckets took
+    which path)."""
+    from ..obs.metrics import registry
+
+    registry().counter(
+        "raft_ivf_scan_path_total",
+        "IVF probe-scan lowerings by family and path",
+    ).inc(family=family, path=path)
+
+
 def list_slab_ptr(lists, cap: int):
     """Storage pointers for a gathered ``[nq, B]`` list block over a
     ``[L, cap, …]`` slab: flat row ``list·cap + slot``, shaped
     ``[nq, B·cap]`` to match the block's candidate lanes — the payload
-    lane :func:`scan_topk_fused` carries so ``rescore`` can re-gather
-    finalists from the flattened slab."""
+    lane a scan carries so :func:`l2_rescorer` can re-gather its
+    survivors from the flattened slab."""
     nq, b = lists.shape
     slot = jnp.arange(cap, dtype=jnp.int32)
     return (lists[:, :, None].astype(jnp.int32) * cap
             + slot[None, None, :]).reshape(nq, b * cap)
 
 
-def l2_rescorer(data, norms, q, qn, metric: str, *, exact: bool = True,
-                clamp: bool = True) -> Callable:
-    """Build the ``rescore(ptr, vids)`` closure for an IVF-style fused
-    scan: re-gather the finalist rows from the flattened ``[L·cap, d]``
-    slab and re-score them with the engine's exact metric algebra
-    (``exact=True`` → :func:`exact_gathered_dots` tiering; ``exact=False``
-    → the recon tier's single bf16 MXU pass).  ``clamp`` matches each
-    engine's squared-L2 floor convention (IVF-Flat clamps at 0, the recon
-    tier does not).
-
-    ``norms=None`` is the stored-norm-free form (the RaBitQ exact-rerank
-    tier keeps no norm slab): the squared norms recompute from the
-    gathered rows and the algebra runs in ``brute_force``'s accumulation
-    order (``qn + yn − 2·dots``, clamped) — f32 addition is not
-    associative, and matching the oracle's order is what lets a
+def l2_rescorer(data, q, qn, metric: str) -> Callable:
+    """Build the ``rescore(ptr, vids)`` closure of an exact re-rank:
+    re-gather the survivor rows from the flattened ``[L·cap, d]`` slab
+    and re-score them with :func:`exact_gathered_dots`.  The squared
+    norms recompute from the gathered rows (the RaBitQ exact-rerank tier
+    keeps no norm slab) and the algebra runs in ``brute_force``'s
+    accumulation order (``qn + yn − 2·dots``, clamped) — f32 addition is
+    not associative, and matching the oracle's order is what lets a
     rerank-everything search bit-match ``brute_force.knn``."""
     flat_data = data.reshape(-1, data.shape[-1])
-    flat_norms = norms.reshape(-1) if norms is not None else None
 
     def rescore(ptr, _vids):
-        rows = flat_data[ptr]                     # [nq, kc, d] finalists
-        if exact:
-            dots = exact_gathered_dots("qkd,qd->qk", rows, q)
-        else:
-            dots = jnp.einsum("qkd,qd->qk", rows, q,
-                              preferred_element_type=jnp.float32)
+        rows = flat_data[ptr]                     # [nq, kc, d] survivors
+        dots = exact_gathered_dots("qkd,qd->qk", rows, q)
         if metric == "inner_product":
             return -dots
-        if flat_norms is None:  # brute-force order, see docstring
-            rf = rows.astype(jnp.float32)
-            yn = row_sq_norms(rf)
-            dist = qn[:, None] + yn - 2.0 * dots
-        else:
-            dist = flat_norms[ptr] - 2.0 * dots + qn[:, None]
-        return jnp.maximum(dist, 0.0) if clamp else dist
+        yn = row_sq_norms(rows.astype(jnp.float32))
+        return jnp.maximum(qn[:, None] + yn - 2.0 * dots, 0.0)
 
     return rescore
 
 
-def scan_kernel_sha() -> str:
-    """Hash of the fused-path sources — scopes the tuned scan-kernel table
-    (``bench/tune_select_k.py`` writes it, :func:`resolve_scan_kernel`
-    rejects a table whose sha no longer matches the kernels it measured)."""
-    import hashlib
-
-    root = os.path.dirname(os.path.abspath(__file__))
-    h = hashlib.sha256()
-    for rel in ("blocked_scan.py", os.path.join("pallas", "fused_scan.py"),
-                os.path.join("pallas", "gate.py")):
-        with open(os.path.join(root, rel), "rb") as f:
-            h.update(f.read())
-    return h.hexdigest()[:16]
-
-
-@lru_cache(maxsize=1)
 def _scan_kernel_table():
-    """Measured xla-vs-fused table written by the ``bench/tune_select_k.py``
-    fused arm.  Canonical name first; a ``.{backend}.json`` suffix holds
-    off-TPU measurements.  A table whose ``kernel_sha`` doesn't match the
-    current fused-path sources is stale and ignored."""
-    base = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "_scan_kernel_table.json")
-    for path in (base, base.replace(".json",
-                                    f".{jax.default_backend()}.json")):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            continue
-        if doc.get("kernel_sha") != scan_kernel_sha():
-            from ..core.logging import default_logger
-
-            default_logger().info(
-                "scan-kernel table %s is sha-stale (table %s, sources %s); "
-                "auto keeps the XLA path", os.path.basename(path),
-                doc.get("kernel_sha"), scan_kernel_sha())
-            continue
-        return doc.get("entries", {})
+    """No tuned scan-kernel table exists.  Kept by name for
+    ``benchmark/tests/test_compile_v5e.py``'s ``as_on_chip`` fixture,
+    which patches it and is its only reader."""
     return {}
-
-
-def resolve_scan_kernel(requested: str, family: str, n_candidates: int,
-                        k: int) -> str:
-    """Resolve the engine ``scan_kernel`` knob to ``"xla"`` or ``"fused"``.
-
-    ``"auto"`` picks fused only where the sha-scoped tuned table for this
-    backend says fused wins for this ``family : candidates-per-block : k``
-    bucket; with no table, or no entry, it picks the XLA path."""
-    from ..core.errors import expects
-
-    expects(requested in ("auto", "xla", "fused"),
-            f"scan_kernel must be auto|xla|fused, got {requested!r}")
-    if requested != "auto":
-        return requested
-    key = f"{family}:{int(n_candidates).bit_length()}:{int(k).bit_length()}"
-    return _scan_kernel_table().get(key, "xla")

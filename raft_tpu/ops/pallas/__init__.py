@@ -15,7 +15,5 @@ LocalCUDACluster analog).
 from .gate import interpret, on_tpu
 from .select_k import select_k_pallas
 from .fused_l2_topk import fused_shortlist
-from .fused_scan import fused_slab_topk
 
-__all__ = ["select_k_pallas", "fused_shortlist", "fused_slab_topk",
-           "interpret", "on_tpu"]
+__all__ = ["select_k_pallas", "fused_shortlist", "interpret", "on_tpu"]

@@ -133,18 +133,16 @@ def _brute_fleet_program(mesh: Mesh, axis: str, k: int, metric: str,
 @lru_cache(maxsize=32)
 def _ivf_flat_fleet_program(mesh: Mesh, axis: str, k: int, n_probes: int,
                             metric: str, probe_block: int, lp: int,
-                            has_keep: bool, grouped: bool, n_lists: int):
+                            has_keep: bool, scan_kernel: str, n_lists: int):
     """shard_map'd IVF-Flat fan-out: replicated (padded) centroid table
     ranks the SAME global probe list everywhere; each shard scans only
     the probed lists it owns (owned-mask, not clamp-and-count) and the
     merge is one all_gather + ranked finish.
 
-    ``grouped``: the program resolved to the grouped scan, as the
-    single-device one does (``ivf_flat.resolve_scan``); a batch takes it
-    where ``ivf_flat.grouped_batch`` says so for the whole index's
-    ``n_lists``, as on one device.  A shard's plan drops the pairs of
-    lists it does not own, and the pairs it keeps form the single-device
-    tiles."""
+    The probe scan is ``blocked_scan.scan_path``'s for ``scan_kernel``
+    over the whole index's ``n_lists``, as on one device.  A grouped
+    shard's plan drops the pairs of lists it does not own, and the
+    pairs it keeps form the single-device tiles."""
 
     def local(q, cen, data, ids, counts, norms, *rest):
         keep = rest[0] if has_keep else None
@@ -156,11 +154,14 @@ def _ivf_flat_fleet_program(mesh: Mesh, axis: str, k: int, n_probes: int,
         _, probes = jax.lax.top_k(-cd, n_probes)  # pads rank last
         shard = jax.lax.axis_index(axis)
         lo = shard * lp
-        on_grouped = grouped and _ivf.grouped_batch(nq, n_probes, n_lists)
-        _ivf.count_scan_path("grouped" if on_grouped else "query_major")
-        if on_grouped:
+        path = _scan.scan_path(scan_kernel, nq, n_probes, n_lists, cap,
+                               data.shape[2], data.dtype, k,
+                               1 if has_keep else 0,
+                               grouped_dtype=jnp.float32)
+        _scan.count_scan_path("ivf_flat", path)
+        if path == "grouped":
             owned = (probes >= lo) & (probes < lo + lp)
-            bias = _ivf.slot_bias(norms, ids, counts, metric, keep)
+            bias = _scan.slot_bias(norms, ids, counts, metric, keep)
             bv, bi = _scan.scan_topk_grouped(
                 qf, qn, data, bias, ids, jnp.clip(probes - lo, 0, lp - 1), k,
                 l2=metric != "inner_product", pair_valid=owned)
@@ -313,7 +314,7 @@ def _rabitq_fleet_program(mesh: Mesh, axis: str, k: int, n_probes: int,
         sids = jnp.take_along_axis(ai, spos, axis=1)
         sptr = jnp.take_along_axis(ap, spos, axis=1)
         sowner = spos // rerank_k
-        rescore = _scan.l2_rescorer(data, None, q, qn, metric)
+        rescore = _scan.l2_rescorer(data, q, qn, metric)
         dist = rescore(sptr, sids)
         mine = (sowner == shard) & jnp.isfinite(sv) & (sids >= 0)
         dist = jnp.where(mine, dist, jnp.inf)
@@ -355,11 +356,10 @@ def make_fleet_searcher(index, k: int, params=None, *, mesh: Mesh,
     (the replica server caches them so the degradation ladder's levels
     share device slabs instead of re-slicing per level).
 
-    IVF-Flat fan-out dispatches the grouped scan where
-    ``scan_kernel="auto"`` takes it on one device
-    (``ivf_flat.resolve_scan``), else the bit-exact ``"xla"`` blocked
-    scan; ``brute_force`` ``mode="fast"`` is rejected — its approximate
-    shortlist cannot be bit-pinned across shard boundaries.
+    IVF-Flat fan-out scans as the single-device program does
+    (``blocked_scan.scan_path``); ``brute_force`` ``mode="fast"`` is
+    rejected — its approximate shortlist cannot be bit-pinned across
+    shard boundaries.
     ``seed`` is accepted for signature parity (no stochastic family is
     fleet-enabled yet)."""
     del seed
@@ -412,12 +412,10 @@ def make_fleet_searcher(index, k: int, params=None, *, mesh: Mesh,
         n_probes = int(min(p.n_probes, index.n_lists))
         probe_block = resolve_probe_block(p.probe_block, n_probes,
                                           index.list_cap, "ivf_flat")
-        grouped = _ivf.resolve_scan(p.scan_kernel, index, k, probe_block,
-                                    keep_arr) == "grouped"
         prog = _ivf_flat_fleet_program(mesh, axis, int(k), n_probes,
                                        index.metric, probe_block,
                                        sl.lists_per, keep_arr is not None,
-                                       grouped, sl.n_lists)
+                                       p.scan_kernel, sl.n_lists)
         ops = (sl.centroids, sl.data, sl.ids, sl.counts, sl.norms)
         if keep_arr is not None:
             kp = jax.device_put(keep_arr, rep)
@@ -441,7 +439,7 @@ def make_fleet_searcher(index, k: int, params=None, *, mesh: Mesh,
             check_filter_covers_ids(keep_arr, index.ids)
         # statics resolve on the UNSHARDED index — same n_probes /
         # rerank_k the single-device searcher would pick
-        n_probes, probe_block, rerank_k, _ = _irq._resolved_static(
+        n_probes, probe_block, rerank_k = _irq._resolved_static(
             index, k, p)
         sl = slices if slices is not None else _irq.fleet_slices(
             index, mesh, axis=axis)

@@ -13,11 +13,8 @@ test_cagra_frontier / test_neighbors):
   winning positions;
 * **filter-mask compose** — +inf'd lanes never surface, a fully-masked
   block is a no-op on the carry;
-* **fused-kernel parity** — ``fused_slab_topk`` under ``interpret=True``
-  (the CPU parity mode) shortlists a superset of the true top-k, and
-  ``scan_topk_fused``'s exact re-score returns the reference answer;
 * **dispatch gate** — the platform alone picks Mosaic or interpret
-  mode, and ``scan_kernel="auto"`` follows the tuned table;
+  mode;
 * **steady state** — alternating warm scan specializations neither
   re-traces nor transfers.
 """
@@ -31,10 +28,8 @@ import numpy as np
 import pytest
 
 from raft_tpu.core import TraceGuard
-from raft_tpu.core.errors import LogicError
 from raft_tpu.ops import blocked_scan as bs
 from raft_tpu.ops.pallas import gate as gate_mod
-from raft_tpu.ops.pallas.fused_scan import fused_slab_topk
 
 NQ, D, K = 8, 24, 10
 
@@ -184,62 +179,6 @@ def test_topk_carry_id_fill():
 
 
 # ---------------------------------------------------------------------------
-# fused Pallas arm: interpret-mode parity on CPU
-
-
-def test_fused_slab_topk_interpret_shortlists_true_topk(corpus):
-    data, q = corpus
-    c = 640  # not a multiple of bn: exercises the +inf candidate pad
-    vecs = jnp.broadcast_to(data[:c][None], (NQ, c, D))
-    base = jnp.broadcast_to(
-        jnp.sum(data[:c].astype(jnp.float32) ** 2, axis=1)[None], (NQ, c))
-    sv, spos = fused_slab_topk(vecs, base, q, bn=256, interpret=True)
-    assert sv.shape == spos.shape == (NQ, 512)
-    assert (np.asarray(spos) >= 0).all() and (np.asarray(spos) < c).all()
-    # shortlist ⊇ exact top-k of the same bf16 surrogate distances
-    d2 = np.asarray(base - 2.0 * jnp.einsum(
-        "qcd,qd->qc", vecs.astype(jnp.bfloat16), q.astype(jnp.bfloat16),
-        preferred_element_type=jnp.float32))
-    true = np.argsort(d2, axis=1, kind="stable")[:, :K]
-    got = np.asarray(spos)
-    rec = np.mean([len(set(t) & set(s)) for t, s in zip(true, got)]) / K
-    assert rec == 1.0, f"shortlist recall {rec}"
-
-
-def test_scan_topk_fused_interpret_matches_reference(corpus):
-    """End-to-end fused scan under interpret=True: the exact re-score must
-    return the reference ids and exact (f32) values at recall 1 on this
-    well-separated corpus."""
-    data, q = corpus
-    n = data.shape[0]
-    n_blocks = 3
-    c = n // n_blocks
-    norms = jnp.sum(data.astype(jnp.float32) ** 2, axis=1)
-    qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=1)
-    lane = jnp.arange(c, dtype=jnp.int32)
-
-    def slab_step(blk):
-        vid = jnp.broadcast_to(blk * c + lane, (NQ, c))
-        return data[vid], norms[vid], vid, vid
-
-    rescore = bs.l2_rescorer(data, norms, q, qn, "sqeuclidean")
-    gv, gi = bs.scan_topk_fused(q, slab_step,
-                                jnp.arange(n_blocks, dtype=jnp.int32),
-                                rescore, NQ, K, interpret=True)
-    rv, ri = _reference_topk(data, q, K)
-    rec = np.mean([len(set(map(int, a)) & set(map(int, b))) / K
-                   for a, b in zip(ri, np.asarray(gi))])
-    assert rec == 1.0, f"fused recall {rec}"
-    # values are exact per the rescore algebra (norms − 2·dots + qn)
-    want = rv + np.asarray(qn)[:, None]
-    order = np.argsort(np.asarray(gi), axis=1)
-    worder = np.argsort(ri, axis=1)
-    np.testing.assert_allclose(
-        np.take_along_axis(np.asarray(gv), order, axis=1),
-        np.take_along_axis(want, worder, axis=1), rtol=1e-5, atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
 # dispatch gate: the platform alone decides
 
 
@@ -261,27 +200,6 @@ def test_gate_resolves_from_platform(monkeypatch, backend, interpret):
     assert gate_mod.on_tpu() is (backend == "tpu")
     assert gate_mod.interpret("select_k") is interpret
     assert _dispatch_count("select_k", mode) == before + 1
-
-
-# ---------------------------------------------------------------------------
-# resolve_scan_kernel
-
-
-def test_resolve_scan_kernel_passthrough_and_validation():
-    assert bs.resolve_scan_kernel("xla", "ivf_flat", 4096, 10) == "xla"
-    assert bs.resolve_scan_kernel("fused", "ivf_pq", 4096, 10) == "fused"
-    with pytest.raises(LogicError):
-        bs.resolve_scan_kernel("mosaic", "ivf_flat", 4096, 10)
-
-
-@pytest.mark.parametrize("table,want", [
-    ({}, "xla"),                                   # no table: XLA
-    ({"ivf_pq:13:4": "fused"}, "xla"),             # no entry: XLA
-    ({"ivf_flat:13:4": "fused"}, "fused"),         # the table decides
-])
-def test_resolve_scan_kernel_auto_follows_table(monkeypatch, table, want):
-    monkeypatch.setattr(bs, "_scan_kernel_table", lambda: table)
-    assert bs.resolve_scan_kernel("auto", "ivf_flat", 4096, 10) == want
 
 
 # ---------------------------------------------------------------------------

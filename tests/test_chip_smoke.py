@@ -57,7 +57,7 @@ def test_device_check_refuses_cpu(smoke, multichip):
 
 
 def test_single_chip_phases(smoke, as_mosaic, capsys, monkeypatch):
-    monkeypatch.setattr(smoke, "FUSED_QUERIES", 32)  # interpret is slow
+    monkeypatch.setattr(smoke, "GROUPED_QUERIES", 32)  # interpret is slow
     dep = smoke.Deployment(rows=20_000, dim=128, queries=600, n_lists=32,
                            n_probes=8)
     smoke.run_single(dep, seed=0)
@@ -67,7 +67,7 @@ def test_single_chip_phases(smoke, as_mosaic, capsys, monkeypatch):
     for ln in lines:
         assert "error" not in ln, ln
     by = {ln["phase"]: ln for ln in lines}
-    assert by["ivf_flat"]["fused_vs_xla_recall"] >= smoke.FUSED_AGREE
+    assert by["ivf_flat"]["grouped_vs_xla_recall"] >= smoke.GROUPED_AGREE
     assert by["ivf_pq"]["recall"] >= smoke.IVF_RECALL
 
 

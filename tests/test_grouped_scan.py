@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from raft_tpu.core.errors import LogicError
 from raft_tpu.neighbors import ivf_flat
 from raft_tpu.obs.metrics import MetricRegistry, set_registry
 from raft_tpu.ops import blocked_scan as bs
@@ -183,58 +184,163 @@ def test_grouped_plan_stays_within_bound(qt):
                 np.zeros((4, 3), bool))
 
 
+#: the SIFT-1M-class cell's IVF-Flat program at 512 rows: 32 probes over
+#: 1024 lists of 1960 × 128 f32 rows, k = 10, on a TPU: grouped
+_FLAT = dict(requested="auto", nq=512, n_probes=32, n_lists=1024, cap=1960,
+             lanes=128, dtype=jnp.float32, k=10, keep_ndim=0,
+             grouped_dtype=jnp.float32, gather_whole=False, tpu=True)
+#: the DEEP-10M-class cell's recon tier: 64 probes over 4096 bf16 lists
+#: of 3664 × 128, 40 candidates (k·ratio), gather-whole clause on
+_PQ = dict(_FLAT, n_probes=64, n_lists=4096, cap=3664, dtype=jnp.bfloat16,
+           k=40, grouped_dtype=jnp.bfloat16, gather_whole=True)
+G, Q = "grouped", "query_major"
+_SCAN_PATH_CASES = {
+    "platform_cpu": (dict(tpu=False), Q),
+    "platform_tpu": ({}, G),
+    "slab_f32": (dict(dtype=jnp.float32), G),
+    "slab_bf16_on_ivf_flat": (dict(dtype=jnp.bfloat16), Q),
+    "slab_int8": (dict(dtype=jnp.int8), Q),
+    "slab_bf16_on_recon": (dict(dtype=jnp.bfloat16,
+                                grouped_dtype=jnp.bfloat16), G),
+    "filter_1d": (dict(keep_ndim=1), G),
+    "bitmap_2d": (dict(keep_ndim=2), Q),
+    "k128": (dict(k=128), G),
+    "k129": (dict(k=129), Q),
+    "list_at_4MiB": (dict(cap=8192), G),
+    "list_over_4MiB": (dict(cap=8200), Q),
+    "bf16_list_at_4MiB": (dict(_PQ, cap=16384), G),
+    "bf16_list_over_4MiB": (dict(_PQ, cap=16400), Q),
+    "batch_just_under_half_a_list": (dict(nq=15), Q),
+    "batch_at_half_a_list": (dict(nq=16), G),
+    "recon_under_gather_whole_one_row": (dict(_PQ, nq=1, cap=2040), Q),
+    "recon_at_gather_whole_one_row": (dict(_PQ, nq=1, cap=2048), Q),
+    "recon_over_gather_whole_one_row": (dict(_PQ, nq=1, cap=2056), G),
+    "recon_wide_rows_one_row": (dict(_PQ, nq=1, cap=2048, lanes=256), G),
+    "flat_has_no_gather_whole_clause": (dict(nq=1, cap=3664), Q),
+    "xla_forced": (dict(requested="xla"), Q),
+    "grouped_forced_on_cpu": (dict(requested="grouped", tpu=False), G),
+    "grouped_forced_small_batch": (dict(requested="grouped", nq=1), Q),
+    "grouped_refused_bf16_on_ivf_flat": (
+        dict(requested="grouped", dtype=jnp.bfloat16), LogicError),
+    "grouped_refused_bitmap": (dict(requested="grouped", keep_ndim=2),
+                               LogicError),
+    "grouped_refused_k129": (dict(requested="grouped", k=129), LogicError),
+    "fused_refused": (dict(requested="fused"), LogicError),
+    # the benchmark's cells, bucket by bucket
+    "sift1m_ivf_flat_nq1": (dict(nq=1), Q),
+    "sift1m_ivf_flat_nq8": (dict(nq=8), Q),
+    "sift1m_ivf_flat_nq64": (dict(nq=64), G),
+    "sift1m_ivf_flat_nq512": (dict(nq=512), G),
+    "deep10m_ivf_pq_nq1": (dict(_PQ, nq=1), G),
+    "deep10m_ivf_pq_nq8": (dict(_PQ, nq=8), G),
+    "deep10m_ivf_pq_nq64": (dict(_PQ, nq=64), G),
+    "deep10m_ivf_pq_nq512": (dict(_PQ, nq=512), G),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SCAN_PATH_CASES))
+def test_scan_path(monkeypatch, case):
+    """``blocked_scan.scan_path``, one condition a case: the platform,
+    the slab's dtype against the one the caller's grouped scan scores, a
+    filter bitmap, k, the list's bytes, the batch's probes per list, the
+    recon tier's gather-whole clause, and each requested value."""
+    from raft_tpu.ops.pallas import gate
+
+    over, want = _SCAN_PATH_CASES[case]
+    kw = dict(_FLAT, **over)
+    tpu = kw.pop("tpu")
+    monkeypatch.setattr(gate, "on_tpu", lambda: tpu)
+    args = [kw.pop(n) for n in ("requested", "nq", "n_probes", "n_lists",
+                                "cap", "lanes", "dtype", "k", "keep_ndim")]
+    if want is LogicError:
+        with pytest.raises(LogicError):
+            bs.scan_path(*args, **kw)
+    else:
+        assert bs.scan_path(*args, **kw) == want
+
+
+@pytest.mark.parametrize("family", ["ivf_flat", "ivf_pq"])
+def test_fused_scan_kernel_is_refused(data, indexes, pq_indexes, family):
+    """``scan_kernel="fused"`` names no engine: a search asking for it
+    raises ``LogicError``, on both IVF params."""
+    from raft_tpu.neighbors import ivf_pq
+
+    _, q = data
+    with pytest.raises(LogicError, match="auto|grouped|xla"):
+        if family == "ivf_flat":
+            ivf_flat.search(indexes["sqeuclidean"], q, K,
+                            ivf_flat.IvfFlatSearchParams(
+                                n_probes=N_PROBES, scan_kernel="fused"))
+        else:
+            ivf_pq.search(pq_indexes["sqeuclidean"], q, K,
+                          ivf_pq.IvfPqSearchParams(
+                              n_probes=N_PROBES, scan_kernel="fused"))
+
+
+def _traced_paths(fn, q, ops, family="ivf_flat"):
+    """The probe-scan paths one trace of ``fn`` lowers, read from
+    ``raft_ivf_scan_path_total`` (abstract evaluation: nothing runs).
+    The rule reads the platform while the program is traced, and a test
+    patches the platform, so no trace is taken from jax's caches or
+    left in them."""
+    from raft_tpu.obs.metrics import registry
+
+    was = set_registry(MetricRegistry())
+    jax.clear_caches()
+    try:
+        jax.eval_shape(fn, q, *ops)
+        return {lb["path"]: v for lb, v in registry().counter(
+            "raft_ivf_scan_path_total").samples() if lb["family"] == family}
+    finally:
+        jax.clear_caches()
+        set_registry(was)
+
+
 def test_scan_path_rule(monkeypatch):
     """The rule at the SIFT-1M-class cell's index, 1024 lists of 1960 ×
-    128 f32 rows, 32 probes, k = 10: the kernel takes the index, and a
-    batch takes the grouped scan from 16 rows (nq·P ≥ L / 2).  The kernel
-    takes bf16 lists too (the IVF-PQ recon slab), at their own itemsize,
-    but IVF-Flat, which scores f32 queries at HIGHEST, keeps a bf16 slab
-    query-major."""
+    128 f32 rows, 32 probes, k = 10: on a TPU a batch takes the grouped
+    scan from 16 rows (nq·P ≥ L / 2); off a TPU ``"auto"`` keeps every
+    batch query-major."""
     from raft_tpu.ops.pallas import gate
 
-    takes = lambda **kw: ivf_flat.grouped_takes(
-        kw.get("cap", 1960), 128, kw.get("k", 10),
-        kw.get("dtype", jnp.float32), kw.get("keep", 0))
-    assert takes() and takes(keep=1)
-    assert not takes(dtype=jnp.uint8)
-    assert takes(dtype=jnp.bfloat16)
-    assert not takes(keep=2)
-    assert not takes(k=256)
-    assert not takes(cap=16384)
-    assert takes(cap=16384, dtype=jnp.bfloat16)      # 4 MiB at 2 bytes
-    assert not takes(cap=16400, dtype=jnp.bfloat16)
+    def ladder():
+        return [bs.scan_path("auto", b, 32, 1024, 1960, 128, jnp.float32,
+                             10, grouped_dtype=jnp.float32) == G
+                for b in (1, 8, 15, 16, 32, 64, 512)]
+
+    assert ladder() == [False] * 7
     monkeypatch.setattr(gate, "on_tpu", lambda: True)
-    lists, d = 8, 128
-    bf16 = ivf_flat.IvfFlatIndex(
-        jnp.zeros((lists, d)), jnp.zeros((lists, 16, d), jnp.bfloat16),
-        jnp.zeros((lists, 16), jnp.int32), jnp.zeros((lists,), jnp.int32),
-        jnp.zeros((lists, 16)), "sqeuclidean")
-    assert ivf_flat.resolve_scan("auto", bf16, 10, 1) != "grouped"
-    with pytest.raises(Exception, match="grouped scan takes"):
-        ivf_flat.resolve_scan("grouped", bf16, 10, 1)
-    assert [ivf_flat.grouped_batch(b, 32, 1024)
-            for b in (1, 8, 15, 16, 32, 64, 512)] == [
-        False, False, False, True, True, True, True]
+    assert ladder() == [False, False, False, True, True, True, True]
 
 
-@pytest.mark.parametrize("kernel", ["auto", "grouped", "xla", "fused"])
-def test_resolve_scan(indexes, monkeypatch, kernel):
-    """``"auto"`` takes the grouped scan on a TPU only; the other values
-    are kept as given; a per-query bitmap keeps the query-major scan."""
+@pytest.mark.parametrize("kernel", ["auto", "grouped", "xla"])
+def test_served_scan_kernel(data, indexes, monkeypatch, kernel):
+    """The served IVF-Flat program resolves ``scan_kernel`` when it is
+    traced: ``"auto"`` takes the grouped scan on a TPU only, ``"grouped"``
+    on any platform, ``"xla"`` never; a per-query bitmap keeps the
+    query-major scan, and a ``"grouped"`` request then raises."""
     from raft_tpu.ops.pallas import gate
 
+    _, q = data
     index = indexes["sqeuclidean"]
-    keep = np.ones((3, int(index.ids.max()) + 1), bool)   # per-query bitmap
-    want = "xla" if kernel == "auto" else kernel
-    assert ivf_flat.resolve_scan(kernel, index, K, 4) == want
+    fn, ops = ivf_flat.searcher(index, K, ivf_flat.IvfFlatSearchParams(
+        n_probes=N_PROBES, scan_kernel=kernel))
+    want = {"auto": Q, "grouped": G, "xla": Q}[kernel]
+    assert _traced_paths(fn, q[:23], ops) == {want: 1.0}
     monkeypatch.setattr(gate, "on_tpu", lambda: True)
-    want = "grouped" if kernel == "auto" else kernel
-    assert ivf_flat.resolve_scan(kernel, index, K, 4) == want
+    want = {"auto": G, "grouped": G, "xla": Q}[kernel]
+    assert _traced_paths(fn, q[:23], ops) == {want: 1.0}
+    keep = np.ones((23, int(index.ids.max()) + 1), bool)   # per-query bitmap
+    p = ivf_flat.IvfFlatSearchParams(n_probes=N_PROBES, scan_kernel=kernel)
     if kernel == "grouped":
-        with pytest.raises(Exception, match="grouped scan takes"):
-            ivf_flat.resolve_scan(kernel, index, K, 4, keep)
+        with pytest.raises(LogicError, match="grouped scan takes"):
+            ivf_flat.search(index, q[:23], K, p, filter=keep)
     else:
-        assert ivf_flat.resolve_scan(kernel, index, K, 4, keep) != "grouped"
+        got = ivf_flat.search(index, q[:23], K, p, filter=keep)
+        ref = ivf_flat.search(index, q[:23], K, dataclasses.replace(
+            p, scan_kernel="xla"))
+        for a, b in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_path_counter_counts_lowerings(data, indexes):
@@ -409,49 +515,54 @@ def test_ivf_pq_grouped_refined_searcher(data, pq_indexes):
 
 
 def test_ivf_pq_scan_path_rule_and_counter(data, pq_indexes, monkeypatch):
-    """``"auto"`` resolves to the grouped scan on a TPU only; its program
-    then scans a bucket grouped where nq·P ≥ L / 2 (2 rows and up here,
-    11 probes over 37 lists) and query-major below, one count per
-    compiled bucket in ``raft_ivf_pq_scan_path_total``.  Lists too large
-    for the TPU to gather whole take the grouped scan at every size."""
+    """``"auto"`` takes the recon tier's grouped scan on a TPU only; its
+    program then scans a bucket grouped where nq·P ≥ L / 2 (2 rows and
+    up here, 11 probes over 37 lists) and query-major below, one count
+    per compiled bucket in ``raft_ivf_scan_path_total{family="ivf_pq"}``.
+    Lists too large for the TPU to gather whole take the grouped scan at
+    every size.  A forced ``"grouped"`` program agrees with ``"xla"``."""
     from raft_tpu.neighbors import ivf_pq
     from raft_tpu.obs.metrics import registry
     from raft_tpu.ops.pallas import gate
 
     _, q = data
     index = pq_indexes["sqeuclidean"]
-    p = ivf_pq.IvfPqSearchParams(n_probes=N_PROBES)
-    assert ivf_pq.recon_scan("auto", index, 40, 1) == "xla"
-    assert ivf_pq.recon_scan("auto", index, 200, 1) == "xla"
-    rule = ivf_pq.grouped_recon_batch
-    assert [rule(b, 64, 4096, 2048, 128) for b in (1, 8, 16, 32, 512)] == [
-        False, False, False, True, True]
-    assert all(rule(b, 64, 4096, 3664, 128) for b in (1, 8, 16, 32, 512))
-    assert rule(1, 64, 4096, 2048, 256) and not rule(1, 64, 4096, 4096, 64)
-    keep = np.ones((3, len(data[0])), bool)            # per-query bitmap
+    fn, ops = ivf_pq.searcher(index, 40, ivf_pq.IvfPqSearchParams(
+        n_probes=N_PROBES))
+    fn200, ops200 = ivf_pq.searcher(index, 200, ivf_pq.IvfPqSearchParams(
+        n_probes=N_PROBES))
+    for rows in (1, 2, 8):
+        assert _traced_paths(fn, q[:rows], ops, "ivf_pq") == {Q: 1.0}
+
+    def rule(nq, cap, lanes):
+        return bs.scan_path("auto", nq, 64, 4096, cap, lanes, jnp.bfloat16,
+                            40, grouped_dtype=jnp.bfloat16,
+                            gather_whole=True) == G
+
     with monkeypatch.context() as m:
         m.setattr(gate, "on_tpu", lambda: True)
-        assert ivf_pq.recon_scan("auto", index, 40, 1) == "grouped"
-        assert ivf_pq.recon_scan("auto", index, 200, 1) == "xla"
-        assert ivf_pq.recon_scan("auto", index, 40, 1, keep) == "xla"
-        fn, ops = ivf_pq.searcher(index, 40, p)     # resolved on a "TPU"
-    was = set_registry(MetricRegistry())
-    try:
-        def paths():
-            return {lb["path"]: v for lb, v in registry().counter(
-                "raft_ivf_pq_scan_path_total").samples()}
-
-        prog = jax.jit(fn)
-        for rows in (1, 2, 8, 8):
-            jax.block_until_ready(prog(q[:rows], *ops))
-        assert [ivf_flat.grouped_batch(r, N_PROBES, N_LISTS)
-                for r in (1, 2)] == [False, True]
-        assert paths() == {"query_major": 1.0, "grouped": 2.0}
-        ref = _pq_search(index, q[:8], "xla", k=40)
-        got = [np.asarray(a) for a in prog(q[:8], *ops)]
-        _assert_agree(_recon_rows(index), q[:8], ref, got, "sqeuclidean")
-    finally:
-        set_registry(was)
+        assert [rule(b, 2048, 128) for b in (1, 8, 16, 32, 512)] == [
+            False, False, False, True, True]
+        assert all(rule(b, 3664, 128) for b in (1, 8, 16, 32, 512))
+        assert rule(1, 2048, 256) and not rule(1, 4096, 64)
+        assert [_traced_paths(fn, q[:rows], ops, "ivf_pq")
+                for rows in (1, 2, 8)] == [{Q: 1.0}, {G: 1.0}, {G: 1.0}]
+        assert _traced_paths(fn200, q[:8], ops200, "ivf_pq") == {Q: 1.0}
+        keep = np.ones((8, len(data[0])), bool)         # per-query bitmap
+        was = set_registry(MetricRegistry())
+        try:
+            jax.clear_caches()
+            ivf_pq.search(index, q[:8], 40, ivf_pq.IvfPqSearchParams(
+                n_probes=N_PROBES), filter=keep)
+            assert {lb["path"]: v for lb, v in registry().counter(
+                "raft_ivf_scan_path_total").samples()} == {Q: 1.0}
+        finally:
+            set_registry(was)
+    gfn, gops = ivf_pq.searcher(index, 40, ivf_pq.IvfPqSearchParams(
+        n_probes=N_PROBES, scan_kernel="grouped"))
+    ref = _pq_search(index, q[:8], "xla", k=40)
+    got = [np.asarray(a) for a in jax.jit(gfn)(q[:8], *gops)]
+    _assert_agree(_recon_rows(index), q[:8], ref, got, "sqeuclidean")
 
 
 @pytest.mark.parametrize("entry", ["build", "build_chunked"])

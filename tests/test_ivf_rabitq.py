@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -174,24 +173,19 @@ def test_probe_block_invariance(index, queries):
 
 
 def test_scan_kernel_arms_agree(index, queries):
-    """'auto' resolves to the XLA estimator scan — both arms must return
-    identical results."""
+    """The estimator scan has one arm, so the params carry no
+    ``scan_kernel``; ``rerank_k=0`` resolves to ``max(32, 8·k)`` and
+    returns what that width asked for by name returns."""
+    assert not hasattr(IvfRabitqSearchParams(), "scan_kernel")
+    assert ivf_rabitq.resolve_rerank_k(0, K, 8, index.list_cap) == max(
+        32, 8 * K)
     outs = []
-    for arm in ("auto", "xla"):
-        p = IvfRabitqSearchParams(n_probes=8, rerank_k=64, scan_kernel=arm)
+    for rk in (0, max(32, 8 * K)):
+        p = IvfRabitqSearchParams(n_probes=8, rerank_k=rk)
         dv, di = ivf_rabitq.search(index, queries, K, p)
         outs.append((np.asarray(dv), np.asarray(di)))
-    for dv, di in outs[1:]:
-        np.testing.assert_array_equal(outs[0][0], dv)
-        np.testing.assert_array_equal(outs[0][1], di)
-
-
-def test_fused_scan_request_is_refused(index, queries):
-    """No fused estimator kernel exists: a ``scan_kernel="fused"``
-    request raises instead of quietly running the XLA scan."""
-    p = IvfRabitqSearchParams(n_probes=8, rerank_k=64, scan_kernel="fused")
-    with pytest.raises(RaftError, match="no fused estimator scan"):
-        ivf_rabitq.search(index, queries, K, p)
+    np.testing.assert_array_equal(outs[0][0], outs[1][0])
+    np.testing.assert_array_equal(outs[0][1], outs[1][1])
 
 
 def test_searcher_matches_search(index, queries):
@@ -449,15 +443,3 @@ def test_oracle_database_covers_rabitq(index):
     vecs2, ids2 = oracle_database(mutation.delete(index, dead))
     assert ids2.shape[0] == N - len(dead)
     assert not np.isin(ids2, dead).any()
-
-
-def test_tune_table_key_matches_tuner():
-    """bench/tune_rabitq.py and the resolver must agree on the bucket
-    key scheme, or tuned entries are silently dead."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "tune_rabitq", os.path.join(os.path.dirname(__file__), "..",
-                                    "bench", "tune_rabitq.py"))
-    src = open(spec.origin).read()
-    assert 'f"ivf_rabitq:{k.bit_length()}:{n_probes.bit_length()}"' in src

@@ -367,16 +367,6 @@ def test_quality_oracle_reads_store(index, db):
     np.testing.assert_array_equal(vecs[np.argsort(ids)], db)
 
 
-def test_fused_scan_request_is_refused(index, queries):
-    """scan_kernel="fused" has no estimator kernel: the request raises
-    (ivf_rabitq's refusal) instead of quietly running the XLA scan."""
-    from raft_tpu.core.errors import RaftError
-
-    with pytest.raises(RaftError, match="no fused estimator scan"):
-        ooc.search(index, queries, K, OocSearchParams(
-            n_probes=4, rerank_k=64, scan_kernel="fused"))
-
-
 # ---------------------------------------------------------------------------
 # persistence (format v5: manifest directory + sharded store)
 

@@ -37,13 +37,10 @@ _KERNELS = {
                                         256, 8192, 256, 2048),
     "fused_l2_topk_int8_256x8192x128": ("fused_l2", jnp.int8,
                                         256, 8192, 256, 2048),
-    # blocked_scan.scan_topk_fused's slab kernel at its default blocks
-    "fused_slab_topk_256x4096x128": ("fused_slab", 256, 4096, 8, 512),
 }
 
 # the device op each kind of kernel shows up as
-_OP_NAMES = {"select_k": "select_k", "fused_l2": "fused_l2_topk",
-             "fused_slab": "fused_scan"}
+_OP_NAMES = {"select_k": "select_k", "fused_l2": "fused_l2_topk"}
 
 
 @pytest.fixture(scope="module")
@@ -89,20 +86,12 @@ def _kernel_program(case, s):
         _, shape, k, bm, bn = case
         return select_k.select_k, (_spec(shape, jnp.float32, s), k, bm, bn,
                                 False)
-    if kind == "fused_l2":
-        from raft_tpu.ops.pallas import fused_l2_topk
+    from raft_tpu.ops.pallas import fused_l2_topk
 
-        _, dtype, m, n, bm, bn = case
-        return fused_l2_topk.fused_l2_topk, (
-            _spec((m, 128), dtype, s), _spec((n, 128), dtype, s),
-            _spec((1, n), jnp.float32, s), bm, bn, False)
-    from raft_tpu.ops.pallas import fused_scan
-
-    _, nq, c, bm, bn = case
-    return fused_scan.fused_scan, (
-        _spec((nq, 128), jnp.bfloat16, s),
-        _spec((nq, c, 128), jnp.bfloat16, s),
-        _spec((nq, c), jnp.float32, s), bm, bn, False)
+    _, dtype, m, n, bm, bn = case
+    return fused_l2_topk.fused_l2_topk, (
+        _spec((m, 128), dtype, s), _spec((n, 128), dtype, s),
+        _spec((1, n), jnp.float32, s), bm, bn, False)
 
 
 @pytest.mark.parametrize("name", sorted(_KERNELS))
@@ -155,7 +144,7 @@ def test_fleet_ivf_flat_compiles_for_v5e_2x2(topo):
     rep, sh = NamedSharding(mesh, P()), NamedSharding(mesh, P("shard"))
     lists, cap, d, nq = 1024, 12_208, 96, 64
     fn = _ivf_flat_fleet_program(mesh, "shard", 10, 32, "sqeuclidean", 1,
-                                 lists // 4, False, False, lists)
+                                 lists // 4, False, "xla", lists)
     args = (_spec((nq, d), jnp.float32, rep),
             _spec((lists, d), jnp.float32, rep),
             _spec((lists, cap, d), jnp.float32, sh),
@@ -184,7 +173,7 @@ def test_fleet_ivf_flat_grouped_compiles_for_v5e_2x2(topo, monkeypatch):
     rep, sh = NamedSharding(mesh, P()), NamedSharding(mesh, P("shard"))
     lists, cap, d, nq = 1024, 1960, 128, 512
     fn = _ivf_flat_fleet_program(mesh, "shard", 10, 32, "sqeuclidean", 1,
-                                 lists // 4, False, True, lists)
+                                 lists // 4, False, "auto", lists)
     args = (_spec((nq, d), jnp.float32, rep),
             _spec((lists, d), jnp.float32, rep),
             _spec((lists, cap, d), jnp.float32, sh),
@@ -208,6 +197,7 @@ def test_ivf_flat_grouped_search_compiles_for_one_v5e(one_chip, monkeypatch,
     1954 rows a list (padded to the f32 sublane tile), the slab is stored
     list-major and nothing in either program copies it."""
     from raft_tpu.neighbors import ivf_flat
+    from raft_tpu.ops.blocked_scan import scan_path
     from raft_tpu.ops.pallas import gate
 
     monkeypatch.setattr(gate, "on_tpu", lambda: True)
@@ -224,7 +214,8 @@ def test_ivf_flat_grouped_search_compiles_for_one_v5e(one_chip, monkeypatch,
                              *ops).compile().as_text()
     kernel = [line.lstrip() for line in text.splitlines()
               if "tpu_custom_call" in line and "ivf_grouped_scan" in line]
-    if ivf_flat.grouped_batch(rows, 32, lists):
+    if scan_path("auto", rows, 32, lists, cap, d, jnp.float32, 10,
+                 grouped_dtype=jnp.float32) == "grouped":
         assert kernel and kernel[0].startswith("%ivf_grouped_scan."), kernel
     else:
         assert not kernel, kernel
@@ -280,14 +271,12 @@ def _served_ivf_pq(one_chip, monkeypatch, rows):
     from raft_tpu.neighbors import ivf_pq
     from raft_tpu.neighbors.refine import Refined
     from raft_tpu.neighbors import _packing
-    from raft_tpu.ops import blocked_scan
     from raft_tpu.ops.pallas import gate
     from raft_tpu.serve import make_searcher
 
     monkeypatch.setattr(gate, "on_tpu", lambda: True)
     monkeypatch.setattr(_packing, "_probe_block_table", lambda: {})
     monkeypatch.setattr(_packing, "_probe_block_cache", {})
-    monkeypatch.setattr(blocked_scan, "_scan_kernel_table", lambda: {})
     cfg = _deep10m_ivf_pq()
     n, d, k = (cfg["data"][key] for key in ("rows", "dim", "k"))
     lists, m = cfg["index"]["n_lists"], cfg["index"]["pq_dim"]
